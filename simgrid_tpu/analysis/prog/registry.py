@@ -4,7 +4,8 @@ example-args factory.
 Each :class:`ProgramSpec` names one jitted program, its
 :class:`~.contract.ProgramContract`, and a ``make(scale)`` factory
 returning the exact ``(args, statics)`` a production driver would
-dispatch it with at a tiny example geometry.  For the drain/fleet
+dispatch it with at a tiny example geometry (``make(scale, dtype)``
+stages the same dispatch in another solve dtype).  For the drain/fleet
 programs the factory does not re-derive the argument assembly — it
 builds a real (tiny) sim and *captures* the driver's own dispatch by
 swapping the module-level jit wrapper for a raiser, so the registry
@@ -60,7 +61,10 @@ class ProgramSpec:
     jitted: Any
     program: Callable
     contract: ProgramContract
-    make: Callable[[int], Tuple[tuple, Dict[str, Any]]]
+    #: ``make(scale, dtype=<the contract's solve dtype>)``; another
+    #: dtype stages the same program for a device whose solver dtype
+    #: differs (chip_smoke.py's compile sweep on the TPU)
+    make: Callable[..., Tuple[tuple, Dict[str, Any]]]
 
 
 class _Captured(Exception):
@@ -269,24 +273,24 @@ def iter_programs() -> List[ProgramSpec]:
         ProgramSpec(
             "drain/superstep", ld._drain_superstep_donate,
             ld._superstep_program, _drain_contract("float64"),
-            lambda s: _solo_superstep(s, f64)),
+            lambda s, dt=f64: _solo_superstep(s, dt)),
         ProgramSpec(
             "drain/superstep_f32", ld._drain_superstep_donate,
             ld._superstep_program, _drain_contract("float32"),
-            lambda s: _solo_superstep(s, f32)),
+            lambda s, dt=f32: _solo_superstep(s, dt)),
         ProgramSpec(
             "drain/superstep_tape", ld._drain_superstep_donate,
             ld._superstep_program, _drain_contract("float64"),
-            lambda s: _solo_superstep(s, f64, tape=True)),
+            lambda s, dt=f64: _solo_superstep(s, dt, tape=True)),
         ProgramSpec(
             "drain/superstep_coll", ld._drain_superstep_donate,
             ld._superstep_program, _drain_contract("float64"),
-            lambda s: _solo_superstep(s, f64, coll=True)),
+            lambda s, dt=f64: _solo_superstep(s, dt, coll=True)),
         ProgramSpec(
             "drain/fused_step", ld._drain_fused_step,
             ld._fused_step_program,
             _drain_contract("float64", donated=(), outputs=fused_out),
-            lambda s: _solo_fused(s, f64)),
+            lambda s, dt=f64: _solo_fused(s, dt)),
         ProgramSpec(
             "drain/solve_chunk", ld._drain_solve_chunk,
             ld._solve_chunk_program,
@@ -295,28 +299,28 @@ def iter_programs() -> List[ProgramSpec]:
                 allowed_dtypes=("float64",) + _COMMON,
                 expected_outputs=chunk_out,
                 donated=(), fma_pinned=False),
-            lambda s: _solo_chunk(s, f64)),
+            lambda s, dt=f64: _solo_chunk(s, dt)),
         ProgramSpec(
             "fleet/superstep", lb._batch_superstep_donate,
             lb._batch_superstep_program, _drain_contract("float64"),
-            lambda s: _fleet_superstep(s, f64)),
+            lambda s, dt=f64: _fleet_superstep(s, dt)),
         ProgramSpec(
             "fleet/superstep_f32", lb._batch_superstep_donate,
             lb._batch_superstep_program, _drain_contract("float32"),
-            lambda s: _fleet_superstep(s, f32)),
+            lambda s, dt=f32: _fleet_superstep(s, dt)),
         ProgramSpec(
             "fleet/superstep_tape", lb._batch_superstep_donate,
             lb._batch_superstep_program, _drain_contract("float64"),
-            lambda s: _fleet_superstep(s, f64, tape=True)),
+            lambda s, dt=f64: _fleet_superstep(s, dt, tape=True)),
         ProgramSpec(
             "fleet/superstep_coll", lb._batch_superstep_donate,
             lb._batch_superstep_program, _drain_contract("float64"),
-            lambda s: _fleet_superstep(s, f64, coll=True)),
+            lambda s, dt=f64: _fleet_superstep(s, dt, coll=True)),
         ProgramSpec(
             "fleet/fused_fresh", lb._batch_fused_fresh,
             lb._batch_fused_fresh.__wrapped__,
             _drain_contract("float64", donated=(), outputs=fused_out),
-            lambda s: _fleet_fused(s, f64)),
+            lambda s, dt=f64: _fleet_fused(s, dt)),
         ProgramSpec(
             "warm/warm_init", lw._warm_init,
             lw._warm_init.__wrapped__,
@@ -324,7 +328,7 @@ def iter_programs() -> List[ProgramSpec]:
                 solve_dtype="float64",
                 allowed_dtypes=("float64",) + _COMMON,
                 expected_outputs=6, donated=(), fma_pinned=False),
-            lambda s: _warm_init_args(s, f64)),
+            lambda s, dt=f64: _warm_init_args(s, dt)),
         ProgramSpec(
             "warm/apply_deltas", lw._apply_deltas,
             lw._apply_deltas.__wrapped__,
@@ -332,6 +336,6 @@ def iter_programs() -> List[ProgramSpec]:
                 solve_dtype="float64",
                 allowed_dtypes=("float64",) + _COMMON,
                 expected_outputs=7, donated=(), fma_pinned=False),
-            lambda s: _apply_deltas_args(s, f64)),
+            lambda s, dt=f64: _apply_deltas_args(s, dt)),
     ]
     return specs

@@ -25,8 +25,8 @@ serves *batches* of advances from one `DrainSim` superstep dispatch
 * with ``drain/pipeline`` > 0 the NEXT superstep is issued
   speculatively the moment ring N is fetched — JAX dispatch is async,
   so the device executes ring N+1 while the engine consumes ring N's
-  batches, and the next fetch finds a ready buffer instead of paying
-  the tunnel round trip.  Speculation never touches the committed
+  batches, and the next fetch finds a ready buffer instead of waiting
+  out the dispatch.  Speculation never touches the committed
   flow state (the dispatch chains from double-buffered immutable
   arrays), so ANY plan teardown — profile event before the horizon,
   an unrecognized ArrayView mutation, a stall — simply discards the
@@ -94,7 +94,9 @@ import numpy as np
 
 from ..utils.config import config
 from . import opstats
+from .device import solve_dtype
 from .lmm_host import double_update
+from .lmm_jax import SolveError
 
 #: started-flow census below which a plan is never attempted (plan
 #: bookkeeping beats the generic path only at scale); the config flag
@@ -288,8 +290,7 @@ class DrainFastPath:
         Amortized over the K advances each superstep serves."""
         from .lmm_drain import DrainSim
 
-        dtype = (np.float32 if config["lmm/dtype"] == "float32"
-                 else np.float64)
+        dtype = solve_dtype(config["lmm/dtype"], "lmm/dtype")
         absorbing = self._transitions_enabled()
         plan = _plan_inputs(self.model, dtype, allow_latency=absorbing)
         if plan is None:
@@ -393,7 +394,7 @@ class DrainFastPath:
         self.served = 0
         try:
             n_live, batches, clean = sim._superstep_collect(tok)
-        except RuntimeError:
+        except SolveError:
             # stall/non-convergence surfaced mid-batch: the advances it
             # applied were never served, so restore the batch-start
             # state (immutable arrays: an O(1) rollback) and hand the
@@ -565,7 +566,7 @@ class DrainFastPath:
             try:
                 self._sync_to_served()
                 done_slots, _n_live = self.sim.partial_advance(delta)
-            except RuntimeError:
+            except SolveError:
                 self._invalidate(sync=True, with_rates=True,
                                  cause="stall")
                 return False

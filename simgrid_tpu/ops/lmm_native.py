@@ -7,11 +7,14 @@ host-side floor of the auto dispatch (small live sets stay native-fast,
 large ones go to the device; SURVEY.md hard part (e)).
 
 The shared library is built on demand from native/lmm.cc with g++ (no
-pip/pybind11 dependency; plain C ABI)."""
+pip/pybind11 dependency; plain C ABI).  Its file name carries a hash of
+the source, so a binary built from other sources is never loaded: when
+lmm.cc changes, the next load builds a new one."""
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -22,32 +25,48 @@ from .lmm_host import System
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libsimgrid_lmm.so")
 
 _lib = None
 _lib_error: Optional[str] = None
 
 
-def _build_library() -> None:
+def _build_library() -> str:
+    """Path of the library built from the lmm.cc that is on disk now,
+    compiling it first unless that exact source was built before."""
     src = os.path.join(_NATIVE_DIR, "lmm.cc")
-    subprocess.run(
-        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _LIB_PATH,
-         src],
-        check=True, capture_output=True, text=True)
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    path = os.path.join(_NATIVE_DIR, f"libsimgrid_lmm-{digest}.so")
+    if not os.path.exists(path):
+        # build beside the target and rename: a concurrent loader never
+        # sees a half-written library
+        tmp = f"{path}.{os.getpid()}.tmp"
+        proc = subprocess.run(
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp,
+             src], capture_output=True, text=True)
+        if proc.returncode != 0:
+            raise OSError(f"g++ failed on {src} "
+                          f"(rc={proc.returncode}):\n{proc.stderr}")
+        os.replace(tmp, path)
+    return path
 
 
 def load_library():
-    """Load (building if needed) the native solver; None if unavailable."""
+    """Load (building if needed) the native solver.  Raises when it
+    cannot be built or loaded, with the compiler's stderr — the native
+    backend was asked for by name, or is needed as the oracle.  The
+    ``auto`` dispatch asks :func:`available` instead."""
     global _lib, _lib_error
-    if _lib is not None or _lib_error is not None:
+    if _lib is not None:
         return _lib
+    if _lib_error is not None:
+        raise RuntimeError(f"native LMM solver unavailable: {_lib_error}")
     try:
-        if not os.path.exists(_LIB_PATH):
-            _build_library()
-        lib = ctypes.CDLL(_LIB_PATH)
-    except (OSError, subprocess.CalledProcessError) as exc:
+        lib = ctypes.CDLL(_build_library())
+    except OSError as exc:      # no g++, failed compile, unloadable .so
         _lib_error = str(exc)
-        return None
+        raise RuntimeError(
+            f"native LMM solver unavailable: {_lib_error}") from exc
     lib.lmm_solve_coo.restype = ctypes.c_int32
     # raw pointers, not np.ctypeslib.ndpointer: the per-call from_param
     # validation machinery cost ~18s of a 175s Chord run (the solver
@@ -64,7 +83,15 @@ def load_library():
 
 
 def available() -> bool:
-    return load_library() is not None
+    """Whether the native solver can be used — the question the
+    ``auto`` dispatch asks before choosing between it and the Python
+    list solver.  Anything that names the native backend calls
+    :func:`load_library` and gets the reason instead."""
+    try:
+        load_library()
+    except RuntimeError:
+        return False
+    return True
 
 
 def solve_coo(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
@@ -72,8 +99,6 @@ def solve_coo(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     """Solve a flattened COO system natively; returns (values, remaining,
     usage) over the first n_v / n_c slots."""
     lib = load_library()
-    if lib is None:
-        raise RuntimeError(f"native LMM solver unavailable: {_lib_error}")
     values = np.empty(n_v, np.float64)
     remaining = np.empty(n_c, np.float64)
     usage = np.empty(n_c, np.float64)

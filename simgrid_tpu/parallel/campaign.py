@@ -61,8 +61,10 @@ import numpy as np
 from ..collectives.spec import CollectiveSpec
 from ..faults import FaultCampaign
 from ..ops import opstats
+from ..ops.device import solve_dtype
 from ..ops.lmm_batch import (BatchDrainSim, ReplicaOverrides,
                              derive_replica_arrays, derive_replica_ew)
+from ..ops.lmm_jax import SolveError
 
 #: a fully-failed link would zero its capacity and stall every flow
 #: routed over it; campaigns clamp availability-derived factors here
@@ -232,10 +234,7 @@ def _mesh_size(mesh) -> int:
         return 0
     if isinstance(mesh, int):
         return int(mesh)
-    try:
-        return int(np.prod(list(mesh.shape.values())))
-    except Exception:
-        return 0
+    return int(np.prod(list(mesh.shape.values())))
 
 
 class ScenarioPlan:
@@ -256,8 +255,8 @@ class ScenarioPlan:
     def __init__(self, e_var, e_cnst, e_w, c_bound, sizes,
                  remains=None, penalty=None, v_bound=None,
                  link_names: Optional[List[Optional[str]]] = None,
-                 eps: float = 1e-9, done_eps: float = 1e-4,
-                 dtype=np.float64, done_mode: str = "rel",
+                 eps: Optional[float] = None, done_eps: float = 1e-4,
+                 dtype=None, done_mode: str = "rel",
                  superstep: int = 8, pipeline: int = 0, mesh=None,
                  fault_mode: Optional[str] = None,
                  collective: Optional[CollectiveSpec] = None,
@@ -274,9 +273,17 @@ class ScenarioPlan:
         self.v_bound = (np.asarray(v_bound, np.float64)
                         if v_bound is not None else None)
         self.link_names = link_names
-        self.eps = float(eps)
         self.done_eps = float(done_eps)
-        self.dtype = np.dtype(dtype)
+        # None = the device's own solver dtype (float64 where it is
+        # IEEE, float32 on the TPU).  A collective plan needs float64,
+        # so on a device without it the request is refused here by name
+        self.dtype = (solve_dtype(np.float64, "ScenarioPlan(collective=)")
+                      if dtype is None and collective is not None
+                      else solve_dtype(dtype, "ScenarioPlan(dtype=)"))
+        # ... and the solver epsilon that dtype can resolve: the
+        # oracle's 1e-9 in float64, maxmin/precision's 1e-5 in float32
+        self.eps = (float(eps) if eps is not None
+                    else 1e-9 if self.dtype == np.float64 else 1e-5)
         self.done_mode = done_mode
         self.superstep = int(superstep)
         self.pipeline = int(pipeline)
@@ -590,7 +597,7 @@ class ScenarioPlan:
         error = None
         try:
             sim.run()
-        except RuntimeError as exc:
+        except SolveError as exc:
             error = str(exc)
         return ReplicaResult(spec, sim.events, sim.t, sim.advances,
                              error, fault_events=sim.fault_events,
@@ -607,8 +614,8 @@ class Campaign:
                  specs: Sequence[ScenarioSpec],
                  remains=None, penalty=None, v_bound=None,
                  link_names: Optional[List[Optional[str]]] = None,
-                 eps: float = 1e-9, done_eps: float = 1e-4,
-                 dtype=np.float64, done_mode: str = "rel",
+                 eps: Optional[float] = None, done_eps: float = 1e-4,
+                 dtype=None, done_mode: str = "rel",
                  superstep: int = 8, pipeline: int = 0, mesh=None,
                  fault_mode: Optional[str] = None, plan_cache=None,
                  collective: Optional[CollectiveSpec] = None):

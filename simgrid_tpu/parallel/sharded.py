@@ -46,13 +46,6 @@ from ..ops.lmm_drain import _advance_math
 from ..ops.lmm_jax import (_MAX_ROUNDS, LmmArrays, _solve_chunk_batched_lane,
                            check_convergence, fixpoint, use_local_rounds)
 
-# jax.shard_map moved to the top level after 0.4.x; fall back to the
-# experimental home so the element-sharded path works on both.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def make_mesh(n_devices: Optional[int] = None, sim: int = 1,
               devices=None) -> Mesh:
     """Build a ("sim", "elem") mesh over the first n_devices devices."""
@@ -87,12 +80,12 @@ def _sharded_run(mesh: Mesh, axis: str, n_c: int, n_v: int,
         in_shardings=(espec, espec, espec, rspec, rspec, rspec, rspec, rspec),
         out_shardings=rspec)
     def run(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound, eps):
-        fn = _shard_map(
+        fn = jax.shard_map(
             functools.partial(fixpoint, n_c=n_c, n_v=n_v, axis=axis,
                               parallel_rounds=parallel_rounds),
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(), P(), P(), P(), P()),
-            out_specs=P(), check_rep=False)
+            out_specs=P(), check_vma=False)
         return fn(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty,
                   v_bound, eps)
 
@@ -210,14 +203,14 @@ def sharded_step(mesh: Mesh, parallel_rounds=None):
 
     def step(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
              v_remains, eps):
-        fn = _shard_map(
+        fn = jax.shard_map(
             jax.vmap(one_sim,
                      in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None)),
             mesh=mesh,
             in_specs=(espec, espec, espec,
                       P("sim"), P("sim"), P("sim"), P("sim"), P("sim"),
                       P()),
-            out_specs=(P("sim"), P("sim"), P("sim")), check_rep=False)
+            out_specs=(P("sim"), P("sim"), P("sim")), check_vma=False)
         return fn(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty,
                   v_bound, v_remains, eps)
 
@@ -234,28 +227,35 @@ def sharded_step(mesh: Mesh, parallel_rounds=None):
 
 def assert_sharded_matches_at_scale(n_devices: int,
                                     n_c: int = 16384, n_v: int = 100_000,
-                                    deg: int = 4) -> str:
-    """BASELINE-scale consistency check (VERDICT r02 item 9): the
+                                    deg: int = 4, devices=None) -> str:
+    """BASELINE-scale consistency check: the
     (elem-)sharded solve over `n_devices` devices must equal the
-    single-device solve bit-for-bit.  Runs on the CPU mesh in f64 (the
-    oracle precision; the caller forces the CPU backend — the real-TPU
-    path is exercised separately in f32 by bench.py).  Shared by
+    single-device solve to 1e-12.  Runs on ``devices`` (default
+    ``jax.devices()``) in their own solver dtype (f64 on a CPU mesh,
+    the oracle precision; f32 with a matching tolerance on TPUs).
+    Shared by
     tests/test_parallel.py and __graft_entry__.dryrun_multichip so the
     check cannot drift between the two."""
     import numpy as _np
 
     from bench import build_arrays
     from ..ops import lmm_jax
+    from ..ops.device import solve_dtype
 
+    if devices is None:
+        devices = jax.devices()
+    dtype = solve_dtype(None, "assert_sharded_matches_at_scale",
+                        devices[0])
+    eps, tol = (1e-9, 1e-12) if dtype == _np.float64 else (1e-5, 1e-4)
     # simlint: ignore[wallclock-rng] -- fixed-seed scenario generator for the self-check harness; never feeds simulation state
-    big = build_arrays(_np.random.default_rng(42), n_c, n_v, deg,
-                       _np.float64)
-    v1, r1, u1, rounds1 = lmm_jax.solve_arrays(big, 1e-9,
+    big = build_arrays(_np.random.default_rng(42), n_c, n_v, deg, dtype)
+    v1, r1, u1, rounds1 = lmm_jax.solve_arrays(big, eps,
+                                               device=devices[0],
                                                parallel_rounds=True)
-    mesh = make_mesh(n_devices, sim=1)
-    v8, r8, u8, rounds8 = sharded_solve(big, 1e-9, mesh)
-    _np.testing.assert_allclose(v8, v1, rtol=1e-12, atol=1e-12)
-    _np.testing.assert_allclose(r8, r1, rtol=1e-12, atol=1e-12)
-    _np.testing.assert_allclose(u8, u1, rtol=1e-12, atol=1e-12)
+    mesh = make_mesh(n_devices, sim=1, devices=devices)
+    v8, r8, u8, rounds8 = sharded_solve(big, eps, mesh)
+    _np.testing.assert_allclose(v8, v1, rtol=tol, atol=tol)
+    _np.testing.assert_allclose(r8, r1, rtol=tol, atol=tol)
+    _np.testing.assert_allclose(u8, u1, rtol=tol, atol=tol)
     return (f"sharded {n_v}-flow solve over {n_devices} devices matches "
             f"single-device ({rounds8} rounds vs {rounds1})")

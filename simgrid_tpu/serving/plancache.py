@@ -16,9 +16,11 @@ layout, dtype, B, superstep, pipeline, mesh, fault_mode) addresses the
 scenario content; the signature appended here (concrete arg shapes +
 dtypes + static kwargs + jax version + platform + device count) makes
 it impossible for a stale or foreign artifact to be invoked on
-mismatched inputs — any miss falls back to compiling (and a failed
-deserialize/execute falls back to the plain traced jit, counted in
-``plan_cache_fallbacks``, never an error).
+mismatched inputs — any miss compiles.  An artifact read from disk
+that fails to deserialize, or that this backend refuses to execute, is
+evicted and recompiled (counted in ``plan_cache_fallbacks``).  A
+failure of a FRESHLY compiled executable is raised: it is the device
+refusing the program, and the traced jit would only hide it.
 
 opstats counters: ``plan_cache_hits`` (memory or disk),
 ``plan_cache_misses`` (fresh AOT compile), ``plan_compile_ms``
@@ -39,7 +41,8 @@ import jax
 from ..ops import opstats
 
 #: bumped when the serialized artifact layout changes
-_FORMAT_VERSION = 1
+#: (2: records carry the ids of the devices the executable runs on)
+_FORMAT_VERSION = 2
 
 
 def _signature(args, statics: Dict[str, Any]) -> str:
@@ -70,7 +73,9 @@ class PlanCache:
         if self.cache_dir:
             os.makedirs(self.cache_dir, exist_ok=True)
         self._mem: Dict[str, Any] = {}
-        self._broken: Dict[str, bool] = {}
+        #: digests whose resident executable was deserialized from disk
+        #: (the only ones ``call`` may recover from)
+        self._from_disk: set = set()
         self.hits = 0
         self.misses = 0
         self.disk_hits = 0
@@ -107,8 +112,15 @@ class PlanCache:
             rec = pickle.load(f)
         if rec.get("format") != _FORMAT_VERSION:
             return None
+        # load onto the devices the executable was compiled for: left
+        # to its default, deserialize_and_load spreads a one-device
+        # program over EVERY visible device and the first call fails
+        by_id = {d.id: d for d in jax.devices()}
+        if not all(i in by_id for i in rec["devices"]):
+            return None
         return serialize_executable.deserialize_and_load(
-            rec["payload"], rec["in_tree"], rec["out_tree"])
+            rec["payload"], rec["in_tree"], rec["out_tree"],
+            execution_devices=[by_id[i] for i in rec["devices"]])
 
     def _store_disk(self, digest: str, compiled) -> None:
         if not self.cache_dir:
@@ -117,7 +129,9 @@ class PlanCache:
         payload, in_tree, out_tree = serialize_executable.serialize(
             compiled)
         rec = {"format": _FORMAT_VERSION, "payload": payload,
-               "in_tree": in_tree, "out_tree": out_tree}
+               "in_tree": in_tree, "out_tree": out_tree,
+               "devices": [d.id for d in compiled.runtime_executable()
+                           .local_devices()]}
         path = self._path(digest)
         tmp = path + ".tmp"
         with open(tmp, "wb") as f:
@@ -142,6 +156,7 @@ class PlanCache:
             ex = None  # corrupt/foreign artifact: recompile below
         if ex is not None:
             self._mem[digest] = ex
+            self._from_disk.add(digest)
             self.hits += 1
             self.disk_hits += 1
             opstats.bump("plan_cache_hits")
@@ -163,31 +178,29 @@ class PlanCache:
 
     def call(self, key: str, kind: str, jitted_fn, args,
              statics: Dict[str, Any]):
-        """Execute one fleet program through the cache.  Any failure in
-        the AOT path (unserializable backend, stale artifact, sharding
-        the executable refuses) falls back to the plain traced jit —
-        correctness never depends on the cache."""
+        """Execute one fleet program through the cache.  An executable
+        deserialized from disk that this backend refuses to run (a
+        stale or foreign artifact) is evicted — from memory and from
+        disk, so a restart recompiles too — and the program is compiled
+        afresh.  Whatever a freshly compiled executable raises
+        propagates."""
         digest = self._digest(key, kind, _signature(args, statics))
-        if not self._broken.get(digest):
+        ex = self.get_or_compile(key, kind, jitted_fn, args, statics)
+        try:
+            return ex(*args)
+        except Exception:
+            if digest not in self._from_disk:
+                raise
+            self._from_disk.discard(digest)
+            self._mem.pop(digest, None)
             try:
-                ex = self.get_or_compile(key, kind, jitted_fn, args,
-                                         statics)
-                return ex(*args)
-            except Exception:
-                self._broken[digest] = True
-                self._mem.pop(digest, None)
-                # evict the on-disk artifact too: a restarted process
-                # would deserialize the same broken executable and
-                # re-fail — deleting it makes the restart RECOMPILE
-                # instead (best-effort; serving continues either way)
-                if self.cache_dir:
-                    try:
-                        os.remove(self._path(digest))
-                    except OSError:
-                        pass
-                self.fallbacks += 1
-                opstats.bump("plan_cache_fallbacks")
-        return jitted_fn(*args, **statics)
+                os.remove(self._path(digest))
+            except OSError:
+                pass
+            self.fallbacks += 1
+            opstats.bump("plan_cache_fallbacks")
+        return self.get_or_compile(key, kind, jitted_fn, args,
+                                   statics)(*args)
 
     def stats(self) -> Dict[str, float]:
         return {"plan_cache_hits": self.hits,
@@ -211,3 +224,13 @@ class CompiledPlan:
              statics: Dict[str, Any]):
         return self.cache.call(self.key, kind, jitted_fn, args,
                                statics)
+
+    def compile(self, kind: str, jitted_fn, args,
+                statics: Dict[str, Any]) -> None:
+        """Make the executable resident (load or compile) without
+        running it — the step a dispatch watchdog keeps outside its
+        retry guard."""
+        cache = self.cache
+        digest = cache._digest(self.key, kind, _signature(args, statics))
+        if digest not in cache._mem:
+            cache.get_or_compile(self.key, kind, jitted_fn, args, statics)

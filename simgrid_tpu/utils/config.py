@@ -240,7 +240,12 @@ declare_flag("lmm/backend",
 declare_flag("lmm/jax-threshold",
              "Minimum live variable count before 'auto' switches the solve "
              "to the JAX backend", 512)
-declare_flag("lmm/dtype", "JAX solver dtype: float64 or float32", "float64")
+declare_flag("lmm/dtype",
+             "JAX solver dtype: float64, float32, or auto (float64 where "
+             "the device's float64 is IEEE double — the CPU backend — "
+             "and float32 on the TPU, whose float64 is an emulated f32 "
+             "pair).  An explicit float64 on such a device is an error, "
+             "not a demotion (ops/device.py)", "auto")
 declare_flag("lmm/layout",
              "Device solver element layout: coo (scatter/segment ops), "
              "ell (dense padded rows — accelerator-native, no scatters), "
@@ -357,9 +362,10 @@ declare_flag("drain/done-eps",
              "maxmin*surf precision instead", 1e-4)
 declare_flag("lmm/unroll",
              "Unroll the device fixpoint into straight-line XLA instead "
-             "of lax.while_loop: on, off, or auto (on for accelerators — "
-             "some backends lower gathers inside while_loop to serialized "
-             "dynamic-slice loops; unrolled code keeps them vectorized)",
+             "of lax.while_loop: on, off, or auto (off on every backend: "
+             "while_loop gathers lower fine on the TPU and unrolling only "
+             "multiplies compile time; on is the escape hatch for a "
+             "backend that serializes gathers inside loops)",
              "auto")
 declare_flag("serve/batch",
              "Resident fleet width of the always-on campaign service "

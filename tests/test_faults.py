@@ -479,7 +479,8 @@ def test_lmm_nonconvergence_falls_back_to_host_solver(monkeypatch):
     s, cnst, var = _jax_system()
 
     def explode(arrays, eps, **kw):
-        raise RuntimeError("LMM JAX solve did not converge (forced)")
+        raise lmm_jax.SolveError(
+            "LMM JAX solve did not converge (forced)")
     monkeypatch.setattr(lmm_jax, "solve_arrays", explode)
     before = lmm_jax.get_fallback_count()
     s.solve()                            # lmm/strict defaults to off
@@ -507,7 +508,8 @@ def test_lmm_strict_mode_preserves_the_raise(monkeypatch):
     s, cnst, var = _jax_system()
 
     def explode(arrays, eps, **kw):
-        raise RuntimeError("LMM JAX solve did not converge (forced)")
+        raise lmm_jax.SolveError(
+            "LMM JAX solve did not converge (forced)")
     monkeypatch.setattr(lmm_jax, "solve_arrays", explode)
     before = lmm_jax.get_fallback_count()
     with pytest.raises(RuntimeError, match="did not converge"):

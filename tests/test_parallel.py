@@ -135,3 +135,22 @@ def test_sharded_100k_flows_matches_single_device():
     from simgrid_tpu.parallel.sharded import assert_sharded_matches_at_scale
     msg = assert_sharded_matches_at_scale(8)
     assert "8 devices" in msg
+
+
+def test_graft_entry_script_runs_on_the_devices_that_are_there():
+    """`python __graft_entry__.py` touches JAX for entry() and then
+    dry-runs the mesh step in the SAME process: the dry run has to use
+    the devices that process already has (here 8 virtual CPU ones)."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    proc = subprocess.run([sys.executable, "__graft_entry__.py"], cwd=root,
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "entry OK" in proc.stdout
+    assert "dryrun_multichip OK on 8 devices" in proc.stdout

@@ -120,6 +120,10 @@ class TestPlanCacheWarmRestart:
         assert warm.misses == 0
         assert warm.compile_ms == 0.0
         assert warm.disk_hits > 0
+        # ... and the deserialized executables actually RAN (on the
+        # 8-device test backend they used to fail on first call and
+        # be answered by the traced jit)
+        assert warm.fallbacks == 0
         for a, b in zip(t_cold, t_warm):
             assert a.result.events == b.result.events
             assert a.result.t == b.result.t

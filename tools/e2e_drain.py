@@ -17,7 +17,7 @@ Usage:
   python tools/e2e_drain.py --backend native|jax [--platform cpu|tpu]
          [--workload random|alltoall] [--flows 100000] [--ranks 320]
          [--fused] [--superstep K]
-         [--out bench_results/e2e_drain.jsonl] [--events-out FILE.npz]
+         [--out FILE.jsonl] [--events-out FILE.npz]
 
 `--fused` runs the jax drain with the single-dispatch solve+advance
 kernel (1 sync/advance); `--superstep K` batches K advances per
@@ -41,6 +41,7 @@ import argparse
 import json
 import os
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
@@ -56,7 +57,8 @@ def build_system(workload: str, flows: int, ranks: int, size: float):
     from tools.scale_proof import build_platform
 
     t0 = time.perf_counter()
-    platform = build_platform("/tmp/dragonfly65k.xml", 65536)
+    platform = build_platform(
+        os.path.join(tempfile.gettempdir(), "dragonfly65k.xml"), 65536)
     e = s4u.Engine(["e2e", "--cfg=lmm/backend:list",
                     "--cfg=network/maxmin-selective-update:no",
                     "--cfg=network/optim:Full"])
@@ -106,13 +108,17 @@ def build_system(workload: str, flows: int, ranks: int, size: float):
                                    flows=len(actions))
 
 
-def drain_native(arrays, slot_flow, size, done_eps=1e-4):
+def drain_native(arrays, slot_flow, size, done_eps=1e-4,
+                 min_events=None):
     """Reference-architecture baseline: the exact C++ maxmin list
     solver (native/lmm.cc) drives the same drain loop.  Per advance the
     live system is repacked with vectorized numpy (cheap next to the
     solve) so the C++ solver only ever sees live flows — the same
     favor the JAX path gets from its repacks.  Completion grouping is
-    relative (done_eps * size), matching DrainSim's default rule."""
+    relative (done_eps * size), matching DrainSim's default rule.
+    ``min_events`` stops the drain after the advance that reaches that
+    many completions (a window of the drain, for comparison with a
+    windowed device run); None drains to completion."""
     import numpy as np
     from simgrid_tpu.ops import lmm_native
 
@@ -130,7 +136,8 @@ def drain_native(arrays, slot_flow, size, done_eps=1e-4):
     events = []
     advances = 0
     t0 = time.perf_counter()
-    while live.any():
+    while live.any() and (min_events is None
+                          or len(events) < min_events):
         keep = np.flatnonzero(live)
         old2new = np.full(n_v, -1, np.int32)
         old2new[keep] = np.arange(len(keep), dtype=np.int32)
@@ -162,6 +169,53 @@ def drain_native(arrays, slot_flow, size, done_eps=1e-4):
                         t_sim=t)
 
 
+def compare_events(ref, got, window=2e-4):
+    """Hold a drain's completion events to a reference drain's, by the
+    rule of tests/test_event_order_parity.py, on whatever part of the
+    drain both cover: same completion order, flows finishing in the
+    same advance (equal dates) being one unordered group; an inversion
+    is admitted only between flows whose reference dates lie within
+    ``window`` relative (2x the relative completion threshold: f32
+    error plus the grouping rule) and inversions stay under 1 % of the
+    events; every date agrees within ``window``; and a flow only one
+    side has finished must sit at the end of the compared window.
+    Raises AssertionError on a breach, else returns the census."""
+    t_ref = {fid: t for t, fid in ref}
+    t_got = {fid: t for t, fid in got}
+    assert len(t_ref) == len(ref) and len(t_got) == len(got), \
+        "a flow completed twice"
+    common = [fid for _, fid in got if fid in t_ref]
+    assert common, "no completion event in common"
+    horizon = min(ref[-1][0], got[-1][0])
+    edge = [fid for fid in set(t_ref) ^ set(t_got)
+            if (t_ref[fid] if fid in t_ref else t_got[fid])
+            < horizon * (1 - window)]
+    assert not edge, (f"{len(edge)} flow(s) finished well inside the "
+                      f"window on one side only, e.g. {sorted(edge)[:5]}")
+    worst = max(abs(t_got[f] - t_ref[f]) / t_ref[f] for f in common)
+    assert worst < window, \
+        f"completion dates differ by {worst:.3e} relative (>= {window})"
+    # walk got in order, same-date groups sorted by reference date
+    inversions = 0
+    high = 0.0
+    order = sorted(range(len(common)),
+                   key=lambda i: (t_got[common[i]], t_ref[common[i]]))
+    for i in order:
+        t = t_ref[common[i]]
+        if t < high:
+            inversions += 1
+            assert t > high * (1 - window), (
+                f"flow {common[i]} finished out of order: reference "
+                f"date {t!r} after a flow dated {high!r}")
+        high = max(high, t)
+    assert inversions < max(1, len(common) // 100), \
+        f"{inversions} order inversions in {len(common)} events"
+    return dict(events_compared=len(common),
+                events_ref=len(ref), events_got=len(got),
+                same_sequence=[f for _, f in ref if f in t_got] == common,
+                inversions=inversions, max_rel_date_err=worst)
+
+
 def drain_jax(arrays, slot_flow, size, platform=None, done_eps=1e-4,
               fused=False, superstep=0, pipeline=0):
     import numpy as np
@@ -170,10 +224,11 @@ def drain_jax(arrays, slot_flow, size, platform=None, done_eps=1e-4,
         jax.config.update("jax_platforms", platform)
     import jax
     from simgrid_tpu.ops import opstats
+    from simgrid_tpu.ops.device import solve_dtype
     from simgrid_tpu.ops.lmm_drain import DrainSim
 
     dev = jax.devices()[0]
-    dtype = np.float32 if dev.platform != "cpu" else np.float64
+    dtype = solve_dtype(None, "e2e_drain")
     E = arrays.n_elem
     sim = DrainSim(arrays.e_var[:E], arrays.e_cnst[:E],
                    arrays.e_w[:E].astype(dtype),

@@ -16,22 +16,34 @@ import argparse
 import os
 import resource
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def build_platform(path: str, n_hosts: int) -> str:
-    # dragonfly hosts = groups * chassis * routers * nodes;
-    # minimal routing needs routers-per-chassis >= groups:
-    # 16 * 4 * 16 * 64 = 65536
-    assert n_hosts == 65536, "layout below is sized for 65536 hosts"
-    xml = """<?xml version='1.0'?>
+#: BASELINE config #4's dragonfly: 16 groups x 4 chassis x 16 routers x
+#: 64 nodes = 65,536 hosts
+CONFIG4_TOPO = "16,3;4,2;16,2;64"
+
+
+def build_platform(path: str, n_hosts: int = 65536,
+                   topo: str = CONFIG4_TOPO) -> str:
+    """Write a one-cluster dragonfly platform.  ``topo`` is
+    "groups,links;chassis,links;routers,links;nodes"; hosts = groups *
+    chassis * routers * nodes, and minimal routing needs
+    routers-per-chassis >= groups."""
+    dims = [int(part.split(",")[0]) for part in topo.split(";")]
+    if dims[0] * dims[1] * dims[2] * dims[3] != n_hosts:
+        raise ValueError(f"topo {topo!r} has "
+                         f"{dims[0] * dims[1] * dims[2] * dims[3]} hosts, "
+                         f"not {n_hosts}")
+    xml = f"""<?xml version='1.0'?>
 <platform version="4.1">
   <zone id="world" routing="Full">
-    <cluster id="dfly" prefix="node-" radical="0-65535" suffix=""
+    <cluster id="dfly" prefix="node-" radical="0-{n_hosts - 1}" suffix=""
              speed="1Gf" bw="125MBps" lat="50us" topology="DRAGONFLY"
-             topo_parameters="16,3;4,2;16,2;64"/>
+             topo_parameters="{topo}"/>
   </zone>
 </platform>
 """
@@ -62,7 +74,8 @@ def main() -> None:
         lines.append(msg)
 
     t0 = time.perf_counter()
-    platform = build_platform("/tmp/dragonfly65k.xml", 65536)
+    platform = build_platform(
+        os.path.join(tempfile.gettempdir(), "dragonfly65k.xml"), 65536)
     e = s4u.Engine(["scale", f"--cfg=lmm/backend:{args.backend}",
                     f"--cfg=lmm/layout:{args.layout}",
                     "--cfg=network/maxmin-selective-update:no",
