@@ -1,0 +1,306 @@
+"""The reductions that read the program's own names: the wire decoder of
+``lib/xmeta.py`` against ``ProfileData`` on the recorded traces, device
+self time by ``sg.*`` scope and idle time by ``sg:`` span
+(``lib/scopes.py``), and the new readers on a run with nothing to read.
+
+Two recorded traces, both one window of ``tiny128-random.drain`` on the
+v5e: ``tiny_drain`` from before the program named anything (PR 26), and
+``tiny_drain_scoped``, recorded with ``benchmarks/tools/passes.py
+--keep`` after ISSUE 27 put ``jax.named_scope`` on the passes and
+``opstats.span`` on the host steps.
+"""
+
+import importlib.util
+import lzma
+import os
+import struct
+import types
+
+import pytest
+
+from lib import manifest as mf, scopes, trace, xmeta
+
+FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
+SUPERSTEP = "jit__superstep_program"
+LMM = ["sg.lmm." + p for p in ("init", "neighmin", "level", "update",
+                               "prune")]
+DRAIN = ["sg.drain." + p for p in ("solve", "advance", "ring", "pack")]
+#: what XLA inserts on its own, with no op-name path to inherit
+XLA_MADE = {"copy-start", "copy-done", "data formatting", "broadcast",
+            "while", "custom-call"}
+NEW_READERS = [m["name"] for m in mf.load_manifest()["per_layer"]
+               if m["name"].split(".")[0] == "setup"
+               or m["name"] in ("drain.issue_ms", "drain.demux_ms",
+                                "drain.idle_unnamed_pct",
+                                "solve.host_block_pct",
+                                "drain.compiles_in_window",
+                                "solve.compiles_in_window")]
+
+
+def unpacked(tmp_path_factory, name):
+    path = tmp_path_factory.mktemp(name) / (name + ".xplane.pb")
+    with lzma.open(os.path.join(FIXTURES, name + ".xplane.pb.xz")) as f:
+        path.write_bytes(f.read())
+    return str(path)
+
+
+@pytest.fixture(scope="module", params=["tiny_drain", "tiny_drain_scoped"])
+def either(request, tmp_path_factory):
+    path = unpacked(tmp_path_factory, request.param)
+    return xmeta.read(path), trace.read_xplane(path)
+
+
+@pytest.fixture(scope="module", params=["tiny_drain", "tiny_drain_scoped"])
+def either_summary(request, tmp_path_factory):
+    path = unpacked(tmp_path_factory, request.param)
+    return xmeta.read(path), trace.TraceSummary(trace.read_xplane(path))
+
+
+@pytest.fixture(scope="module")
+def plain(tmp_path_factory):
+    path = unpacked(tmp_path_factory, "tiny_drain")
+    return xmeta.read(path), trace.TraceSummary(trace.read_xplane(path))
+
+
+@pytest.fixture(scope="module")
+def scoped(tmp_path_factory):
+    path = unpacked(tmp_path_factory, "tiny_drain_scoped")
+    return xmeta.read(path), trace.TraceSummary(trace.read_xplane(path))
+
+
+# -- the wire decoder ----------------------------------------------------
+
+def test_decoder_reads_the_events_profiledata_reads(either):
+    meta, planes = either
+    assert sum(len(evs) for lines in planes.values()
+               for evs in lines.values()) > 5000
+    for plane, lines in planes.items():
+        assert set(lines) == set(meta[plane].line_names)
+        for line, events in lines.items():
+            assert events == [(meta[plane].names[op.metadata_id],
+                               op.start_ns, op.end_ns)
+                              for op in meta[plane].ops(line)], (plane, line)
+
+
+def test_decoder_recovers_the_op_name_path_of_every_lowered_op(either):
+    meta, _planes = either
+    device = meta["/device:TPU:0"]
+    ids = {op.metadata_id for op in device.ops(trace.OPS_LINE)}
+    assert len(ids) > 100
+    for i in ids:
+        path = device.stat_of(i, "tf_op")
+        category = device.stat_of(i, "hlo_category")
+        assert isinstance(category, str) and category
+        assert isinstance(device.stat_of(i, "bytes_accessed"), int)
+        if path is None:
+            assert category in XLA_MADE, device.names[i]
+        else:                 # a jitted op's path, or an argument's name
+            assert path.endswith(":")
+            assert path.startswith("jit(") or "/" not in path, path
+    assert sum(device.stat_of(i, "tf_op") is not None for i in ids) \
+        > 0.8 * len(ids)
+
+
+def test_wire_primitives():
+    buf = memoryview(bytes([0x08, 0xAC, 0x02]) + bytes([0x12, 0x02])
+                     + b"hi" + bytes([0x19]) + struct.pack("<d", 1.5))
+    assert [(n, w, bytes(v) if w == xmeta.BYTES else v)
+            for n, w, v in xmeta.fields(buf)] == [
+        (1, xmeta.VARINT, 300), (2, xmeta.BYTES, b"hi"),
+        (3, xmeta.FIXED64, struct.pack("<d", 1.5))]
+    assert xmeta.signed((1 << 64) - 5) == -5 and xmeta.signed(7) == 7
+    with pytest.raises(ValueError):
+        list(xmeta.fields(memoryview(bytes([0x0B]))))   # a group: wire 3
+    # a stat by value and by reference into the stat names
+    names = {1: "tf_op", 2: "jit(f)/sg.lmm.init/mul:"}
+    assert xmeta.stat(memoryview(bytes([0x08, 1, 0x38, 2])), names) == (
+        "tf_op", "jit(f)/sg.lmm.init/mul:")
+    assert xmeta.stat(memoryview(bytes([0x08, 1, 0x2A, 1]) + b"x"),
+                      names) == ("tf_op", "x")
+
+
+# -- device time by scope ------------------------------------------------
+
+@pytest.mark.parametrize("path,scope", [
+    ("jit(f)/while/body/sg.drain.solve/while/body/sg.lmm.update/add:",
+     "sg.lmm.update"),
+    ("jit(f)/while/body/sg.drain.ring/scatter:", "sg.drain.ring"),
+    ("jit(f)/while/cond/lt:", scopes.UNSCOPED),
+    ("jit(f)/msg.lmm.x/add:", scopes.UNSCOPED),
+    ("", scopes.UNSCOPED), (None, scopes.UNSCOPED)])
+def test_innermost_scope(path, scope):
+    assert scopes.innermost_scope(path) == scope
+
+
+def test_self_time_by_op_is_what_trace_self_times_reads(either_summary):
+    """The decoder's events under ``trace.self_times``' own nesting give,
+    per op, what ProfileData's events give inside the window."""
+    meta, summary = either_summary
+    by_name = {}
+    for (_program, _scope, name), s in scopes.device_scopes(
+            meta, summary).by_op.items():
+        by_name[name] = by_name.get(name, 0.0) + s
+    inside = [(n, max(a, summary.lo), min(b, summary.hi))
+              for n, a, b in summary.planes[summary.devices[0]][
+                  trace.OPS_LINE] if b > summary.lo and a < summary.hi]
+    want = trace.self_times(inside)
+    assert set(by_name) == set(want)
+    for name, ns in want.items():
+        assert by_name[name] == pytest.approx(ns / 1e9, rel=1e-9, abs=1e-12)
+
+
+def test_a_trace_without_scopes_is_all_unscoped(plain):
+    meta, summary = plain
+    got = scopes.device_scopes(meta, summary)
+    assert {scope for _program, scope in got.by} == {scopes.UNSCOPED}
+    assert sum(got.scopes(SUPERSTEP).values()) == pytest.approx(
+        summary.busy_s, rel=1e-9)
+
+
+def test_every_scope_has_device_time_and_they_add_up_to_busy(scoped):
+    meta, summary = scoped
+    got = scopes.device_scopes(meta, summary)
+    by = got.scopes(SUPERSTEP)
+    assert set(LMM + DRAIN) <= set(by), sorted(by)
+    assert all(by[s] > 0 for s in LMM + DRAIN)
+    # the superstep's scopes and its unscoped rest are the whole of the
+    # superstep's busy time (ops of one core never overlap but by nesting)
+    module_s, runs = summary.module_seconds(SUPERSTEP)
+    assert runs >= 1
+    assert sum(by.values()) <= module_s
+    everything = sum(got.by.values())
+    assert everything == pytest.approx(summary.busy_s, rel=1e-9)
+    # the round's passes are most of a drain's device time
+    passes = sum(by[s] for s in LMM if s != "sg.lmm.init")
+    assert passes > 0.5 * sum(by.values())
+    program, scope, op, seconds = got.top_ops(3)[0]
+    assert program == SUPERSTEP and scope in LMM and op.startswith("%")
+    assert seconds == max(got.by_op.values())
+
+
+# -- idle time by host span ----------------------------------------------
+
+def test_innermost_segments():
+    spans = [("sg:a", 0, 100), ("sg:b", 10, 40), ("sg:c", 20, 30),
+             ("sg:d", 120, 130), ("sg:e", 125, 160)]
+    assert scopes.innermost_segments(spans) == [
+        (0, 10, "sg:a"), (10, 20, "sg:b"), (20, 30, "sg:c"),
+        (30, 40, "sg:b"), (40, 100, "sg:a"), (120, 125, "sg:d"),
+        (125, 130, "sg:e")]      # e outlasts d: cut at d's end
+
+
+def summary_of(ops, notes, window=(0, 1000)):
+    return trace.TraceSummary({
+        "/device:TPU:0": {trace.OPS_LINE: ops, trace.MODULES_LINE: []},
+        "/host:CPU": {"python": notes + [
+            (trace.WINDOW, window[0], window[1])]}})
+
+
+def test_idle_goes_to_the_innermost_span_open_at_the_time():
+    s = summary_of(
+        ops=[("%a", 100, 300), ("%b", 500, 800)],
+        notes=[("sg:drain.collect", 250, 620), ("sg:fetch", 260, 520),
+               ("sg:drain.demux", 530, 600), ("bench:lap", 0, 1000)])
+    assert scopes.idle_by_span(s) == {
+        "fetch": 200, scopes.UNNAMED: 100 + 200}
+    s = summary_of(ops=[("%a", 100, 900)], notes=[])
+    assert scopes.idle_by_span(s) == {scopes.UNNAMED: 200}
+
+
+def test_recorded_idle_time_is_named_by_the_programs_spans(scoped, plain):
+    _meta, summary = scoped
+    by = scopes.idle_by_span(summary)
+    idle_ns = round((summary.window_s - summary.busy_s) * 1e9)
+    assert sum(by.values()) == pytest.approx(idle_ns, abs=2)
+    assert {n[len(scopes.HOST_PREFIX):] for n, _a, _b
+            in scopes.host_spans(summary)} >= {
+        "drain.init", "drain.issue", "drain.collect", "fetch",
+        "drain.demux"}
+    assert by["fetch"] > 0 and by[scopes.UNNAMED] < sum(by.values())
+    _meta, old = plain
+    assert set(scopes.idle_by_span(old)) == {scopes.UNNAMED}
+
+
+# -- the readers ----------------------------------------------------------
+
+def fake_run(window_from=float("inf"), trace_summary=None):
+    return types.SimpleNamespace(
+        trace=trace_summary, counters={}, record={"wall_s": 1.0},
+        spans=types.SimpleNamespace(window_from=window_from))
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_reader_finds_nothing_in_a_program_without_the_facility(
+        name, monkeypatch, plain):
+    from simgrid_tpu.ops import opstats
+    monkeypatch.delattr(opstats, "spans")
+    monkeypatch.delattr(opstats, "span")
+    monkeypatch.setattr(opstats, "_counters", {})
+    assert len(NEW_READERS) == 11
+    run = fake_run(trace_summary=plain[1])    # a trace, but no facility
+    assert mf.load_module("metrics", name).read(run) is None
+
+
+def test_readers_cut_the_programs_spans_at_the_window(monkeypatch):
+    from simgrid_tpu.ops import opstats
+    rows = [opstats.Span("platform.load", 1.0, 3.0, None, None, 1),
+            opstats.Span("xla.compile", 4.0, 4.5, None, "jit(f)", 2),
+            opstats.Span("drain.issue", 5.0, 5.25, None, 0, 3),
+            opstats.Span("drain.issue", 11.0, 11.001, None, 1, 4),
+            opstats.Span("drain.demux", 12.0, 12.002, 5, 1, 6),
+            opstats.Span("drain.issue", 13.0, 13.003, None, 2, 7)]
+    monkeypatch.setattr(opstats, "spans", lambda: rows)
+    monkeypatch.setattr(opstats, "_counters", {"post_ms": 2500.0})
+    run = fake_run(window_from=10.0)
+    run.counters = {"post_ms": 500.0, "host_block_ms": 250.0}
+
+    def read(name):
+        return mf.load_module("metrics", name).read(run)
+
+    assert read("setup.parse_s") == 2.0
+    assert read("setup.compile_s") == 0.5
+    assert read("setup.lmm_flatten_s") is None          # no such span
+    assert read("setup.post_s") == 2.0
+    assert read("setup.post_us_per_flow") is None       # no flow counted
+    opstats._counters["flows_posted"] = 1000
+    assert read("setup.post_us_per_flow") == pytest.approx(2000.0)
+    run.counters["flows_posted"] = 200                   # the window's own
+    assert read("setup.post_us_per_flow") == pytest.approx(2500.0)
+    assert read("drain.issue_ms") == pytest.approx(2.0)  # warm-up's left out
+    assert read("drain.demux_ms") == pytest.approx(2.0)
+    assert read("drain.compiles_in_window") == 0
+    assert read("solve.host_block_pct") == 25.0
+    rows.append(opstats.Span("xla.compile", 14.0, 15.0, 7, "jit(g)", 8))
+    assert read("solve.compiles_in_window") == 1
+    assert read("setup.compile_s") == 0.5
+
+
+def test_idle_unnamed_reader(scoped, plain):
+    read = mf.load_module("metrics", "drain.idle_unnamed_pct").read
+    assert read(fake_run()) is None
+    assert 0 <= read(fake_run(trace_summary=scoped[1])) < 100
+    # spans opened, none in the trace: a reading (the facility failed)
+    assert read(fake_run(trace_summary=plain[1])) == 100.0
+
+
+# -- the tool's arithmetic -------------------------------------------------
+
+def test_passes_breakdown_accounts_for_the_module(scoped):
+    spec = importlib.util.spec_from_file_location(
+        "bench_tools_passes", os.path.join(mf.BENCH, "tools", "passes.py"))
+    passes = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(passes)
+    meta, summary = scoped
+    run = fake_run(trace_summary=summary)
+    run.cell = types.SimpleNamespace(traffic={"driver": "drain"})
+    run.counters = {"fixpoint_rounds": 10}
+    run.record = {"advances": 2}
+    got = passes.breakdown(run, scopes.device_scopes(meta, summary))
+    assert got["scopes_over_module"] == pytest.approx(1.0, abs=0.03)
+    accounted = (sum(got["round_ms"].values()) * 10
+                 + (got["solve_init_ms"] + got["retire_ms"]) * 2) / 1e3
+    assert accounted == pytest.approx(sum(got["scope_s"].values()),
+                                      rel=1e-9)
+    run.counters = {}
+    assert passes.breakdown(run, scopes.device_scopes(meta, summary)) \
+        is None

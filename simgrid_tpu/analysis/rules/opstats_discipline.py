@@ -19,6 +19,13 @@ This is a project-level rule (one pass over every linted file):
 * a declared exact counter that no linted file ever bumps → finding at
   its docstring bullet.  Wildcard families are exempt (their members
   are data-dependent).
+
+The same docstring's "Spans" section is the registry of host spans,
+one ``* ``dotted.name`` — description`` bullet each, held to the
+``span()`` sites the same way: ``span("x")`` with ``x`` undeclared, or
+``span(<non-literal>)``, is a finding at the call site (a span name
+has no families: a reader finds spans by exact name), and a declared
+span that no linted file opens is a finding at its bullet.
 """
 
 from __future__ import annotations
@@ -35,6 +42,10 @@ OPSTATS_PATH = "simgrid_tpu/ops/opstats.py"
 _TABLE_END = "Counters only ever increase"
 
 _TOKEN = re.compile(r"``([A-Za-z0-9_]+(?:<[A-Za-z_.]+>)?)``")
+
+#: the span table: from this underlined heading to the docstring's end
+_SPANS_HEAD = re.compile(r"^Spans\n-+\n", re.M)
+_SPAN_BULLET = re.compile(r"^\* ``([a-z0-9_.]+)``\s+—")
 
 
 def declared_counters(doc: str) -> Tuple[Dict[str, int],
@@ -68,6 +79,21 @@ def declared_counters(doc: str) -> Tuple[Dict[str, int],
     return exact, wild
 
 
+def declared_spans(doc: str) -> Dict[str, int]:
+    """Span name -> docstring line, from the "Spans" section's bullet
+    heads ({} when the docstring has no such section)."""
+    head = _SPANS_HEAD.search(doc)
+    if head is None:
+        return {}
+    first = doc[:head.end()].count("\n")
+    out: Dict[str, int] = {}
+    for i, raw in enumerate(doc[head.end():].splitlines()):
+        m = _SPAN_BULLET.match(raw.strip())
+        if m:
+            out.setdefault(m.group(1), first + i + 1)
+    return out
+
+
 def _const_prefix(node: ast.AST) -> Optional[str]:
     """The leading constant string of a counter-name expression, or
     None when there isn't one.  ("abc" -> "abc"; f"abc{x}" -> "abc";
@@ -93,9 +119,19 @@ def _is_bump(ctx: FileContext, node: ast.Call) -> bool:
     return ctx.path == OPSTATS_PATH and dotted == "bump"
 
 
+def _is_span(ctx: FileContext, node: ast.Call) -> bool:
+    dotted = ctx.imports.resolve(node.func)
+    if ImportMap.matches(dotted, "simgrid_tpu.ops.opstats.span"):
+        return True
+    # inside opstats.py: span() by its local name, and the records
+    # note_compile() builds directly
+    return ctx.path == OPSTATS_PATH and dotted in ("span", "Span")
+
+
 class OpstatsDisciplineRule:
     id = "opstats-discipline"
-    doc = "bump() sites and the opstats docstring registry must agree"
+    doc = ("bump() and span() sites and the opstats docstring registry "
+           "must agree")
 
     def applies(self, relpath: str) -> bool:
         return False            # project-level only
@@ -111,19 +147,39 @@ class OpstatsDisciplineRule:
         doc = ast.get_docstring(registry_ctx.tree) or ""
         exact, wild = declared_counters(doc)
 
+        spans = declared_spans(doc)
+
         out: List[Finding] = []
         bumped: set = set()     # literal names seen
         prefixes: set = set()   # dynamic prefixes seen
+        opened: set = set()     # literal span names seen
 
         for ctx in ctxs:
             if not (ctx.path.startswith("simgrid_tpu/")
                     or ctx.path.startswith("tools/")):
                 continue
             for node in ast.walk(ctx.tree):
-                if not (isinstance(node, ast.Call)
-                        and _is_bump(ctx, node) and node.args):
+                if not (isinstance(node, ast.Call) and node.args):
                     continue
                 arg = node.args[0]
+                if _is_span(ctx, node):
+                    if isinstance(arg, ast.Constant) \
+                            and isinstance(arg.value, str):
+                        opened.add(arg.value)
+                        if arg.value not in spans:
+                            out.append(ctx.finding(
+                                self.id, node,
+                                f"span {arg.value!r} is opened here but "
+                                f"not declared in the {OPSTATS_PATH} "
+                                f"docstring's span table"))
+                    elif ctx.path != OPSTATS_PATH:
+                        out.append(ctx.finding(
+                            self.id, node,
+                            "span name is not a literal — a reader "
+                            "finds spans by exact name; use a literal"))
+                    continue
+                if not _is_bump(ctx, node):
+                    continue
                 if isinstance(arg, ast.Constant) \
                         and isinstance(arg.value, str):
                     name = arg.value
@@ -164,4 +220,11 @@ class OpstatsDisciplineRule:
                 f"counter {name!r} is declared in the docstring table "
                 f"but never bumped by any linted file",
                 registry_ctx.snippet(line)))
+        for name, line in sorted(spans.items()):
+            if name not in opened:
+                out.append(Finding(
+                    self.id, OPSTATS_PATH, line, 0,
+                    f"span {name!r} is declared in the docstring's span "
+                    f"table but never opened by any linted file",
+                    registry_ctx.snippet(line)))
         return out

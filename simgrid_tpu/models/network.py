@@ -13,6 +13,7 @@ weight 0.05; TCP-gamma window bound rate <= gamma/(2*RTT).
 from __future__ import annotations
 
 import math
+import time
 from typing import List, Optional
 
 from ..kernel.resource import (Action, ActionState, HeapType, Model, Resource,
@@ -321,6 +322,7 @@ class NetworkCm02Model(NetworkModel):
 
     def communicate(self, src, dst, size: float, rate: float) -> NetworkAction:
         # reference NetworkCm02Model::communicate (network_cm02.cpp:165-279)
+        t_post = time.perf_counter()
         route: List[LinkImpl] = []
         if src is dst:
             # Hosts without an explicit self-route ride the default
@@ -452,6 +454,8 @@ class NetworkCm02Model(NetworkModel):
                 self.system.expand(link.constraint, action.variable, 0.05)
 
         LinkImpl.on_communicate(action, src, dst)
+        opstats.bump("post_ms", (time.perf_counter() - t_post) * 1e3)
+        opstats.bump("flows_posted")
         return action
 
 

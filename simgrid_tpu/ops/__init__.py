@@ -22,15 +22,34 @@ cache is placed here, once (``compile_cache()`` says where, and why):
 Sub-second programs are cached too: a process starts with no compiled
 code, the drain drivers dispatch a few dozen small programs beside the
 large ones, and a lookup costs less than any compile the TPU does.
+
+The cache's key takes in the programs' metadata
+(``jax_compilation_cache_include_metadata_in_key``).  By default JAX
+strips it, so two programs that differ only in their op-name paths
+share one entry: a cache another commit filled would hand back
+executables without this commit's ``jax.named_scope`` names
+(``sg.lmm.*``, ``sg.drain.*``) and a device trace would attribute no
+time to any pass, in silence.  The price: the key then also holds each
+op's source path, line and recorded Python call stack, so an edit that
+only moves a line, a checkout at another path or another entry script
+compiles once more (43 s for the config-#4 superstep, PERF.md).
+
+Compiles are counted here too: one listener on JAX's monitoring events
+turns every program that leaves the backend-compile step into an
+``xla.compile`` span and the ``xla_compiles`` / ``xla_compile_ms``
+counters of :mod:`.opstats` (that step wraps the persistent-cache
+lookup, so a hit is a short span whose id starts with ``cached:``).
 """
 
 import os
+import threading
 from typing import Optional, Tuple
 
 import jax
 
 jax.config.update("jax_enable_x64", True)
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     _CACHE_SOURCE = "env JAX_COMPILATION_CACHE_DIR"
@@ -48,6 +67,32 @@ def compile_cache() -> Tuple[Optional[str], str]:
     """(directory or None, where that choice came from)."""
     return jax.config.jax_compilation_cache_dir, _CACHE_SOURCE
 
+
+from . import opstats  # noqa: E402
+
+#: JAX's monitoring events (jax 0.9.0: jax._src.dispatch
+#: BACKEND_COMPILE_EVENT, jax._src.compiler): the duration event closes
+#: every backend compile, persistent-cache lookups included; the plain
+#: event fires inside it when the lookup hit
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_cache_hit = threading.local()
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _cache_hit.seen = True
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        cached = getattr(_cache_hit, "seen", False)
+        _cache_hit.seen = False
+        opstats.note_compile(seconds, kw.get("fun_name"), cached)
+
+
+jax.monitoring.register_event_listener(_on_event)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 from .lmm_host import (System, Constraint, Variable, Element, SharingPolicy,  # noqa: E402
                        make_new_maxmin_system, double_update, double_positive,

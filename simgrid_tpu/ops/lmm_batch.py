@@ -695,7 +695,6 @@ def solve_arrays_batch(e_var, e_cnst, e_w, c_bound, c_fatpipe,
                 batch_w=batch_w)
         values, remaining, usage, rounds, carry = out
         opstats.bump("dispatches")
-        opstats.bump("batch_dispatches")
         rdt = values.dtype
         fetched = np.asarray(jnp.concatenate([
             jnp.stack([rounds.astype(rdt),
@@ -942,7 +941,6 @@ class BatchDrainSim:
                 "materialize_ew", _materialize_ew,
                 (self._base_ew_dev, ei_dev, ewv_dev), {})
             opstats.bump("dispatches")
-            opstats.bump("batch_dispatches")
             ew_dev = self._pin(ew_dev)
         self._dev = [self._put_shared(ev2),
                      self._put_shared(ec2), ew_dev]
@@ -972,7 +970,6 @@ class BatchDrainSim:
             "materialize", _materialize,
             (*base_dev, *payload_dev), {})
         opstats.bump("dispatches")
-        opstats.bump("batch_dispatches")
         if done_mode == "rel":
             thresh64 = self.done_eps * sz64
         else:
@@ -1134,7 +1131,6 @@ class BatchDrainSim:
         self.spec_issued = 0
         self.spec_committed = 0
         self.spec_rolled_back = 0
-        opstats.bump("batch_replicas", self.B)
 
     # -- device placement (single-device or replica-sharded) ---------------
 
@@ -1301,7 +1297,6 @@ class BatchDrainSim:
             t0_out = t0_in + packed[:, 3].astype(jnp.float64)
         self.supersteps += 1
         opstats.bump("dispatches")
-        opstats.bump("batch_dispatches")
         if speculative:
             self.spec_issued += 1
             opstats.bump("speculations_issued")
@@ -1589,7 +1584,6 @@ class BatchDrainSim:
         self._rem = self._pin(rem)
         self._thresh = self._pin(thresh)
         opstats.bump("dispatches")
-        opstats.bump("batch_dispatches")
         if self.has_tape:
             # always rewrite the lane's tape row — the previous
             # occupant may have left unfired entries behind
@@ -1625,7 +1619,6 @@ class BatchDrainSim:
             opstats.bump("uploaded_bytes_delta",
                          row_t.nbytes + row_s.nbytes + row_vd.nbytes)
             opstats.bump("dispatches")
-            opstats.bump("batch_dispatches")
         if self.has_coll:
             # the admitted lane replays the fleet's shared schedule
             # from its own t=0: fresh DAG walk state, zeroed clock
@@ -1645,7 +1638,6 @@ class BatchDrainSim:
             opstats.bump("uploaded_bytes_delta",
                          cp.nbytes + cr.nbytes + 16)
             opstats.bump("dispatches")
-            opstats.bump("batch_dispatches")
         if self.batch_w:
             # re-materialize the lane's weight row from the shared base
             # + this spec's indexed payload (clears the previous lane)
@@ -1663,13 +1655,11 @@ class BatchDrainSim:
             opstats.bump("uploaded_bytes_delta",
                          ei.nbytes + ewv.nbytes)
             opstats.bump("dispatches")
-            opstats.bump("batch_dispatches")
         self.overrides[b] = ov
         self.replicas[b] = ReplicaState(b)
         self._alive[b] = True
         self.admitted += 1
         opstats.bump("lanes_admitted")
-        opstats.bump("batch_replicas")
 
     def _rescue_fused(self, stuck: List[int]) -> None:
         self.rescues += 1
@@ -1694,7 +1684,6 @@ class BatchDrainSim:
                     n_c=self.n_c, n_v=self.n_v, chunk=chunk,
                     has_bounds=self.has_bounds, batch_w=self.batch_w)
             opstats.bump("dispatches")
-            opstats.bump("batch_dispatches")
             st = self._fetch(stats)[:, :k_live]
             for b in list(stuck):
                 if not active[b]:

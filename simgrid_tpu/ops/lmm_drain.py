@@ -351,139 +351,142 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
             cb_c = c_bound
         if has_coll:
             pred_c, ready_c = st[idx], st[idx + 1]
-        out = fixpoint(e_var, e_cnst, e_w, cb_c, fat, pen_c, v_bound,
-                       eps_c, n_c, n_v, parallel_rounds=True,
-                       carry=None, max_rounds=round_budget - rounds,
-                       return_carry=True, has_bounds=has_bounds,
-                       has_fatpipe=False)
-        carry2 = out[4]
-        r = out[3].astype(jnp.int32)
-        converged = jnp.count_nonzero(carry2[4]) == 0
-        if has_tape or has_coll:
-            # planned dt (the _advance_math front half), then the event
-            # peek: fire iff the next fault/activation date lands inside
-            # this advance (ties go to the event, and a pending event
-            # rescues an infinite dt).  Clock math in f64: the event
-            # dates are f64, so placement is exact even on f32 drains.
-            live = pen_c > 0
-            rate = jnp.where(live, carry2[0], 0.0)
-            flowing = live & (rate > 0)
-            dt_plan = jnp.min(jnp.where(
-                flowing, rem_c / jnp.where(flowing, rate, 1.0), jnp.inf))
+        with jax.named_scope("sg.drain.solve"):
+            out = fixpoint(e_var, e_cnst, e_w, cb_c, fat, pen_c, v_bound,
+                           eps_c, n_c, n_v, parallel_rounds=True,
+                           carry=None, max_rounds=round_budget - rounds,
+                           return_carry=True, has_bounds=has_bounds,
+                           has_fatpipe=False)
+            carry2 = out[4]
+            r = out[3].astype(jnp.int32)
+            converged = jnp.count_nonzero(carry2[4]) == 0
+        with jax.named_scope("sg.drain.advance"):
+            if has_tape or has_coll:
+                # planned dt (the _advance_math front half), then the event
+                # peek: fire iff the next fault/activation date lands inside
+                # this advance (ties go to the event, and a pending event
+                # rescues an infinite dt).  Clock math in f64: the event
+                # dates are f64, so placement is exact even on f32 drains.
+                live = pen_c > 0
+                rate = jnp.where(live, carry2[0], 0.0)
+                flowing = live & (rate > 0)
+                dt_plan = jnp.min(jnp.where(
+                    flowing, rem_c / jnp.where(flowing, rate, 1.0), jnp.inf))
+                if has_tape:
+                    ti = jnp.minimum(tpos, T - 1)
+                    next_ft = jnp.where(tpos < T, tape_t[ti], jnp.inf)
+                else:
+                    next_ft = jnp.asarray(jnp.inf, jnp.float64)
+                if has_coll:
+                    # collective clocks are absolute (carried across
+                    # dispatches); t0 is already folded into t_sum
+                    next_at = jnp.min(ready_c)
+                    now = t_sum.astype(jnp.float64)
+                else:
+                    next_at = jnp.asarray(jnp.inf, jnp.float64)
+                    now = t0 + t_sum.astype(jnp.float64)
+                next_t = jnp.minimum(next_ft, next_at)
+                fire = jnp.isfinite(next_t) & (
+                    next_t <= now + dt_plan.astype(jnp.float64))
+                dt = jnp.where(
+                    fire, jnp.maximum(next_t - now, 0.0).astype(dtype),
+                    dt_plan)
+                f_fire = fire & (next_ft <= next_at)
+                prod = _rounded_product(rate, dt, zero_bits)
+                rem2 = jnp.where(flowing, rem_c - prod, rem_c)
+                done = flowing & (rem2 < thresh)
+                pen2 = jnp.where(done, 0.0, pen_c)
+                rem2 = jnp.where(done, 0.0, rem2)
+            else:
+                dt, pen2, rem2, done = _advance_math(pen_c, rem_c, thresh,
+                                                     carry2[0], zero_bits)
+            ok = converged & jnp.isfinite(dt)
+
+            # Kahan clock: per-advance dts combine compensated so the f32
+            # in-dispatch clock error is O(k ulp), not O(advances) drift
+            y = dt - t_comp
+            t_new = t_sum + y
+            t_comp2 = (t_new - t_sum) - y
+
+        with jax.named_scope("sg.drain.ring"):
+            # completion ring: positions by stable slot order (cumsum), the
+            # same within-advance order the host paths emit; non-done slots
+            # scatter out-of-range and are dropped.  2D index shape: the
+            # ops/ scatter convention.
+            dcount = jnp.cumsum(done.astype(jnp.int32))
+            pos = jnp.where(done, n_ev + dcount - 1, ring_n)
+            pos2 = pos.reshape(-1, group)
+            ring_t2 = ring_t.at[pos2].set(
+                jnp.broadcast_to(t_new, pos2.shape), mode="drop")
+            ring_id2 = ring_id.at[pos2].set(ids.reshape(-1, group),
+                                            mode="drop")
+            n_done = dcount[-1]
+
             if has_tape:
-                ti = jnp.minimum(tpos, T - 1)
-                next_ft = jnp.where(tpos < T, tape_t[ti], jnp.inf)
+                # the fault fires AFTER this advance's completions (they
+                # retire AT the event date; the new capacity governs from
+                # the event onward): tagged ring entry, bound scatter, and
+                # cursor bump — all dropped when not firing
+                slot = tape_slot[ti]
+                fpos = jnp.where(f_fire, n_ev + n_done, ring_n)
+                ring_t2 = ring_t2.at[fpos].set(t_new, mode="drop")
+                ring_id2 = ring_id2.at[fpos].set(-(1 + slot), mode="drop")
+                n_new = n_ev + n_done + f_fire.astype(jnp.int32)
+                cb2 = cb_c.at[jnp.where(f_fire, slot, n_c)].set(
+                    tape_val[ti], mode="drop")
+                tpos2 = tpos + (ok & f_fire).astype(jnp.int32)
             else:
-                next_ft = jnp.asarray(jnp.inf, jnp.float64)
+                n_new = n_ev + n_done
+
             if has_coll:
-                # collective clocks are absolute (carried across
-                # dispatches); t0 is already folded into t_sum
-                next_at = jnp.min(ready_c)
-                now = t_sum.astype(jnp.float64)
-            else:
-                next_at = jnp.asarray(jnp.inf, jnp.float64)
-                now = t0 + t_sum.astype(jnp.float64)
-            next_t = jnp.minimum(next_ft, next_at)
-            fire = jnp.isfinite(next_t) & (
-                next_t <= now + dt_plan.astype(jnp.float64))
-            dt = jnp.where(
-                fire, jnp.maximum(next_t - now, 0.0).astype(dtype),
-                dt_plan)
-            f_fire = fire & (next_ft <= next_at)
-            prod = _rounded_product(rate, dt, zero_bits)
-            rem2 = jnp.where(flowing, rem_c - prod, rem_c)
-            done = flowing & (rem2 < thresh)
-            pen2 = jnp.where(done, 0.0, pen_c)
-            rem2 = jnp.where(done, 0.0, rem2)
-        else:
-            dt, pen2, rem2, done = _advance_math(pen_c, rem_c, thresh,
-                                                 carry2[0], zero_bits)
-        ok = converged & jnp.isfinite(dt)
+                # activations fire AFTER completions and any fault entry:
+                # every pending flow whose ready date is <= the event date
+                # wakes up (penalty scatter), its ready slot is consumed,
+                # and a tagged entry id = -(1 + n_c + flow_id) logs the
+                # fired successor at the (absolute) advance clock
+                a_any = fire & (next_at <= next_ft)
+                act = a_any & (ready_c <= next_t)
+                acount = jnp.cumsum(act.astype(jnp.int32))
+                apos = jnp.where(act, n_new + acount - 1, ring_n)
+                ring_t2 = ring_t2.at[apos].set(
+                    jnp.broadcast_to(t_new, apos.shape), mode="drop")
+                ring_id2 = ring_id2.at[apos].set(-(1 + n_c + ids),
+                                                 mode="drop")
+                n_new = n_new + acount[-1]
+                pen2 = jnp.where(act, jnp.asarray(1.0, dtype), pen2)
+                ready2 = jnp.where(act, jnp.inf, ready_c)
+                # DAG walk: completions decrement their successors'
+                # outstanding-predecessor counts; flows reaching zero get
+                # a ready date = completion clock + exec cost (activation
+                # happens on a LATER advance, never the completing one)
+                pred2 = pred_c.at[edge_dst].add(
+                    -jnp.take(done.astype(jnp.int32), edge_src), mode="drop")
+                newly = (pred2 <= 0) & (pred_c > 0)
+                ready2 = jnp.where(
+                    newly, t_new.astype(jnp.float64) + exec_cost, ready2)
 
-        # Kahan clock: per-advance dts combine compensated so the f32
-        # in-dispatch clock error is O(k ulp), not O(advances) drift
-        y = dt - t_comp
-        t_new = t_sum + y
-        t_comp2 = (t_new - t_sum) - y
+            adv_dt2 = adv_dt.at[adv].set(dt.astype(dtype))
+            adv_nev2 = adv_nev.at[adv].set(n_new)
 
-        # completion ring: positions by stable slot order (cumsum), the
-        # same within-advance order the host paths emit; non-done slots
-        # scatter out-of-range and are dropped.  2D index shape: the
-        # ops/ scatter convention.
-        dcount = jnp.cumsum(done.astype(jnp.int32))
-        pos = jnp.where(done, n_ev + dcount - 1, ring_n)
-        pos2 = pos.reshape(-1, group)
-        ring_t2 = ring_t.at[pos2].set(
-            jnp.broadcast_to(t_new, pos2.shape), mode="drop")
-        ring_id2 = ring_id.at[pos2].set(ids.reshape(-1, group),
-                                        mode="drop")
-        n_done = dcount[-1]
+            flag2 = jnp.where(~converged, _FLAG_BUDGET,
+                              jnp.where(jnp.isfinite(dt), _FLAG_OK,
+                                        _FLAG_STALLED)).astype(jnp.int32)
 
-        if has_tape:
-            # the fault fires AFTER this advance's completions (they
-            # retire AT the event date; the new capacity governs from
-            # the event onward): tagged ring entry, bound scatter, and
-            # cursor bump — all dropped when not firing
-            slot = tape_slot[ti]
-            fpos = jnp.where(f_fire, n_ev + n_done, ring_n)
-            ring_t2 = ring_t2.at[fpos].set(t_new, mode="drop")
-            ring_id2 = ring_id2.at[fpos].set(-(1 + slot), mode="drop")
-            n_new = n_ev + n_done + f_fire.astype(jnp.int32)
-            cb2 = cb_c.at[jnp.where(f_fire, slot, n_c)].set(
-                tape_val[ti], mode="drop")
-            tpos2 = tpos + (ok & f_fire).astype(jnp.int32)
-        else:
-            n_new = n_ev + n_done
-
-        if has_coll:
-            # activations fire AFTER completions and any fault entry:
-            # every pending flow whose ready date is <= the event date
-            # wakes up (penalty scatter), its ready slot is consumed,
-            # and a tagged entry id = -(1 + n_c + flow_id) logs the
-            # fired successor at the (absolute) advance clock
-            a_any = fire & (next_at <= next_ft)
-            act = a_any & (ready_c <= next_t)
-            acount = jnp.cumsum(act.astype(jnp.int32))
-            apos = jnp.where(act, n_new + acount - 1, ring_n)
-            ring_t2 = ring_t2.at[apos].set(
-                jnp.broadcast_to(t_new, apos.shape), mode="drop")
-            ring_id2 = ring_id2.at[apos].set(-(1 + n_c + ids),
-                                             mode="drop")
-            n_new = n_new + acount[-1]
-            pen2 = jnp.where(act, jnp.asarray(1.0, dtype), pen2)
-            ready2 = jnp.where(act, jnp.inf, ready_c)
-            # DAG walk: completions decrement their successors'
-            # outstanding-predecessor counts; flows reaching zero get
-            # a ready date = completion clock + exec cost (activation
-            # happens on a LATER advance, never the completing one)
-            pred2 = pred_c.at[edge_dst].add(
-                -jnp.take(done.astype(jnp.int32), edge_src), mode="drop")
-            newly = (pred2 <= 0) & (pred_c > 0)
-            ready2 = jnp.where(
-                newly, t_new.astype(jnp.float64) + exec_cost, ready2)
-
-        adv_dt2 = adv_dt.at[adv].set(dt.astype(dtype))
-        adv_nev2 = adv_nev.at[adv].set(n_new)
-
-        flag2 = jnp.where(~converged, _FLAG_BUDGET,
-                          jnp.where(jnp.isfinite(dt), _FLAG_OK,
-                                    _FLAG_STALLED)).astype(jnp.int32)
-
-        sel = lambda a, b: jnp.where(ok, a, b)
-        out_st = (sel(pen2, pen_c), sel(rem2, rem_c),
-                  sel(t_new, t_sum), sel(t_comp2, t_comp),
-                  jnp.where(ok, ring_t2, ring_t),
-                  jnp.where(ok, ring_id2, ring_id),
-                  jnp.where(ok, adv_dt2, adv_dt),
-                  jnp.where(ok, adv_nev2, adv_nev),
-                  sel(n_new, n_ev),
-                  adv + ok.astype(jnp.int32), rounds + r, flag2)
-        if has_tape:
-            out_st = out_st + (jnp.where(ok, cb2, cb_c),
-                               jnp.where(ok, tpos2, tpos))
-        if has_coll:
-            out_st = out_st + (jnp.where(ok, pred2, pred_c),
-                               jnp.where(ok, ready2, ready_c))
+            sel = lambda a, b: jnp.where(ok, a, b)
+            out_st = (sel(pen2, pen_c), sel(rem2, rem_c),
+                      sel(t_new, t_sum), sel(t_comp2, t_comp),
+                      jnp.where(ok, ring_t2, ring_t),
+                      jnp.where(ok, ring_id2, ring_id),
+                      jnp.where(ok, adv_dt2, adv_dt),
+                      jnp.where(ok, adv_nev2, adv_nev),
+                      sel(n_new, n_ev),
+                      adv + ok.astype(jnp.int32), rounds + r, flag2)
+            if has_tape:
+                out_st = out_st + (jnp.where(ok, cb2, cb_c),
+                                   jnp.where(ok, tpos2, tpos))
+            if has_coll:
+                out_st = out_st + (jnp.where(ok, pred2, pred_c),
+                                   jnp.where(ok, ready2, ready_c))
         return out_st
 
     zero = jnp.asarray(0, jnp.int32)
@@ -518,15 +521,16 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                            t_comp_o.astype(jnp.float64)])
     else:
         pred_o, ready_o, clk_o = coll_pred, coll_ready, coll_clk
-    n_live = jnp.count_nonzero(pen_o > 0)
-    live_elems = jnp.count_nonzero(
-        (e_w > 0) & jnp.take(pen_o > 0, e_var, fill_value=False))
-    stats = jnp.stack([rounds.astype(dtype), adv.astype(dtype),
-                       n_ev.astype(dtype), t_sum,
-                       n_live.astype(dtype), flag.astype(dtype),
-                       live_elems.astype(dtype)])
-    packed = jnp.concatenate([stats, adv_dt, adv_nev.astype(dtype),
-                              ring_t, ring_id.astype(dtype)])
+    with jax.named_scope("sg.drain.pack"):
+        n_live = jnp.count_nonzero(pen_o > 0)
+        live_elems = jnp.count_nonzero(
+            (e_w > 0) & jnp.take(pen_o > 0, e_var, fill_value=False))
+        stats = jnp.stack([rounds.astype(dtype), adv.astype(dtype),
+                           n_ev.astype(dtype), t_sum,
+                           n_live.astype(dtype), flag.astype(dtype),
+                           live_elems.astype(dtype)])
+        packed = jnp.concatenate([stats, adv_dt, adv_nev.astype(dtype),
+                                  ring_t, ring_id.astype(dtype)])
     return pen_o, rem_o, cb_o, tpos_o, pred_o, ready_o, clk_o, packed
 
 
@@ -664,12 +668,13 @@ class SuperstepToken:
     __slots__ = ("pen_in", "rem_in", "pen_out", "rem_out", "packed",
                  "k", "k_max", "want_stop", "speculative",
                  "cb_in", "cb_out", "tpos_out", "t0",
-                 "pred_out", "ready_out", "clk_out")
+                 "pred_out", "ready_out", "clk_out", "seq")
 
     def __init__(self, pen_in, rem_in, pen_out, rem_out, packed,
                  k: int, k_max: int, want_stop: int, speculative: bool,
                  cb_in=None, cb_out=None, tpos_out=None, t0=None,
-                 pred_out=None, ready_out=None, clk_out=None):
+                 pred_out=None, ready_out=None, clk_out=None,
+                 seq: Optional[int] = None):
         self.pen_in = pen_in
         self.rem_in = rem_in
         self.pen_out = pen_out
@@ -692,6 +697,9 @@ class SuperstepToken:
         self.pred_out = pred_out
         self.ready_out = ready_out
         self.clk_out = clk_out
+        # which dispatch of its sim this is (``DrainSim.supersteps`` at
+        # issue): the ``id`` its issue and collect spans share
+        self.seq = seq
 
 
 class DrainSim:
@@ -772,149 +780,150 @@ class DrainSim:
         else:
             self.superstep_rounds = 0
 
-        self._host: Optional[dict] = dict(
-            e_var=np.asarray(e_var, np.int32),
-            e_cnst=np.asarray(e_cnst, np.int32),
-            e_w=np.asarray(e_w, self.dtype))
-        self.n_c = len(c_bound)
-        self.n_v = len(sizes)
-        self._c_bound = np.asarray(c_bound, self.dtype)
-        self._sizes = np.asarray(sizes, np.float64)
-        if self.n_v >= 1 << 24 and self.dtype == np.float32:
-            raise ValueError(
-                "flow ids beyond 2^24 are not exact in the f32 "
-                "single-transfer fetch; use float64 or shard the drain")
-        # flow slot -> original flow id (survives repacks); host mirror
-        # may go stale after an on-device repack and is refetched
-        # lazily (_host_ids)
-        self._ids = np.arange(self.n_v)
-        self._ids_stale = False
+        with opstats.span("drain.init"):
+            self._host: Optional[dict] = dict(
+                e_var=np.asarray(e_var, np.int32),
+                e_cnst=np.asarray(e_cnst, np.int32),
+                e_w=np.asarray(e_w, self.dtype))
+            self.n_c = len(c_bound)
+            self.n_v = len(sizes)
+            self._c_bound = np.asarray(c_bound, self.dtype)
+            self._sizes = np.asarray(sizes, np.float64)
+            if self.n_v >= 1 << 24 and self.dtype == np.float32:
+                raise ValueError(
+                    "flow ids beyond 2^24 are not exact in the f32 "
+                    "single-transfer fetch; use float64 or shard the drain")
+            # flow slot -> original flow id (survives repacks); host mirror
+            # may go stale after an on-device repack and is refetched
+            # lazily (_host_ids)
+            self._ids = np.arange(self.n_v)
+            self._ids_stale = False
 
-        if done_mode == "rel":
-            thresh = self.done_eps * self._sizes
-        else:
-            thresh = np.full(self.n_v, self.done_eps)
-        # engine plans hand in mid-simulation state: per-slot penalties
-        # (0 = not a live flow) and already-partially-drained remains
-        pen0 = (np.asarray(penalty, self.dtype) if penalty is not None
-                else np.ones(self.n_v, self.dtype))
-        rem0 = (np.asarray(remains, self.dtype) if remains is not None
-                else self._sizes.astype(self.dtype))
-        self._pen = jax.device_put(pen0, device)
-        self._rem = jax.device_put(rem0, device)
-        self._thresh = jax.device_put(thresh.astype(self.dtype), device)
-        self._ids_dev = jax.device_put(
-            np.arange(self.n_v, dtype=np.int32), device)
-        self._dev = [jax.device_put(_to2d(self._host[k]), device)
-                     for k in ("e_var", "e_cnst", "e_w")]
-        self._cb = jax.device_put(self._c_bound, device)
-        if v_bound is not None:
-            vb = np.asarray(v_bound, self.dtype)
-            self.has_bounds = bool(np.any(vb > 0))
-        else:
-            vb = np.full(self.n_v, -1.0, self.dtype)
-            self.has_bounds = False
-        self._vb = jax.device_put(vb, device)
+            if done_mode == "rel":
+                thresh = self.done_eps * self._sizes
+            else:
+                thresh = np.full(self.n_v, self.done_eps)
+            # engine plans hand in mid-simulation state: per-slot penalties
+            # (0 = not a live flow) and already-partially-drained remains
+            pen0 = (np.asarray(penalty, self.dtype) if penalty is not None
+                    else np.ones(self.n_v, self.dtype))
+            rem0 = (np.asarray(remains, self.dtype) if remains is not None
+                    else self._sizes.astype(self.dtype))
+            self._pen = jax.device_put(pen0, device)
+            self._rem = jax.device_put(rem0, device)
+            self._thresh = jax.device_put(thresh.astype(self.dtype), device)
+            self._ids_dev = jax.device_put(
+                np.arange(self.n_v, dtype=np.int32), device)
+            self._dev = [jax.device_put(_to2d(self._host[k]), device)
+                         for k in ("e_var", "e_cnst", "e_w")]
+            self._cb = jax.device_put(self._c_bound, device)
+            if v_bound is not None:
+                vb = np.asarray(v_bound, self.dtype)
+                self.has_bounds = bool(np.any(vb > 0))
+            else:
+                vb = np.full(self.n_v, -1.0, self.dtype)
+                self.has_bounds = False
+            self._vb = jax.device_put(vb, device)
 
-        # fault event tape: `tape` is (dates, slots, values) — f64
-        # absolute sim dates (sorted), constraint slots, and the
-        # ABSOLUTE new capacity each event installs (mirroring the
-        # engine's set_bandwidth semantics, so a recovery restores the
-        # exact pre-fault bound).  Device-resident; the superstep loop
-        # clamps dt so no advance steps over an entry (see
-        # _superstep_program).
-        self.has_tape = False
-        self.fault_events: list = []     # (time, constraint slot)
-        self._tpos_host = 0              # fired-entry count (host view)
-        self._last_fired = False
-        if tape is not None and len(tape[0]):
-            tt = np.asarray(tape[0], np.float64)
-            ts = np.asarray(tape[1], np.int32)
-            tv = np.asarray(tape[2], np.float64).astype(self.dtype)
-            if not (len(tt) == len(ts) == len(tv)):
-                raise ValueError("tape arrays must have equal length")
-            if np.any(np.diff(tt) < 0):
-                raise ValueError("tape dates must be time-sorted")
-            if np.any((ts < 0) | (ts >= self.n_c)):
-                raise ValueError("tape slot out of range")
-            if not superstep:
-                raise ValueError("tape= needs superstep=K (faults fire "
-                                 "inside the superstep loop)")
-            self.has_tape = True
-            self._tape = tuple(jax.device_put(a, device)
-                               for a in (tt, ts, tv))
-            self._tpos = jax.device_put(np.int32(0), device)
-            opstats.bump("fault_tape_slots", len(tt))
-            opstats.bump("uploaded_bytes_delta",
-                         tt.nbytes + ts.nbytes + tv.nbytes)
-        else:
-            # dummy triple keeps the jit call sites uniform; with
-            # has_tape=False the program never reads it (XLA DCE)
-            self._tape = (
-                jax.device_put(np.full(1, np.inf), device),
-                jax.device_put(np.full(1, self.n_c, np.int32), device),
-                jax.device_put(np.zeros(1, self.dtype), device))
-            self._tpos = np.int32(0)
+            # fault event tape: `tape` is (dates, slots, values) — f64
+            # absolute sim dates (sorted), constraint slots, and the
+            # ABSOLUTE new capacity each event installs (mirroring the
+            # engine's set_bandwidth semantics, so a recovery restores the
+            # exact pre-fault bound).  Device-resident; the superstep loop
+            # clamps dt so no advance steps over an entry (see
+            # _superstep_program).
+            self.has_tape = False
+            self.fault_events: list = []     # (time, constraint slot)
+            self._tpos_host = 0              # fired-entry count (host view)
+            self._last_fired = False
+            if tape is not None and len(tape[0]):
+                tt = np.asarray(tape[0], np.float64)
+                ts = np.asarray(tape[1], np.int32)
+                tv = np.asarray(tape[2], np.float64).astype(self.dtype)
+                if not (len(tt) == len(ts) == len(tv)):
+                    raise ValueError("tape arrays must have equal length")
+                if np.any(np.diff(tt) < 0):
+                    raise ValueError("tape dates must be time-sorted")
+                if np.any((ts < 0) | (ts >= self.n_c)):
+                    raise ValueError("tape slot out of range")
+                if not superstep:
+                    raise ValueError("tape= needs superstep=K (faults fire "
+                                     "inside the superstep loop)")
+                self.has_tape = True
+                self._tape = tuple(jax.device_put(a, device)
+                                   for a in (tt, ts, tv))
+                self._tpos = jax.device_put(np.int32(0), device)
+                opstats.bump("fault_tape_slots", len(tt))
+                opstats.bump("uploaded_bytes_delta",
+                             tt.nbytes + ts.nbytes + tv.nbytes)
+            else:
+                # dummy triple keeps the jit call sites uniform; with
+                # has_tape=False the program never reads it (XLA DCE)
+                self._tape = (
+                    jax.device_put(np.full(1, np.inf), device),
+                    jax.device_put(np.full(1, self.n_c, np.int32), device),
+                    jax.device_put(np.zeros(1, self.dtype), device))
+                self._tpos = np.int32(0)
 
-        # collective schedule tape: `collective` is (pred, ready,
-        # edge_src, edge_dst, exec_cost) — the compiled comm DAG
-        # (collectives.tape.DeviceCollective.drain_args()).  Dormant
-        # flows (penalty 0) activate on device when their outstanding
-        # predecessor count hits zero; the superstep loop walks the
-        # whole schedule without host involvement (see
-        # _superstep_program's has_coll docs).
-        self.has_coll = False
-        self.collective_events: list = []   # (time, flow id) activations
-        if collective is not None:
-            cp, cr, ces, ced, cec = collective
-            cp = np.asarray(cp, np.int32)
-            cr = np.asarray(cr, np.float64)
-            ces = np.asarray(ces, np.int32)
-            ced = np.asarray(ced, np.int32)
-            cec = np.asarray(cec, np.float64)
-            if not (len(cp) == len(cr) == len(cec) == self.n_v):
-                raise ValueError("collective arrays must be per-flow "
-                                 f"(n_v={self.n_v})")
-            if len(ces) != len(ced):
-                raise ValueError("collective edge arrays must have "
-                                 "equal length")
-            if not superstep:
-                raise ValueError("collective= needs superstep=K (the "
-                                 "DAG walks inside the superstep loop)")
-            if self.dtype != np.float64:
-                raise ValueError("collective= needs dtype=float64 (the "
-                                 "carried Kahan clock must match the "
-                                 "host-maestro oracle bit-for-bit)")
-            self.has_coll = True
-            # a repack would scramble the DAG's static slot indexing
-            self.repack_min = 1 << 62
-            self._coll = tuple(jax.device_put(a, device)
-                               for a in (cp, cr))
-            self._coll_edges = tuple(jax.device_put(a, device)
-                                     for a in (ces, ced, cec))
-            self._coll_clk = jax.device_put(
-                np.zeros(2, np.float64), device)
-            self._coll_total = int(self.n_v)
-            opstats.bump("collective_tape_slots", self.n_v)
-            opstats.bump("uploaded_bytes_delta",
-                         cp.nbytes + cr.nbytes + ces.nbytes
-                         + ced.nbytes + cec.nbytes)
-        else:
-            self._coll = (
-                jax.device_put(np.zeros(1, np.int32), device),
-                jax.device_put(np.full(1, np.inf), device))
-            self._coll_edges = (
-                jax.device_put(np.zeros(1, np.int32), device),
-                jax.device_put(np.zeros(1, np.int32), device),
-                jax.device_put(np.zeros(1, np.float64), device))
-            self._coll_clk = jax.device_put(np.zeros(2, np.float64),
-                                            device)
-            self._coll_total = 0
+            # collective schedule tape: `collective` is (pred, ready,
+            # edge_src, edge_dst, exec_cost) — the compiled comm DAG
+            # (collectives.tape.DeviceCollective.drain_args()).  Dormant
+            # flows (penalty 0) activate on device when their outstanding
+            # predecessor count hits zero; the superstep loop walks the
+            # whole schedule without host involvement (see
+            # _superstep_program's has_coll docs).
+            self.has_coll = False
+            self.collective_events: list = []   # (time, flow id) activations
+            if collective is not None:
+                cp, cr, ces, ced, cec = collective
+                cp = np.asarray(cp, np.int32)
+                cr = np.asarray(cr, np.float64)
+                ces = np.asarray(ces, np.int32)
+                ced = np.asarray(ced, np.int32)
+                cec = np.asarray(cec, np.float64)
+                if not (len(cp) == len(cr) == len(cec) == self.n_v):
+                    raise ValueError("collective arrays must be per-flow "
+                                     f"(n_v={self.n_v})")
+                if len(ces) != len(ced):
+                    raise ValueError("collective edge arrays must have "
+                                     "equal length")
+                if not superstep:
+                    raise ValueError("collective= needs superstep=K (the "
+                                     "DAG walks inside the superstep loop)")
+                if self.dtype != np.float64:
+                    raise ValueError("collective= needs dtype=float64 (the "
+                                     "carried Kahan clock must match the "
+                                     "host-maestro oracle bit-for-bit)")
+                self.has_coll = True
+                # a repack would scramble the DAG's static slot indexing
+                self.repack_min = 1 << 62
+                self._coll = tuple(jax.device_put(a, device)
+                                   for a in (cp, cr))
+                self._coll_edges = tuple(jax.device_put(a, device)
+                                         for a in (ces, ced, cec))
+                self._coll_clk = jax.device_put(
+                    np.zeros(2, np.float64), device)
+                self._coll_total = int(self.n_v)
+                opstats.bump("collective_tape_slots", self.n_v)
+                opstats.bump("uploaded_bytes_delta",
+                             cp.nbytes + cr.nbytes + ces.nbytes
+                             + ced.nbytes + cec.nbytes)
+            else:
+                self._coll = (
+                    jax.device_put(np.zeros(1, np.int32), device),
+                    jax.device_put(np.full(1, np.inf), device))
+                self._coll_edges = (
+                    jax.device_put(np.zeros(1, np.int32), device),
+                    jax.device_put(np.zeros(1, np.int32), device),
+                    jax.device_put(np.zeros(1, np.float64), device))
+                self._coll_clk = jax.device_put(np.zeros(2, np.float64),
+                                                device)
+                self._coll_total = 0
 
-        opstats.bump("uploaded_bytes_full",
-                     pen0.nbytes + rem0.nbytes + thresh.nbytes
-                     + self._ids_dev.nbytes + self._cb.nbytes + vb.nbytes
-                     + sum(d.nbytes for d in self._dev))
+            opstats.bump("uploaded_bytes_full",
+                         pen0.nbytes + rem0.nbytes + thresh.nbytes
+                         + self._ids_dev.nbytes + self._cb.nbytes + vb.nbytes
+                         + sum(d.nbytes for d in self._dev))
         self._live0 = (int(np.count_nonzero(pen0 > 0))
                        if penalty is not None else self.n_v)
 
@@ -1260,16 +1269,18 @@ class DrainSim:
         donate = (donate and not speculative
                   and pen is None and rem is None)
         step = _drain_superstep_donate if donate else _drain_superstep
-        (pen_out, rem_out, cb_out, tpos_out, pred_out, ready_out,
-         clk_out, packed) = step(
-            *self._dev, cb_in, self._vb, pen_in, rem_in,
-            self._thresh, self._ids_dev,
-            np.int32(k), np.int32(budget), np.int32(want_stop),
-            _ZERO_BITS, *self._tape, tpos_in,
-            pred_in, ready_in, clk_in, *self._coll_edges, t0_in,
-            eps=self.eps, n_c=self.n_c, n_v=self.n_v,
-            k_max=k_max, group=group, has_bounds=self.has_bounds,
-            has_tape=self.has_tape, has_coll=self.has_coll)
+        seq = self.supersteps
+        with opstats.span("drain.issue", id=seq):
+            (pen_out, rem_out, cb_out, tpos_out, pred_out, ready_out,
+             clk_out, packed) = step(
+                *self._dev, cb_in, self._vb, pen_in, rem_in,
+                self._thresh, self._ids_dev,
+                np.int32(k), np.int32(budget), np.int32(want_stop),
+                _ZERO_BITS, *self._tape, tpos_in,
+                pred_in, ready_in, clk_in, *self._coll_edges, t0_in,
+                eps=self.eps, n_c=self.n_c, n_v=self.n_v,
+                k_max=k_max, group=group, has_bounds=self.has_bounds,
+                has_tape=self.has_tape, has_coll=self.has_coll)
         if donate:
             # the dispatch consumed the committed buffers: adopt the
             # outputs NOW so no reachable reference is left deleted
@@ -1288,7 +1299,7 @@ class DrainSim:
                               cb_in=cb_in, cb_out=cb_out,
                               tpos_out=tpos_out, t0=t0_in,
                               pred_out=pred_out, ready_out=ready_out,
-                              clk_out=clk_out)
+                              clk_out=clk_out, seq=seq)
 
     def _discard_token(self, tok: SuperstepToken) -> None:
         """Drop an un-collected speculative superstep: processing the
@@ -1311,28 +1322,71 @@ class DrainSim:
         it (no repack, no stop-trigger decay, flow set still live, the
         dispatch exited _FLAG_OK), so a speculative successor may
         commit; on False the caller must discard in-flight tokens."""
-        self._pen, self._rem = tok.pen_out, tok.rem_out
-        if self.has_tape:
-            self._cb = tok.cb_out
-            self._tpos = tok.tpos_out
-        if self.has_coll:
-            self._coll = (tok.pred_out, tok.ready_out)
-            self._coll_clk = tok.clk_out
-        k_max = tok.k_max
-        p = opstats.timed_fetch(tok.packed)
-        self.syncs += 1
-        rounds, adv, n_ev = int(p[0]), int(p[1]), int(p[2])
-        t_sum = float(p[3])
-        if np.isnan(t_sum):
-            # a poisoned scenario (e.g. NaN link capacity) makes the
-            # whole advance NaN — fail with a cause instead of
-            # committing a garbage clock/ring (the solo mirror of the
-            # fleet's nan_solve lane quarantine)
-            raise SolveError(
-                "drain solve produced a non-finite clock advance "
-                "(NaN)")
-        n_live, flag = int(p[4]), int(p[5])
-        live_elems = int(p[6])
+        with opstats.span("drain.collect", id=tok.seq):
+            self._pen, self._rem = tok.pen_out, tok.rem_out
+            if self.has_tape:
+                self._cb = tok.cb_out
+                self._tpos = tok.tpos_out
+            if self.has_coll:
+                self._coll = (tok.pred_out, tok.ready_out)
+                self._coll_clk = tok.clk_out
+            p = opstats.timed_fetch(tok.packed)
+            self.syncs += 1
+            rounds, adv = int(p[0]), int(p[1])
+            t_sum = float(p[3])
+            if np.isnan(t_sum):
+                # a poisoned scenario (e.g. NaN link capacity) makes the
+                # whole advance NaN — fail with a cause instead of
+                # committing a garbage clock/ring (the solo mirror of the
+                # fleet's nan_solve lane quarantine)
+                raise SolveError(
+                    "drain solve produced a non-finite clock advance "
+                    "(NaN)")
+            n_live, flag = int(p[4]), int(p[5])
+            live_elems = int(p[6])
+
+            self.rounds += rounds
+            opstats.bump("fixpoint_rounds", rounds)
+            self.advances += adv
+            with opstats.span("drain.demux"):
+                batches, fired = self._demux(p, adv, tok.k_max, t_sum)
+
+            if flag == _FLAG_STALLED:
+                raise SolveError(
+                    f"drain stalled: no flow holds bandwidth "
+                    f"({n_live} live)")
+            if flag == _FLAG_BUDGET and adv == 0 and rounds >= _MAX_ROUNDS:
+                raise SolveError("drain solve did not converge")
+            repacked = False
+            decayed = False
+            if self._should_repack(n_live):
+                repacked = self._repack_device(n_live, live_elems)
+            if not repacked and tok.want_stop and n_live <= tok.want_stop:
+                # the stop-for-repack threshold fired but no repack was
+                # possible (small live set / dense elements): decay the
+                # trigger so the next superstep doesn't exit immediately
+                self._live0 = max(n_live, 1)
+                decayed = True
+            self._last_flag = flag
+            if tok.speculative:
+                self.spec_committed += 1
+                opstats.bump("speculations_committed")
+            # a tape fire is a clean-collect boundary for speculation: the
+            # spec issue chained from the fired bounds (values were right),
+            # but replaying from the committed state keeps the oracle
+            # trivially aligned with the unpipelined driver
+            clean = (flag == _FLAG_OK and n_live > 0
+                     and not repacked and not decayed and not fired)
+            if self.on_batches is not None and batches:
+                self.on_batches(batches)
+        return n_live, batches, clean
+
+    def _demux(self, p: np.ndarray, adv: int, k_max: int, t_sum: float
+               ) -> Tuple[List[Tuple[float, List[int]]], int]:
+        """Replay one fetched ring into ``events`` (and the fault and
+        collective streams) and the f64 master clock; returns the
+        per-advance ``(dt, [flow ids])`` batches and how many fault
+        entries fired."""
         o = 7
         adv_dt = p[o:o + k_max]
         adv_nev = p[o + k_max:o + 2 * k_max].astype(np.int64)
@@ -1341,10 +1395,6 @@ class DrainSim:
                   + (self.n_v if self.has_coll else 0))
         ring_t = p[o:o + ring_n]
         ring_id = p[o + ring_n:o + 2 * ring_n].astype(np.int64)
-
-        self.rounds += rounds
-        opstats.bump("fixpoint_rounds", rounds)
-        self.advances += adv
         batches: List[Tuple[float, List[int]]] = []
         start = 0
         # collective rings carry ABSOLUTE dates (the Kahan clock pair is
@@ -1396,36 +1446,7 @@ class DrainSim:
         # superstep, accumulated on host in f64 (collective runs carry
         # the absolute clock on device; t_base is 0 there)
         self.t = t_base + t_sum
-
-        if flag == _FLAG_STALLED:
-            raise SolveError(
-                f"drain stalled: no flow holds bandwidth "
-                f"({n_live} live)")
-        if flag == _FLAG_BUDGET and adv == 0 and rounds >= _MAX_ROUNDS:
-            raise SolveError("drain solve did not converge")
-        repacked = False
-        decayed = False
-        if self._should_repack(n_live):
-            repacked = self._repack_device(n_live, live_elems)
-        if not repacked and tok.want_stop and n_live <= tok.want_stop:
-            # the stop-for-repack threshold fired but no repack was
-            # possible (small live set / dense elements): decay the
-            # trigger so the next superstep doesn't exit immediately
-            self._live0 = max(n_live, 1)
-            decayed = True
-        self._last_flag = flag
-        if tok.speculative:
-            self.spec_committed += 1
-            opstats.bump("speculations_committed")
-        # a tape fire is a clean-collect boundary for speculation: the
-        # spec issue chained from the fired bounds (values were right),
-        # but replaying from the committed state keeps the oracle
-        # trivially aligned with the unpipelined driver
-        clean = (flag == _FLAG_OK and n_live > 0
-                 and not repacked and not decayed and not fired)
-        if self.on_batches is not None and batches:
-            self.on_batches(batches)
-        return n_live, batches, clean
+        return batches, fired
 
     def superstep_batch(self, k: Optional[int] = None,
                         fetch: bool = True, stop_live: int = 0,

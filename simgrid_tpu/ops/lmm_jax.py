@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..utils.config import config
+from . import opstats
 from .device import default_platform, solve_dtype
 from .lmm_host import SharingPolicy, System, Constraint, Variable
 
@@ -440,30 +441,33 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     def allmin(x):
         return lax.pmin(x, axis) if axis else x
 
-    v_enabled = v_penalty > 0
-    e_valid = (e_w > 0) & jnp.take(v_enabled, e_var, fill_value=False)
-    safe_pen = jnp.where(v_enabled, v_penalty, 1.0)
-    e_upen = jnp.where(e_valid, e_w / jnp.take(safe_pen, e_var), 0.0)
+    with jax.named_scope("sg.lmm.init"):
+        v_enabled = v_penalty > 0
+        e_valid = (e_w > 0) & jnp.take(v_enabled, e_var, fill_value=False)
+        safe_pen = jnp.where(v_enabled, v_penalty, 1.0)
+        e_upen = jnp.where(e_valid, e_w / jnp.take(safe_pen, e_var), 0.0)
 
-    # Initial usage per constraint: sum for SHARED, max for FATPIPE.
-    usage_sum = allsum(jnp.zeros(n_c, dtype).at[e_cnst].add(e_upen))
-    usage_max = allmax(jnp.zeros(n_c, dtype).at[e_cnst].max(e_upen))
-    usage0 = jnp.where(c_fatpipe, usage_max, usage_sum)
+        # Initial usage per constraint: sum for SHARED, max for FATPIPE.
+        usage_sum = allsum(jnp.zeros(n_c, dtype).at[e_cnst].add(e_upen))
+        usage_max = allmax(jnp.zeros(n_c, dtype).at[e_cnst].max(e_upen))
+        usage0 = jnp.where(c_fatpipe, usage_max, usage_sum)
 
-    remaining0 = c_bound
-    # Initial light set: usage strictly positive (exact, maxmin.cpp:545) and
-    # remaining above the relative epsilon (maxmin.cpp:524).
-    light0 = (remaining0 > c_bound * eps) & (usage0 > 0)
+        remaining0 = c_bound
+        # Initial light set: usage strictly positive (exact,
+        # maxmin.cpp:545) and remaining above the relative epsilon
+        # (maxmin.cpp:524).
+        light0 = (remaining0 > c_bound * eps) & (usage0 > 0)
 
-    # Derive the initial carry from the inputs (not fresh constants) so its
-    # varying-manual-axes match the loop output under shard_map+vmap.
-    # Parked variables carry penalty=inf and inf*0.0 is NaN, so sanitize.
-    v_value0 = jnp.where(jnp.isfinite(v_penalty), v_penalty, 0.0) * 0.0
-    v_fixed0 = v_penalty < 0
+        # Derive the initial carry from the inputs (not fresh constants)
+        # so its varying-manual-axes match the loop output under
+        # shard_map+vmap.  Parked variables carry penalty=inf and
+        # inf*0.0 is NaN, so sanitize.
+        v_value0 = jnp.where(jnp.isfinite(v_penalty), v_penalty, 0.0) * 0.0
+        v_fixed0 = v_penalty < 0
 
-    if carry is None:
-        carry = (v_value0, v_fixed0, remaining0, usage0, light0,
-                 jnp.array(0, jnp.int32))
+        if carry is None:
+            carry = (v_value0, v_fixed0, remaining0, usage0, light0,
+                     jnp.array(0, jnp.int32))
     start_it = carry[5]
     if max_rounds is None:
         max_rounds = _MAX_ROUNDS
@@ -477,50 +481,65 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
         """Shared round tail: write fixed values, batched double_update of
         every touched constraint, epsilon-based light-set pruning."""
         v_value, v_fixed, remaining, usage, light, it = state
-        v_value = jnp.where(fix_now, new_value, v_value)
-        v_fixed = v_fixed | fix_now
+        with jax.named_scope("sg.lmm.update"):
+            v_value = jnp.where(fix_now, new_value, v_value)
+            v_fixed = v_fixed | fix_now
 
-        # Batched double_update on every constraint touched by fixed vars.
-        e_fix = e_valid & jnp.take(fix_now, e_var)
-        d_rem = allsum(jnp.zeros(n_c, dtype).at[e_cnst].add(
-            jnp.where(e_fix, e_w * jnp.take(v_value, e_var), 0.0)))
-        d_use = allsum(jnp.zeros(n_c, dtype).at[e_cnst].add(
-            jnp.where(e_fix, e_upen, 0.0)))
+            # Batched double_update on every constraint touched by fixed
+            # vars.
+            e_fix = e_valid & jnp.take(fix_now, e_var)
+            d_rem = allsum(jnp.zeros(n_c, dtype).at[e_cnst].add(
+                jnp.where(e_fix, e_w * jnp.take(v_value, e_var), 0.0)))
+            d_use = allsum(jnp.zeros(n_c, dtype).at[e_cnst].add(
+                jnp.where(e_fix, e_upen, 0.0)))
 
-        new_remaining = remaining - d_rem
-        new_remaining = jnp.where(new_remaining < c_bound * eps, 0.0, new_remaining)
-        new_usage_sum = usage - d_use
-        new_usage_sum = jnp.where(new_usage_sum < eps, 0.0, new_usage_sum)
+            new_remaining = remaining - d_rem
+            new_remaining = jnp.where(new_remaining < c_bound * eps, 0.0,
+                                      new_remaining)
+            new_usage_sum = usage - d_use
+            new_usage_sum = jnp.where(new_usage_sum < eps, 0.0,
+                                      new_usage_sum)
 
-        e_live2 = e_valid & ~jnp.take(v_fixed, e_var)
-        touched = allmax(jnp.zeros(n_c, dtype=bool).at[e_cnst].max(e_fix))
-        if has_fatpipe:
-            # FATPIPE: usage is re-derived as the max over unset variables.
-            new_usage_max = allmax(jnp.zeros(n_c, dtype).at[e_cnst].max(
-                jnp.where(e_live2, e_upen, 0.0)))
-            new_usage = jnp.where(c_fatpipe, new_usage_max, new_usage_sum)
-            usage = jnp.where(touched, new_usage, usage)
-            remaining = jnp.where(touched & ~c_fatpipe, new_remaining,
-                                  remaining)
-        else:
-            # static specialization (host-checked): no FATPIPE constraint
-            # in the system, so the max-usage recompute drops out
-            usage = jnp.where(touched, new_usage_sum, usage)
-            remaining = jnp.where(touched, new_remaining, remaining)
+        with jax.named_scope("sg.lmm.prune"):
+            e_live2 = e_valid & ~jnp.take(v_fixed, e_var)
+            touched = allmax(
+                jnp.zeros(n_c, dtype=bool).at[e_cnst].max(e_fix))
+            if has_fatpipe:
+                # FATPIPE: usage is re-derived as the max over unset
+                # variables.
+                new_usage_max = allmax(jnp.zeros(n_c, dtype).at[e_cnst].max(
+                    jnp.where(e_live2, e_upen, 0.0)))
+        with jax.named_scope("sg.lmm.update"):
+            if has_fatpipe:
+                new_usage = jnp.where(c_fatpipe, new_usage_max,
+                                      new_usage_sum)
+                usage = jnp.where(touched, new_usage, usage)
+                remaining = jnp.where(touched & ~c_fatpipe, new_remaining,
+                                      remaining)
+            else:
+                # static specialization (host-checked): no FATPIPE
+                # constraint in the system, so the max-usage recompute
+                # drops out
+                usage = jnp.where(touched, new_usage_sum, usage)
+                remaining = jnp.where(touched, new_remaining, remaining)
 
-        # A constraint leaves the light set only when *touched* by a fixed
-        # variable and failing the epsilon tests (maxmin.cpp:607-609);
-        # untouched constraints with tiny-but-positive usage stay in.
-        drop = touched & (~(usage > eps) | ~(remaining > c_bound * eps))
-        light = light & ~drop
-        # Numerical safety net (no effect in exact arithmetic, where
-        # usage - d_use reaches 0 exactly and the epsilon drop fires): a
-        # constraint with no live variable left can never fix anything
-        # again, so it must leave the light set even when f32 rounding of
-        # the usage residual keeps it above eps — otherwise the loop spins
-        # on an unfixable min-rou constraint until _MAX_ROUNDS.
-        has_live = allmax(jnp.zeros(n_c, bool).at[e_cnst].max(e_live2))
-        light = light & has_live
+        with jax.named_scope("sg.lmm.prune"):
+            # A constraint leaves the light set only when *touched* by a
+            # fixed variable and failing the epsilon tests
+            # (maxmin.cpp:607-609); untouched constraints with
+            # tiny-but-positive usage stay in.
+            drop = touched & (~(usage > eps)
+                              | ~(remaining > c_bound * eps))
+            light = light & ~drop
+            # Numerical safety net (no effect in exact arithmetic, where
+            # usage - d_use reaches 0 exactly and the epsilon drop
+            # fires): a constraint with no live variable left can never
+            # fix anything again, so it must leave the light set even
+            # when f32 rounding of the usage residual keeps it above eps
+            # — otherwise the loop spins on an unfixable min-rou
+            # constraint until _MAX_ROUNDS.
+            has_live = allmax(jnp.zeros(n_c, bool).at[e_cnst].max(e_live2))
+            light = light & has_live
         return v_value, v_fixed, remaining, usage, light, it + 1
 
     def body_global(state):
@@ -528,35 +547,41 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
         maxmin.cpp:560-680)."""
         v_value, v_fixed, remaining, usage, light, it = state
 
-        rou = jnp.where(light, remaining / jnp.where(light, usage, 1.0), inf)
-        min_usage = jnp.min(rou)
-        saturated_c = light & (rou == min_usage)
+        with jax.named_scope("sg.lmm.neighmin"):
+            rou = jnp.where(light, remaining / jnp.where(light, usage, 1.0),
+                            inf)
+        with jax.named_scope("sg.lmm.level"):
+            min_usage = jnp.min(rou)
+            saturated_c = light & (rou == min_usage)
 
-        # Saturated variables: any live element inside a saturated constraint.
-        e_live = e_valid & ~jnp.take(v_fixed, e_var)
-        e_sat = e_live & jnp.take(saturated_c, e_cnst)
-        v_sat = allmax(jnp.zeros(n_v, dtype=bool).at[e_var].max(e_sat))
+            # Saturated variables: any live element inside a saturated
+            # constraint.
+            e_live = e_valid & ~jnp.take(v_fixed, e_var)
+            e_sat = e_live & jnp.take(saturated_c, e_cnst)
+            v_sat = allmax(jnp.zeros(n_v, dtype=bool).at[e_var].max(e_sat))
 
-        if not has_bounds:
-            # static specialization: no active variable bound, so the
-            # bound-first rule drops out of the compiled round body
-            return apply_fixes(state, v_sat,
-                               min_usage / jnp.where(v_enabled, v_penalty,
-                                                     1.0))
+            if not has_bounds:
+                # static specialization: no active variable bound, so
+                # the bound-first rule drops out of the compiled round
+                # body
+                fix_now = v_sat
+                new_value = min_usage / jnp.where(v_enabled, v_penalty, 1.0)
+            else:
+                # Bound-first rule (maxmin.cpp:566-596): if any saturated
+                # variable's bound*penalty sits below min_usage, fix
+                # (only) the variables whose bound*penalty equals the
+                # smallest such value this round.
+                bp = v_bound * v_penalty
+                has_low_bound = v_sat & (v_bound > 0) & (bp < min_usage)
+                min_bound = jnp.min(jnp.where(has_low_bound, bp, inf))
+                use_bounds = jnp.isfinite(min_bound)
 
-        # Bound-first rule (maxmin.cpp:566-596): if any saturated variable's
-        # bound*penalty sits below min_usage, fix (only) the variables whose
-        # bound*penalty equals the smallest such value this round.
-        bp = v_bound * v_penalty
-        has_low_bound = v_sat & (v_bound > 0) & (bp < min_usage)
-        min_bound = jnp.min(jnp.where(has_low_bound, bp, inf))
-        use_bounds = jnp.isfinite(min_bound)
-
-        fix_now = jnp.where(use_bounds,
-                            v_sat & (jnp.abs(bp - min_bound) < eps),
-                            v_sat)
-        new_value = jnp.where(use_bounds, v_bound,
-                              min_usage / jnp.where(v_enabled, v_penalty, 1.0))
+                fix_now = jnp.where(use_bounds,
+                                    v_sat & (jnp.abs(bp - min_bound) < eps),
+                                    v_sat)
+                new_value = jnp.where(
+                    use_bounds, v_bound,
+                    min_usage / jnp.where(v_enabled, v_penalty, 1.0))
         return apply_fixes(state, fix_now, new_value)
 
     def body_local(state):
@@ -568,60 +593,70 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
         matter in which order the rest of the graph saturates."""
         v_value, v_fixed, remaining, usage, light, it = state
 
-        rou = jnp.where(light, remaining / jnp.where(light, usage, 1.0), inf)
-        e_live = e_valid & ~jnp.take(v_fixed, e_var)
+        with jax.named_scope("sg.lmm.neighmin"):
+            rou = jnp.where(light, remaining / jnp.where(light, usage, 1.0),
+                            inf)
+            e_live = e_valid & ~jnp.take(v_fixed, e_var)
 
-        # Two-hop neighborhood min of rou: constraint -> vars -> constraint.
-        e_rou = jnp.where(e_live, jnp.take(rou, e_cnst), inf)
-        nmin_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(e_rou))
-        e_nmin = jnp.where(e_live, jnp.take(nmin_v, e_var), inf)
-        nmin_c = allmin(jnp.full(n_c, inf, dtype).at[e_cnst].min(e_nmin))
-        processable = light & (rou <= nmin_c)
+            # Two-hop neighborhood min of rou: constraint -> vars ->
+            # constraint.
+            e_rou = jnp.where(e_live, jnp.take(rou, e_cnst), inf)
+            nmin_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(e_rou))
+            e_nmin = jnp.where(e_live, jnp.take(nmin_v, e_var), inf)
+            nmin_c = allmin(
+                jnp.full(n_c, inf, dtype).at[e_cnst].min(e_nmin))
+            processable = light & (rou <= nmin_c)
 
-        # Saturated vars and their levels (min processable rou containing v).
-        e_proc = e_live & jnp.take(processable, e_cnst)
+        with jax.named_scope("sg.lmm.level"):
+            # Saturated vars and their levels (min processable rou
+            # containing v).
+            e_proc = e_live & jnp.take(processable, e_cnst)
 
-        if not has_bounds:
-            # static specialization: with no active variable bound every
-            # processable constraint is unblocked, so the level of a
-            # saturated variable is just its min processable rou
-            level2_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(
-                jnp.where(e_proc, e_rou, inf)))
-            fix_now = jnp.isfinite(level2_v) & ~v_fixed
-            return apply_fixes(state, fix_now,
-                               level2_v / jnp.where(v_enabled, v_penalty,
-                                                    1.0))
+            if not has_bounds:
+                # static specialization: with no active variable bound
+                # every processable constraint is unblocked, so the level
+                # of a saturated variable is just its min processable rou
+                level2_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(
+                    jnp.where(e_proc, e_rou, inf)))
+                fix_now = jnp.isfinite(level2_v) & ~v_fixed
+                new_value = level2_v / jnp.where(v_enabled, v_penalty, 1.0)
+            else:
+                v_sat = allmax(
+                    jnp.zeros(n_v, dtype=bool).at[e_var].max(e_proc))
+                level_v = nmin_v
 
-        v_sat = allmax(jnp.zeros(n_v, dtype=bool).at[e_var].max(e_proc))
-        level_v = nmin_v
+                # Bound-first rule, localized: a processable constraint
+                # holding a below-level bounded variable only fixes its
+                # minimal such bounds this round (the constraint
+                # re-enters with an updated rou), and any constraint
+                # sharing a variable with it must wait, exactly as the
+                # reference's global-min-bound round defers level fixing.
+                bp = v_bound * v_penalty
+                low_v = v_sat & (v_bound > 0) & (bp < level_v)
+                e_bp = jnp.where(e_live & jnp.take(low_v, e_var),
+                                 jnp.take(bp, e_var), inf)
+                mb_c = allmin(jnp.full(n_c, inf, dtype).at[e_cnst].min(e_bp))
+                mb_c = jnp.where(processable, mb_c, inf)
+                e_mb = jnp.where(e_proc, jnp.take(mb_c, e_cnst), inf)
+                mb_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(e_mb))
+                e_blocked = e_proc & jnp.isfinite(jnp.take(mb_v, e_var))
+                blocked_c = allmax(
+                    jnp.zeros(n_c, dtype=bool).at[e_cnst].max(e_blocked))
 
-        # Bound-first rule, localized: a processable constraint holding a
-        # below-level bounded variable only fixes its minimal such bounds
-        # this round (the constraint re-enters with an updated rou), and
-        # any constraint sharing a variable with it must wait, exactly as
-        # the reference's global-min-bound round defers level fixing.
-        bp = v_bound * v_penalty
-        low_v = v_sat & (v_bound > 0) & (bp < level_v)
-        e_bp = jnp.where(e_live & jnp.take(low_v, e_var),
-                         jnp.take(bp, e_var), inf)
-        mb_c = allmin(jnp.full(n_c, inf, dtype).at[e_cnst].min(e_bp))
-        mb_c = jnp.where(processable, mb_c, inf)
-        e_mb = jnp.where(e_proc, jnp.take(mb_c, e_cnst), inf)
-        mb_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(e_mb))
-        e_blocked = e_proc & jnp.isfinite(jnp.take(mb_v, e_var))
-        blocked_c = allmax(jnp.zeros(n_c, dtype=bool).at[e_cnst].max(e_blocked))
+                # Level-fixing only through processable, unblocked
+                # constraints.
+                ok_c = processable & ~blocked_c
+                e_rou_ok = jnp.where(e_live & jnp.take(ok_c, e_cnst),
+                                     jnp.take(rou, e_cnst), inf)
+                level2_v = allmin(
+                    jnp.full(n_v, inf, dtype).at[e_var].min(e_rou_ok))
 
-        # Level-fixing only through processable, unblocked constraints.
-        ok_c = processable & ~blocked_c
-        e_rou_ok = jnp.where(e_live & jnp.take(ok_c, e_cnst),
-                             jnp.take(rou, e_cnst), inf)
-        level2_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(e_rou_ok))
-
-        fix_bound = low_v & (jnp.abs(bp - mb_v) < eps)
-        fix_level = jnp.isfinite(level2_v) & ~v_fixed & ~fix_bound
-        fix_now = fix_bound | fix_level
-        new_value = jnp.where(fix_bound, v_bound,
-                              level2_v / jnp.where(v_enabled, v_penalty, 1.0))
+                fix_bound = low_v & (jnp.abs(bp - mb_v) < eps)
+                fix_level = jnp.isfinite(level2_v) & ~v_fixed & ~fix_bound
+                fix_now = fix_bound | fix_level
+                new_value = jnp.where(
+                    fix_bound, v_bound,
+                    level2_v / jnp.where(v_enabled, v_penalty, 1.0))
         return apply_fixes(state, fix_now, new_value)
 
     out = _run_rounds(cond, body_local if parallel_rounds else body_global,
@@ -980,7 +1015,6 @@ def _device_args(kind: str, host_args, device):
             _DEVICE_ARGS_CACHE[key] = hit
             return dev_args
     dev_args = [jax.device_put(a, device) for a in host_args]
-    from . import opstats
     opstats.bump("uploaded_bytes_full",
                  sum(getattr(a, "nbytes", 0) for a in host_args))
     if len(_DEVICE_ARGS_CACHE) >= 8:
@@ -1102,51 +1136,52 @@ def flatten(cnst_list: List[Constraint], dtype=np.float64
     each constraint, the enabled-element list order, giving the same
     deterministic structure the reference's intrusive lists provide.
     """
-    var_slots = {}
-    v_penalty: List[float] = []
-    v_bound: List[float] = []
-    vars_in_order = []
-    e_var: List[int] = []
-    e_cnst: List[int] = []
-    e_w: List[float] = []
-    c_bound: List[float] = []
-    c_fat: List[bool] = []
+    with opstats.span("lmm.flatten"):
+        var_slots = {}
+        v_penalty: List[float] = []
+        v_bound: List[float] = []
+        vars_in_order = []
+        e_var: List[int] = []
+        e_cnst: List[int] = []
+        e_w: List[float] = []
+        c_bound: List[float] = []
+        c_fat: List[bool] = []
 
-    for ci, cnst in enumerate(cnst_list):
-        c_bound.append(cnst.bound)
-        c_fat.append(cnst.sharing_policy == SharingPolicy.FATPIPE)
-        for elem in cnst.enabled_element_set:
-            var = elem.variable
-            slot = var_slots.get(id(var))
-            if slot is None:
-                slot = len(v_penalty)
-                var_slots[id(var)] = slot
-                v_penalty.append(var.sharing_penalty)
-                v_bound.append(var.bound)
-                vars_in_order.append(var)
-            e_var.append(slot)
-            e_cnst.append(ci)
-            e_w.append(elem.consumption_weight)
+        for ci, cnst in enumerate(cnst_list):
+            c_bound.append(cnst.bound)
+            c_fat.append(cnst.sharing_policy == SharingPolicy.FATPIPE)
+            for elem in cnst.enabled_element_set:
+                var = elem.variable
+                slot = var_slots.get(id(var))
+                if slot is None:
+                    slot = len(v_penalty)
+                    var_slots[id(var)] = slot
+                    v_penalty.append(var.sharing_penalty)
+                    v_bound.append(var.bound)
+                    vars_in_order.append(var)
+                e_var.append(slot)
+                e_cnst.append(ci)
+                e_w.append(elem.consumption_weight)
 
-    n_e, n_c, n_v = len(e_var), len(c_bound), len(v_penalty)
-    if n_c == 0:
-        return None
-    E, C, V = _bucket(max(n_e, 1)), _bucket(n_c), _bucket(max(n_v, 1))
+        n_e, n_c, n_v = len(e_var), len(c_bound), len(v_penalty)
+        if n_c == 0:
+            return None
+        E, C, V = _bucket(max(n_e, 1)), _bucket(n_c), _bucket(max(n_v, 1))
 
-    arrays = LmmArrays(
-        e_var=np.zeros(E, np.int32), e_cnst=np.zeros(E, np.int32),
-        e_w=np.zeros(E, dtype), c_bound=np.zeros(C, dtype),
-        c_fatpipe=np.zeros(C, bool), v_penalty=np.zeros(V, dtype),
-        v_bound=np.full(V, -1.0, dtype), n_elem=n_e, n_cnst=n_c, n_var=n_v)
-    arrays.e_var[:n_e] = e_var
-    # Padding elements point at constraint slot 0 with weight 0: harmless.
-    arrays.e_cnst[:n_e] = e_cnst
-    arrays.e_w[:n_e] = e_w
-    arrays.c_bound[:n_c] = c_bound
-    arrays.c_fatpipe[:n_c] = c_fat
-    arrays.v_penalty[:n_v] = v_penalty
-    arrays.v_bound[:n_v] = v_bound
-    return arrays, vars_in_order
+        arrays = LmmArrays(
+            e_var=np.zeros(E, np.int32), e_cnst=np.zeros(E, np.int32),
+            e_w=np.zeros(E, dtype), c_bound=np.zeros(C, dtype),
+            c_fatpipe=np.zeros(C, bool), v_penalty=np.zeros(V, dtype),
+            v_bound=np.full(V, -1.0, dtype), n_elem=n_e, n_cnst=n_c, n_var=n_v)
+        arrays.e_var[:n_e] = e_var
+        # Padding elements point at constraint slot 0 with weight 0: harmless.
+        arrays.e_cnst[:n_e] = e_cnst
+        arrays.e_w[:n_e] = e_w
+        arrays.c_bound[:n_c] = c_bound
+        arrays.c_fatpipe[:n_c] = c_fat
+        arrays.v_penalty[:n_v] = v_penalty
+        arrays.v_bound[:n_v] = v_bound
+        return arrays, vars_in_order
 
 
 def use_local_rounds() -> bool:
@@ -1425,23 +1460,23 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
                 unroll=unroll, has_bounds=has_bounds,
                 has_fatpipe=has_fatpipe)
 
-    from . import opstats
     carry = None
     prev_progress = None
     while True:
-        values, remaining, usage, rounds, carry = run_chunk(carry)
-        opstats.bump("dispatches")
-        # ONE host sync per chunk: [rounds, light count, fixed count]
-        # AND the result vectors ride a single device->host transfer
-        # — a converged solve pays exactly one round-trip.  Counts are
-        # exact in f32 (< 2^24).
-        rdt = values.dtype
-        n_vc, n_cc = values.shape[0], remaining.shape[0]
-        fetched = np.asarray(jnp.concatenate([
-            jnp.stack([rounds.astype(rdt),
-                       jnp.count_nonzero(carry[4]).astype(rdt),
-                       jnp.count_nonzero(carry[1]).astype(rdt)]),
-            values, remaining.astype(rdt), usage.astype(rdt)]))
+        with opstats.span("solve.chunk"):
+            values, remaining, usage, rounds, carry = run_chunk(carry)
+            opstats.bump("dispatches")
+            # ONE host sync per chunk: [rounds, light count, fixed
+            # count] AND the result vectors ride a single device->host
+            # transfer — a converged solve pays exactly one round-trip.
+            # Counts are exact in f32 (< 2^24).
+            rdt = values.dtype
+            n_vc, n_cc = values.shape[0], remaining.shape[0]
+            fetched = opstats.timed_fetch(jnp.concatenate([
+                jnp.stack([rounds.astype(rdt),
+                           jnp.count_nonzero(carry[4]).astype(rdt),
+                           jnp.count_nonzero(carry[1]).astype(rdt)]),
+                values, remaining.astype(rdt), usage.astype(rdt)]))
         rounds, n_light, n_fixed = (int(fetched[0]), int(fetched[1]),
                                     int(fetched[2]))
         if n_light == 0:
@@ -1639,7 +1674,6 @@ def solve_jax(system: System) -> None:
         system.fallback_count = getattr(system, "fallback_count", 0) + 1
         # per-stage visibility (the global int cannot be attributed):
         # quarantine decisions and bench rows read this scoped counter
-        from . import opstats
         opstats.bump("solver_fallbacks")
         if not _fallback_warned:
             _fallback_warned = True

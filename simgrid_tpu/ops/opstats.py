@@ -1,4 +1,5 @@
-"""Process-wide performance counters for the device solve paths.
+"""Process-wide performance counters and host spans: the program's one
+tracing facility.
 
 Every device-facing module reports into one flat counter table so
 tools can attribute cost per simulation phase without plumbing a
@@ -6,10 +7,6 @@ context object through the solver entry points:
 
 * ``dispatches``            — device kernel dispatches (solver chunks,
                               drain advances/supersteps, warm solves)
-* ``batch_dispatches``      — dispatches that ran a whole replica
-                              FLEET (ops.lmm_batch); always also
-                              counted in ``dispatches``
-* ``batch_replicas``        — replicas admitted into batched fleets
 * ``fixpoint_rounds``       — saturation rounds executed on device
 * ``uploaded_bytes_full``   — host->device bytes shipped as whole
                               arrays (fresh ``device_put``)
@@ -45,9 +42,9 @@ context object through the solver entry points:
                               grows
 * ``fetches``               — device->host result transfers routed
                               through :func:`timed_fetch` (drain ring
-                              fetches, batched fleet fetches; each
-                              shard block of a sharded fleet counts
-                              once)
+                              fetches, ``solve_arrays`` chunk fetches,
+                              batched fleet fetches; each shard block
+                              of a sharded fleet counts once)
 * ``fetched_bytes``         — device->host bytes moved by those
                               transfers
 * ``blocking_fetches``      — the subset of ``fetches`` whose device
@@ -116,8 +113,6 @@ context object through the solver entry points:
 * ``lanes_admitted``        — dead fleet lanes revived mid-flight with
                               a NEW scenario by the serving admission
                               path (BatchDrainSim.admit_lane)
-* ``serve_device_results``  — queries the campaign service answered
-                              with exact device simulation
 * ``surrogate_answers`` / ``surrogate_escalations`` — queries the
                               serving surrogate answered from its
                               conformal-interval prediction vs routed
@@ -138,8 +133,6 @@ context object through the solver entry points:
                               written by the campaign service
 * ``checkpoint_ms``         — monotonic milliseconds spent building +
                               writing those checkpoints
-* ``fleet_resumes``         — services rebuilt from a FleetCheckpoint
-                              token (CampaignService.resume)
 * ``watchdog_retries`` / ``watchdog_exhausted`` /
   ``watchdog_slow_dispatches`` — dispatch-watchdog activity: seeded-
                               backoff retries of failed device
@@ -182,6 +175,19 @@ context object through the solver entry points:
                               discarded because the superstep they
                               chained from fired a collective tape
                               event (mirror of ``fault_replays``)
+* ``flows_posted`` / ``post_ms`` — flows posted through
+                              ``NetworkCm02Model.communicate`` and the
+                              monotonic milliseconds spent inside it
+                              (route lookup, variable, expands): a
+                              counter pair, not a span — a workload
+                              posts 100,000 of them
+* ``xla_compiles`` / ``xla_compile_ms`` — programs JAX handed to the
+                              backend compiler (a jit's first call
+                              with new shapes or statics, an AOT
+                              ``compile()``) and the monotonic
+                              milliseconds inside: each is also an
+                              ``xla.compile`` span.  A steady-state
+                              window must keep both flat
 * ``retraces``              — jit trace executions of the kernel
                               program functions (bumped at TRACE time
                               only, from inside the program body): a
@@ -210,17 +216,92 @@ can no longer double-count the previous stage's work::
     with opstats.scoped("sweep/b64") as st:
         campaign.run_batched(batch=64)
     st["dispatches"]          # this stage only
+
+Spans
+-----
+
+``span(name, id=None)`` brackets one host step: on exit it appends
+``Span(name, start, end, parent, id, seq)`` to a bounded in-memory
+buffer (``spans()`` reads it, ``reset()`` clears it; the oldest
+records fall off, so a service that runs for days does not grow).
+``start``/``end`` are ``time.perf_counter`` seconds, the clock a
+benchmark's own spans use, so a reader cuts warm-up from window with
+one comparison.  ``seq`` numbers spans in the order they were
+entered, ``parent`` is the ``seq`` of the enclosing span of the same
+thread (None at the top), and ``id`` ties the spans of one device
+dispatch together (``DrainSim.supersteps`` at issue); a span without
+one inherits its parent's.  The same interval is entered as a
+``jax.profiler.TraceAnnotation("sg:" + name)``, which records only
+while a profiler session is running: tracing is "on" when the
+profiler is, and there is no flag.  In a trace the ``sg:`` host spans
+sit on the device's own timeline, beside the device passes that
+``jax.named_scope`` names ``sg.lmm.*`` (ops.lmm_jax.fixpoint) and
+``sg.drain.*`` (ops.lmm_drain._superstep_program).
+
+Rule: a span site fires at most once per device dispatch or once per
+set-up phase.  Finer work gets a counter pair (``*_ms`` + count, as
+``post_ms`` / ``flows_posted``).  Span names are literals declared in
+this table, like the counters (the ``opstats-discipline`` lint checks
+``span("...")`` against it):
+
+* ``platform.load``   — ``s4u.Engine.load_platform``: the XML parse and
+                        the zones, hosts, links and routes it builds
+* ``lmm.flatten``     — ``lmm_jax.flatten``: the live host system
+                        walked into padded COO arrays
+* ``drain.init``      — ``DrainSim.__init__``: the host arrays shaped
+                        and handed to the device (``device_put``)
+* ``drain.issue``     — ``DrainSim._superstep_issue``: one superstep
+                        dispatch enqueued (trace and compile on a
+                        first call, then the async enqueue alone)
+* ``drain.collect``   — ``DrainSim._superstep_collect``: the ring
+                        fetched (child ``fetch``), replayed (child
+                        ``drain.demux``) and the repack decision
+* ``drain.demux``     — the fetched ring replayed into ``events`` /
+                        ``fault_events`` / ``collective_events``
+* ``solve.chunk``     — one dispatch + fetch of ``solve_arrays``' loop
+* ``fetch``           — every :func:`timed_fetch`: the host inside one
+                        device->host transfer (``blocking_fetches``
+                        still says whether the device was ready)
+* ``xla.compile``     — one program through the backend compiler, as
+                        JAX's monitoring reports it when it ends:
+                        ``(now - duration, now)``; ``id`` is the jitted
+                        function's name, prefixed ``cached:`` when the
+                        persistent compilation cache served it
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
+import threading
 import time
-from typing import Dict, Iterator
+from collections import deque
+from typing import Dict, Iterator, List, NamedTuple, Optional
 
 import numpy as np
 
+import jax
+
 _counters: Dict[str, float] = {}
+
+
+class Span(NamedTuple):
+    """One closed host span (see the module docstring)."""
+    name: str
+    start: float
+    end: float
+    parent: Optional[int]
+    id: Optional[object]
+    seq: int
+
+
+#: how many closed spans are kept; ~25 fire per 9 s dispatch, so this
+#: is hours of a drain and a bounded few MB of a service's life
+SPAN_BUFFER = 65536
+
+_spans: "deque[Span]" = deque(maxlen=SPAN_BUFFER)
+_open = threading.local()         # .stack: the thread's open spans
+_seq = itertools.count(1)         # next() is one bytecode: thread-safe
 
 #: per-stage deltas recorded by ``scoped`` (last run of each stage)
 stage_stats: Dict[str, Dict[str, float]] = {}
@@ -240,14 +321,72 @@ def timed_fetch(arr) -> "np.ndarray":
     blocking fetches into ready ones; this is where that is measured.
     """
     ready = bool(getattr(arr, "is_ready", lambda: False)())
-    t0 = time.perf_counter()
-    out = np.asarray(arr)
-    bump("host_block_ms", (time.perf_counter() - t0) * 1e3)
+    with span("fetch") as sp:
+        out = np.asarray(arr)
+    bump("host_block_ms", (sp.end - sp.start) * 1e3)
     bump("fetches")
     bump("fetched_bytes", out.nbytes)
     if not ready:
         bump("blocking_fetches")
     return out
+
+
+class span:
+    """Context manager recording one host span (module docstring,
+    "Spans").  A class, not a generator: a site costs two clock reads,
+    one ``TraceAnnotation`` and one append."""
+
+    __slots__ = ("name", "id", "start", "end", "_seq", "_parent", "_note")
+
+    def __init__(self, name: str, id=None):
+        self.name = name
+        self.id = id
+        self.start = self.end = 0.0
+
+    def __enter__(self) -> "span":
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        if stack:
+            self._parent = stack[-1]._seq
+            if self.id is None:
+                self.id = stack[-1].id
+        else:
+            self._parent = None
+        self._seq = next(_seq)
+        stack.append(self)
+        self._note = jax.profiler.TraceAnnotation("sg:" + self.name)
+        self._note.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        self._note.__exit__(*exc)
+        _open.stack.pop()
+        _spans.append(Span(self.name, self.start, self.end, self._parent,
+                           self.id, self._seq))
+
+
+def spans() -> List[Span]:
+    """The closed spans still in the buffer, in the order they were
+    entered (a parent before its children)."""
+    return sorted(_spans, key=lambda s: s.seq)
+
+
+def note_compile(seconds: float, fun_name: Optional[str],
+                 cached: bool) -> None:
+    """One program left JAX's backend-compile step after ``seconds``
+    (the listener ``ops/__init__.py`` registers calls this): a closed
+    ``xla.compile`` span ending now, and the ``xla_*`` counters."""
+    end = time.perf_counter()
+    stack = getattr(_open, "stack", None)
+    _spans.append(Span("xla.compile", end - seconds, end,
+                       stack[-1]._seq if stack else None,
+                       ("cached:" if cached else "") + str(fun_name),
+                       next(_seq)))
+    bump("xla_compiles")
+    bump("xla_compile_ms", seconds * 1e3)
 
 
 def snapshot() -> Dict[str, float]:
@@ -284,7 +423,10 @@ def get_stage(name: str) -> Dict[str, float]:
 
 
 def reset() -> None:
-    """Clear every counter AND the recorded stage deltas (fresh
-    process-equivalent state for tests and multi-phase tools)."""
+    """Clear every counter, the recorded stage deltas AND the span
+    buffer (fresh process-equivalent state for tests and multi-phase
+    tools).  Spans still open keep their place on their thread's stack
+    and are recorded when they close."""
     _counters.clear()
     stage_stats.clear()
+    _spans.clear()

@@ -13,6 +13,7 @@ from typing import Callable, Dict, List, Optional
 from ..exceptions import ParseError
 from ..kernel.engine import EngineImpl
 from ..models.registry import setup_models
+from ..ops import opstats
 from ..platform.xml import PlatformLoader
 from ..utils.config import config
 from ..utils import log as _xlog
@@ -100,7 +101,8 @@ class Engine:
 
     def load_platform(self, path: str) -> None:
         self._ensure_models()
-        PlatformLoader(self.pimpl).load(path)
+        with opstats.span("platform.load"):
+            PlatformLoader(self.pimpl).load(path)
         # TRACE_start fires on platform creation in the reference
         # (instr_config.cpp:297); same here so actors created before
         # run() are captured.

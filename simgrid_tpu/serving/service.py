@@ -302,7 +302,6 @@ class CampaignService:
             t.done_at = time.perf_counter()
             self.completed.append(t)
             self._lane_tickets[b] = None
-            opstats.bump("serve_device_results")
             if rep.error is None:
                 if self.surrogate is not None:
                     self.surrogate.observe(t.spec, rep.t)
@@ -705,7 +704,6 @@ class CampaignService:
             svc._lane_tickets = [
                 by_id[i] if i is not None else None
                 for i in svc_tok["lane_tickets"]]
-        opstats.bump("fleet_resumes")
         return svc
 
     # -- introspection -----------------------------------------------------

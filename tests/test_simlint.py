@@ -11,6 +11,8 @@ import json
 import os
 import sys
 
+import pytest
+
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)
@@ -284,6 +286,72 @@ class TestOpstatsDiscipline:
             "opstats.bump('undeclared')"
             "  # simlint: ignore[opstats-discipline] -- migration\n")
         assert rules_of(fs, "opstats-discipline") == []
+
+
+SPANS_FIXTURE = OPSTATS_FIXTURE.replace(
+    '"""\ndef bump', (
+        "\n"
+        "Spans\n"
+        "-----\n"
+        "\n"
+        "Prose naming ``not.a.bullet`` declares nothing.\n"
+        "\n"
+        "* ``drain.issue``  — a declared span\n"
+        "* ``xla.compile``  — recorded by hand inside opstats\n"
+        '"""\n'
+        "def bump")) + (
+    "class Span(tuple):\n"
+    "    pass\n"
+    "class span:\n"
+    "    def __init__(self, name, id=None):\n"
+    "        self.name = name\n"
+    "    def __exit__(self, *exc):\n"
+    "        Span(self.name)\n"
+    "def note_compile():\n"
+    "    Span('xla.compile')\n")
+
+BUMPS = ("opstats.bump('declared')\nopstats.bump('ghost')\n"
+         "opstats.bump('fam_' + kind)\n")
+
+
+class TestOpstatsSpanDiscipline:
+    def lint(self, user_src):
+        return lint_sources({
+            "simgrid_tpu/ops/opstats.py": SPANS_FIXTURE,
+            "simgrid_tpu/ops/user.py": (
+                "from simgrid_tpu.ops import opstats\n" + BUMPS
+                + user_src),
+        })
+
+    @pytest.mark.parametrize("src,lines", [
+        ("with opstats.span('drain.issue', id=3):\n    pass\n", []),
+        ("with opstats.span('drain.issue'):\n    pass\n"
+         "with opstats.span('drain.other'):\n    pass\n", [7]),
+        ("with opstats.span('drain.issue'):\n    pass\n"
+         "with opstats.span('drain.' + kind):\n    pass\n", [7]),
+        ("with opstats.span('drain.issue'):\n    pass\n"
+         "with opstats.span('not.a.bullet'):\n    pass\n", [7]),
+    ], ids=["declared", "undeclared", "non-literal", "prose-token"])
+    def test_span_sites_are_held_to_the_table(self, src, lines):
+        got = rules_of(self.lint(src), "opstats-discipline")
+        assert sorted(f.line for f in got) == lines
+        assert all(f.path.endswith("user.py") for f in got)
+
+    def test_declared_but_never_opened_is_flagged_at_registry(self):
+        got = rules_of(self.lint("x = 1\n"), "opstats-discipline")
+        assert len(got) == 1
+        assert got[0].path == "simgrid_tpu/ops/opstats.py"
+        assert "'drain.issue'" in got[0].message
+        assert "drain.issue" in SPANS_FIXTURE.splitlines()[got[0].line - 1]
+
+    def test_the_real_table_declares_every_span_the_tree_opens(self):
+        from simgrid_tpu.analysis.rules.opstats_discipline import \
+            declared_spans
+        from simgrid_tpu.ops import opstats
+        names = set(declared_spans(opstats.__doc__))
+        assert {"platform.load", "lmm.flatten", "drain.init",
+                "drain.issue", "drain.collect", "drain.demux",
+                "solve.chunk", "fetch", "xla.compile"} == names
 
 
 # -- engine: suppressions ------------------------------------------------
