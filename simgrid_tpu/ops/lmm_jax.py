@@ -408,9 +408,11 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     (``axis=None`` — the reductions are plain segment ops), vmapped
     batches, and mesh-sharded element lists (``axis`` names the shard_map
     mesh axis; cross-shard combines are one psum/pmax pair per round in
-    global mode and ~7 psum/pmax/pmin collectives per round in local
-    mode, which still wins because local mode needs far fewer rounds —
-    see simgrid_tpu.parallel.sharded).
+    global mode and 4-10 psum/pmax/pmin collectives per round in local
+    mode (one per segment reduction: four without bounds, one more with
+    FATPIPE; with bounds one for the bound block's predicate and four
+    more in a round whose block runs), which still wins because local
+    mode needs far fewer rounds — see simgrid_tpu.parallel.sharded).
 
     ``parallel_rounds=False`` replays the reference's sequential order
     exactly: one global bottleneck level per round.  ``True`` fixes every
@@ -427,10 +429,23 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     fresh initial state) and hand the full loop state back, so the host
     can bound device-kernel run time per dispatch and check convergence
     between chunks (a non-converging f32 solve must come back to the
-    host and raise, not spin inside one dispatch).
+    host and raise, not spin inside one dispatch).  With
+    ``return_carry`` the result is ``(values, remaining, usage, rounds,
+    carry, bound_rounds)``: the 6-tuple carry to hand back in, and the
+    number of this call's rounds that took the bound-first rule (the
+    local round's bound block, the global round's min-bound branch).
     """
     dtype = e_w.dtype
     inf = jnp.array(jnp.inf, dtype)
+    big = jnp.array(jnp.finfo(dtype).max, dtype)
+    # apply_fixes counts a round's fixed elements per constraint in
+    # `dtype`: exact while no constraint can hold more elements than
+    # the dtype has consecutive integers (2^24 in f32).
+    n_elems = e_var.size * (lax.psum(1, axis) if axis else 1)
+    if n_elems >= 2 ** (jnp.finfo(dtype).nmant + 1):
+        raise ValueError(
+            f"{n_elems} elements: more than {dtype.name} counts exactly "
+            "per constraint")
 
     def allsum(x):
         return lax.psum(x, axis) if axis else x
@@ -468,30 +483,54 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
         if carry is None:
             carry = (v_value0, v_fixed0, remaining0, usage0, light0,
                      jnp.array(0, jnp.int32))
+
+        # Element liveness and the live count per constraint ride the
+        # loop state, so no round gathers v_fixed again.  Both are
+        # rebuilt HERE from the carry's v_fixed (fresh, or a mid-solve
+        # carry handed back by a chunked caller) and dropped at exit:
+        # the public carry stays the 6-tuple.
+        e_live0 = e_valid & ~jnp.take(carry[1], e_var)
+        n_live_c0 = allsum(jnp.zeros(n_c, jnp.int32).at[e_cnst].add(
+            e_live0.astype(jnp.int32)))
     start_it = carry[5]
     if max_rounds is None:
         max_rounds = _MAX_ROUNDS
 
     def cond(state):
-        _, _, _, _, light, it = state
+        light, it = state[4], state[5]
         return (jnp.any(light) & (it < _MAX_ROUNDS)
                 & (it - start_it < max_rounds))
 
-    def apply_fixes(state, fix_now, new_value):
+    def apply_fixes(state, fix_now, new_value, took_bounds):
         """Shared round tail: write fixed values, batched double_update of
-        every touched constraint, epsilon-based light-set pruning."""
-        v_value, v_fixed, remaining, usage, light, it = state
+        every touched constraint, epsilon-based light-set pruning.
+        ``fix_now`` only ever holds unfixed variables, so the elements it
+        fixes are live ones and next round's liveness is this round's
+        minus them."""
+        (v_value, v_fixed, remaining, usage, light, it,
+         e_live, n_live_c, bound_rounds) = state
         with jax.named_scope("sg.lmm.update"):
             v_value = jnp.where(fix_now, new_value, v_value)
             v_fixed = v_fixed | fix_now
 
             # Batched double_update on every constraint touched by fixed
-            # vars.
-            e_fix = e_valid & jnp.take(fix_now, e_var)
-            d_rem = allsum(jnp.zeros(n_c, dtype).at[e_cnst].add(
-                jnp.where(e_fix, e_w * jnp.take(v_value, e_var), 0.0)))
-            d_use = allsum(jnp.zeros(n_c, dtype).at[e_cnst].add(
-                jnp.where(e_fix, e_upen, 0.0)))
+            # vars.  ONE gather by e_var carries the flag and the value:
+            # rates are >= 0, so -1 marks "not fixed this round" (a NaN
+            # rate still counts as fixed and poisons d_rem as it must).
+            e_fixval = jnp.take(jnp.where(fix_now, new_value, -1.0), e_var)
+            e_fix = e_live & ~(e_fixval < 0)
+            # d_rem, d_use and the count of fixed elements ride ONE
+            # scatter with a 3-wide window: on the chip a scatter costs
+            # by the index, and the 3-wide one 1.7x a 1-wide one, not 3x
+            # (PERF.md §5).  The count answers both "touched by a fix"
+            # and, against the carried live count, "any live element
+            # left"; it is exact below 2^24 elements a constraint in f32
+            # (checked at entry against the whole element count).
+            sums = allsum(jnp.zeros((n_c, 3), dtype).at[e_cnst].add(
+                jnp.stack([jnp.where(e_fix, e_w * e_fixval, 0.0),
+                           jnp.where(e_fix, e_upen, 0.0),
+                           e_fix.astype(dtype)], axis=-1)))
+            d_rem, d_use = sums[:, 0], sums[:, 1]
 
             new_remaining = remaining - d_rem
             new_remaining = jnp.where(new_remaining < c_bound * eps, 0.0,
@@ -501,14 +540,15 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
                                       new_usage_sum)
 
         with jax.named_scope("sg.lmm.prune"):
-            e_live2 = e_valid & ~jnp.take(v_fixed, e_var)
-            touched = allmax(
-                jnp.zeros(n_c, dtype=bool).at[e_cnst].max(e_fix))
+            n_fix = sums[:, 2].astype(jnp.int32)
+            touched = n_fix > 0
+            e_live = e_live & ~e_fix
+            n_live_c = n_live_c - n_fix
             if has_fatpipe:
                 # FATPIPE: usage is re-derived as the max over unset
                 # variables.
                 new_usage_max = allmax(jnp.zeros(n_c, dtype).at[e_cnst].max(
-                    jnp.where(e_live2, e_upen, 0.0)))
+                    jnp.where(e_live, e_upen, 0.0)))
         with jax.named_scope("sg.lmm.update"):
             if has_fatpipe:
                 new_usage = jnp.where(c_fatpipe, new_usage_max,
@@ -538,14 +578,15 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
             # when f32 rounding of the usage residual keeps it above eps
             # — otherwise the loop spins on an unfixable min-rou
             # constraint until _MAX_ROUNDS.
-            has_live = allmax(jnp.zeros(n_c, bool).at[e_cnst].max(e_live2))
-            light = light & has_live
-        return v_value, v_fixed, remaining, usage, light, it + 1
+            light = light & (n_live_c > 0)
+        return (v_value, v_fixed, remaining, usage, light, it + 1,
+                e_live, n_live_c,
+                bound_rounds + took_bounds.astype(jnp.int32))
 
     def body_global(state):
         """One global bottleneck level per round (reference order,
         maxmin.cpp:560-680)."""
-        v_value, v_fixed, remaining, usage, light, it = state
+        v_value, v_fixed, remaining, usage, light, it, e_live = state[:7]
 
         with jax.named_scope("sg.lmm.neighmin"):
             rou = jnp.where(light, remaining / jnp.where(light, usage, 1.0),
@@ -556,7 +597,6 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
 
             # Saturated variables: any live element inside a saturated
             # constraint.
-            e_live = e_valid & ~jnp.take(v_fixed, e_var)
             e_sat = e_live & jnp.take(saturated_c, e_cnst)
             v_sat = allmax(jnp.zeros(n_v, dtype=bool).at[e_var].max(e_sat))
 
@@ -566,6 +606,7 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
                 # body
                 fix_now = v_sat
                 new_value = min_usage / jnp.where(v_enabled, v_penalty, 1.0)
+                use_bounds = jnp.array(False, jnp.bool_)
             else:
                 # Bound-first rule (maxmin.cpp:566-596): if any saturated
                 # variable's bound*penalty sits below min_usage, fix
@@ -582,7 +623,7 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
                 new_value = jnp.where(
                     use_bounds, v_bound,
                     min_usage / jnp.where(v_enabled, v_penalty, 1.0))
-        return apply_fixes(state, fix_now, new_value)
+        return apply_fixes(state, fix_now, new_value, use_bounds)
 
     def body_local(state):
         """Fix every local-minimum constraint per round.  Exact: a
@@ -591,12 +632,11 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
         so a constraint whose rou is minimal among every constraint it
         shares a live variable with already sits at its final level, no
         matter in which order the rest of the graph saturates."""
-        v_value, v_fixed, remaining, usage, light, it = state
+        v_value, v_fixed, remaining, usage, light, it, e_live = state[:7]
 
         with jax.named_scope("sg.lmm.neighmin"):
             rou = jnp.where(light, remaining / jnp.where(light, usage, 1.0),
                             inf)
-            e_live = e_valid & ~jnp.take(v_fixed, e_var)
 
             # Two-hop neighborhood min of rou: constraint -> vars ->
             # constraint.
@@ -620,50 +660,81 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
                     jnp.where(e_proc, e_rou, inf)))
                 fix_now = jnp.isfinite(level2_v) & ~v_fixed
                 new_value = level2_v / jnp.where(v_enabled, v_penalty, 1.0)
+                any_low = jnp.array(False, jnp.bool_)
             else:
-                v_sat = allmax(
-                    jnp.zeros(n_v, dtype=bool).at[e_var].max(e_proc))
-                level_v = nmin_v
-
-                # Bound-first rule, localized: a processable constraint
-                # holding a below-level bounded variable only fixes its
-                # minimal such bounds this round (the constraint
-                # re-enters with an updated rou), and any constraint
-                # sharing a variable with it must wait, exactly as the
-                # reference's global-min-bound round defers level fixing.
-                bp = v_bound * v_penalty
-                low_v = v_sat & (v_bound > 0) & (bp < level_v)
-                e_bp = jnp.where(e_live & jnp.take(low_v, e_var),
-                                 jnp.take(bp, e_var), inf)
-                mb_c = allmin(jnp.full(n_c, inf, dtype).at[e_cnst].min(e_bp))
-                mb_c = jnp.where(processable, mb_c, inf)
-                e_mb = jnp.where(e_proc, jnp.take(mb_c, e_cnst), inf)
-                mb_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(e_mb))
-                e_blocked = e_proc & jnp.isfinite(jnp.take(mb_v, e_var))
-                blocked_c = allmax(
-                    jnp.zeros(n_c, dtype=bool).at[e_cnst].max(e_blocked))
-
-                # Level-fixing only through processable, unblocked
-                # constraints.
-                ok_c = processable & ~blocked_c
-                e_rou_ok = jnp.where(e_live & jnp.take(ok_c, e_cnst),
-                                     jnp.take(rou, e_cnst), inf)
-                level2_v = allmin(
-                    jnp.full(n_v, inf, dtype).at[e_var].min(e_rou_ok))
-
-                fix_bound = low_v & (jnp.abs(bp - mb_v) < eps)
+                level2_v, fix_bound, any_low = bounded_level(
+                    e_live, e_rou, e_proc, processable, nmin_v)
                 fix_level = jnp.isfinite(level2_v) & ~v_fixed & ~fix_bound
                 fix_now = fix_bound | fix_level
                 new_value = jnp.where(
                     fix_bound, v_bound,
                     level2_v / jnp.where(v_enabled, v_penalty, 1.0))
-        return apply_fixes(state, fix_now, new_value)
+        return apply_fixes(state, fix_now, new_value, any_low)
 
+    def bounded_level(e_live, e_rou, e_proc, processable, level_v):
+        """The local round's level under variable bounds: (level2_v,
+        fix_bound, whether the bound block ran)."""
+        # The bound-free level first: it is the answer whenever no bound
+        # binds, and it tells the saturated variables.  A processable
+        # element sends its rou capped at the largest finite number, so
+        # "any processable element" (v_sat) reads off the same scatter
+        # even where a light constraint's rou is inf (a usage so small
+        # that remaining/usage overflows): its flows still take their
+        # bounds.
+        capped_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(
+            jnp.where(e_proc, jnp.minimum(e_rou, big), inf)))
+        v_sat = capped_v < inf
+        bp = v_bound * v_penalty
+        low_v = v_sat & (v_bound > 0) & (bp < level_v)
+
+        def bound_block(_):
+            # Bound-first rule, localized: a processable constraint
+            # holding a below-level bounded variable only fixes its
+            # minimal such bounds this round (the constraint re-enters
+            # with an updated rou), and any constraint sharing a
+            # variable with it must wait, exactly as the reference's
+            # global-min-bound round defers level fixing.
+            e_bp = jnp.where(
+                e_live, jnp.take(jnp.where(low_v, bp, inf), e_var), inf)
+            mb_c = allmin(jnp.full(n_c, inf, dtype).at[e_cnst].min(e_bp))
+            mb_c = jnp.where(processable, mb_c, inf)
+            e_mb = jnp.where(e_proc, jnp.take(mb_c, e_cnst), inf)
+            mb_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(e_mb))
+            e_blocked = e_proc & jnp.isfinite(jnp.take(mb_v, e_var))
+            blocked_c = allmax(
+                jnp.zeros(n_c, dtype=bool).at[e_cnst].max(e_blocked))
+
+            # Level-fixing only through processable, unblocked
+            # constraints.
+            ok_c = processable & ~blocked_c
+            level2_v = allmin(jnp.full(n_v, inf, dtype).at[e_var].min(
+                jnp.where(jnp.take(ok_c, e_cnst), e_rou, inf)))
+            return level2_v, low_v & (jnp.abs(bp - mb_v) < eps)
+
+        def free_result(_):
+            # No bound under its level: the block's quantities reduce
+            # line by line to the bound-free round (mb_c = mb_v = inf,
+            # nothing blocked, ok_c = processable).  One corner departs
+            # from the parent: a processable constraint whose rou is
+            # EXACTLY the largest finite number reads as inf here, and
+            # its variables are not level-fixed at it this round.
+            return (jnp.where(capped_v < big, capped_v, inf),
+                    jnp.zeros_like(low_v))
+
+        # The predicate is data, and the same on every shard (allmax
+        # says so to the compiler).  Under vmap the cond is a select and
+        # both sides run.
+        any_low = allmax(jnp.any(low_v))
+        level2_v, fix_bound = lax.cond(any_low, bound_block, free_result,
+                                       None)
+        return level2_v, fix_bound, any_low
+
+    state0 = (*carry, e_live0, n_live_c0, jnp.array(0, jnp.int32))
     out = _run_rounds(cond, body_local if parallel_rounds else body_global,
-                      carry, max_rounds, unroll)
-    v_value, v_fixed, remaining, usage, light, rounds = out
+                      state0, max_rounds, unroll)
+    v_value, v_fixed, remaining, usage, light, rounds = out[:6]
     if return_carry:
-        return v_value, remaining, usage, rounds, out
+        return v_value, remaining, usage, rounds, out[:6], out[8]
     return v_value, remaining, usage, rounds
 
 
@@ -1057,8 +1128,10 @@ def _solve_kernel_chunk(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty,
                         unroll: bool = False, has_bounds: bool = True,
                         has_fatpipe: bool = True):
     """Run at most `chunk` more saturation rounds from `carry` (None =
-    fresh start) and return (values, remaining, usage, rounds, carry).
-    eps is static for the same reason as _solve_ell_chunk's."""
+    fresh start) and return (values, remaining, usage, rounds, carry,
+    bound_rounds): the last is how many of THIS dispatch's rounds took
+    the bound-first rule.  eps is static for the same reason as
+    _solve_ell_chunk's."""
     return fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty,
                     v_bound, jnp.asarray(eps, e_w.dtype), n_c, n_v,
                     axis=None, parallel_rounds=parallel_rounds,
@@ -1075,7 +1148,7 @@ def _solve_chunk_batched_lane(e_var, e_cnst, ew, cb, fat, pen, vb, carry,
                     jnp.asarray(eps, ew.dtype), n_c, n_v, axis=None,
                     parallel_rounds=parallel_rounds, carry=carry,
                     max_rounds=chunk, return_carry=True,
-                    has_bounds=has_bounds, has_fatpipe=has_fatpipe)
+                    has_bounds=has_bounds, has_fatpipe=has_fatpipe)[:5]
 
 
 @functools.partial(jax.jit,
@@ -1439,11 +1512,13 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
              ell.v_bound, ell.vc_w], device)
 
         def run_chunk(carry):
-            return _solve_ell_chunk(*args, carry, eps=eps_f,
-                                    parallel_rounds=parallel_rounds,
-                                    chunk=chunk, unroll=unroll,
-                                    has_bounds=has_bounds,
-                                    has_fatpipe=has_fatpipe)
+            # the ELL bodies do not count their bound rounds: None, so
+            # the counter is not bumped and reads "not counted", not 0
+            return (*_solve_ell_chunk(*args, carry, eps=eps_f,
+                                      parallel_rounds=parallel_rounds,
+                                      chunk=chunk, unroll=unroll,
+                                      has_bounds=has_bounds,
+                                      has_fatpipe=has_fatpipe), None)
     else:
         args = _device_args(
             "coo",
@@ -1462,27 +1537,34 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
 
     carry = None
     prev_progress = None
+    bound_rounds = 0
     while True:
         with opstats.span("solve.chunk"):
-            values, remaining, usage, rounds, carry = run_chunk(carry)
+            values, remaining, usage, rounds, carry, n_bound = \
+                run_chunk(carry)
             opstats.bump("dispatches")
             # ONE host sync per chunk: [rounds, light count, fixed
-            # count] AND the result vectors ride a single device->host
-            # transfer — a converged solve pays exactly one round-trip.
-            # Counts are exact in f32 (< 2^24).
+            # count, and on the COO path bound rounds] AND the result
+            # vectors ride a single device->host transfer — a converged
+            # solve pays exactly one round-trip.  Counts are exact in
+            # f32 (< 2^24).
             rdt = values.dtype
             n_vc, n_cc = values.shape[0], remaining.shape[0]
+            head = [rounds, jnp.count_nonzero(carry[4]),
+                    jnp.count_nonzero(carry[1])]
+            if n_bound is not None:
+                head.append(n_bound)
+            n_h = len(head)
             fetched = opstats.timed_fetch(jnp.concatenate([
-                jnp.stack([rounds.astype(rdt),
-                           jnp.count_nonzero(carry[4]).astype(rdt),
-                           jnp.count_nonzero(carry[1]).astype(rdt)]),
+                jnp.stack([h.astype(rdt) for h in head]),
                 values, remaining.astype(rdt), usage.astype(rdt)]))
         rounds, n_light, n_fixed = (int(fetched[0]), int(fetched[1]),
                                     int(fetched[2]))
+        bound_rounds += int(fetched[3:n_h].sum())
         if n_light == 0:
-            values = fetched[3:3 + n_vc]
-            remaining = fetched[3 + n_vc:3 + n_vc + n_cc]
-            usage = fetched[3 + n_vc + n_cc:3 + n_vc + 2 * n_cc]
+            values = fetched[n_h:n_h + n_vc]
+            remaining = fetched[n_h + n_vc:n_h + n_vc + n_cc]
+            usage = fetched[n_h + n_vc + n_cc:n_h + n_vc + 2 * n_cc]
             break
         if rounds >= _MAX_ROUNDS:
             raise SolveError(
@@ -1511,6 +1593,8 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
                 # compaction requires the live set to halve)
                 prev_progress = None
     opstats.bump("fixpoint_rounds", rounds)
+    if n_bound is not None:
+        opstats.bump("fixpoint_bound_rounds", bound_rounds)
     merged = (compactor.merge(values, remaining, usage)
               if compactor is not None else None)
     if merged is not None:

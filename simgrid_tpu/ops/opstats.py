@@ -8,6 +8,16 @@ context object through the solver entry points:
 * ``dispatches``            — device kernel dispatches (solver chunks,
                               drain advances/supersteps, warm solves)
 * ``fixpoint_rounds``       — saturation rounds executed on device
+* ``fixpoint_bound_rounds`` — the subset of a ``solve_arrays`` solve's
+                              COO rounds that took the bound-first
+                              rule (the local round's bound block
+                              behind its ``lax.cond``, the global
+                              round's min-bound branch): rides the
+                              chunk fetch beside the round count.  0
+                              on a system whose variable bounds never
+                              bind — the block was skipped every round;
+                              absent after ELL solves, which do not
+                              count
 * ``uploaded_bytes_full``   — host->device bytes shipped as whole
                               arrays (fresh ``device_put``)
 * ``uploaded_bytes_delta``  — host->device bytes shipped as indexed

@@ -1,0 +1,73 @@
+"""`tools/coo_round_probe.py` reads device times, so it measures on a
+TPU or not at all; its plumbing is checked here, on the CPU, at 128
+hosts, with nothing written anywhere."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOOL = os.path.join(ROOT, "tools", "coo_round_probe.py")
+
+
+@pytest.fixture(scope="module")
+def records(tmp_path_factory):
+    """Every reading at 128 hosts, from a process of its own (the tool
+    puts ``benchmarks/`` on ``sys.path``, as the benchmark's tests do),
+    through ``readings``: the part of the tool under ``main``'s device
+    check, writing nothing."""
+    code = ("import json, sys; sys.path.insert(0, sys.argv[1]); "
+            "import coo_round_probe as p; "
+            "p.readings(lambda **r: print(json.dumps(r)), None, "
+            "'tiny128-random', reps=1)")
+    done = subprocess.run(
+        [sys.executable, "-c", code, os.path.dirname(TOOL)],
+        cwd=tmp_path_factory.mktemp("probe"),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return [json.loads(line) for line in done.stdout.splitlines()]
+
+
+def test_without_a_tpu_it_measures_nothing(tmp_path):
+    """No fallback: exit 2, nothing on stdout, no record appended."""
+    out = os.path.join(ROOT, "chiprun_out", "coo_round_probe.jsonl")
+    before = os.path.getsize(out) if os.path.exists(out) else None
+    done = subprocess.run(
+        [sys.executable, TOOL, "--only", "ops"], cwd=tmp_path,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=120)
+    assert done.returncode == 2
+    assert done.stdout == ""
+    assert "no TPU" in done.stderr
+    assert (os.path.getsize(out) if os.path.exists(out) else None) == before
+
+
+def test_the_round_readings_at_128_hosts(records):
+    """Bounds that never bind skip the block every round; the binding
+    variant takes it, solo and as a vmapped lane beside one that does
+    not, and every lane has the reference's rates."""
+    by = {r["cell"]: r for r in records if r["what"] == "round"}
+    assert list(by) == ["solve-like", "solve-bind", "solve-vmap2",
+                        "drain-like"]
+    assert by["solve-like"]["bound_rounds"] == [0]
+    assert by["solve-bind"]["bound_rounds"][0] > 0
+    assert by["solve-bind"]["taken_round_ms"] > 0
+    assert by["solve-vmap2"]["rounds"] == (by["solve-bind"]["rounds"]
+                                           + by["solve-like"]["rounds"])
+    assert by["solve-vmap2"]["bound_rounds"] == [
+        by["solve-bind"]["bound_rounds"][0], 0]
+    for cell in ("solve-like", "solve-bind", "solve-vmap2"):
+        assert by[cell]["light_left"] == 0
+        assert max(by[cell]["rate_gap"]) < 2e-3     # the solve cell's limit
+    assert by["solve-vmap2"]["rates_sum"] == (
+        by["solve-bind"]["rates_sum"] + by["solve-like"]["rates_sum"])
+
+
+def test_every_op_kind_has_a_price_in_both_layouts(records):
+    ops = [r for r in records if r["what"] == "op"]
+    assert len({(r["layout"], r["op"]) for r in ops}) == len(ops) == 20
+    assert all(r["ms_per_op"] > 0 for r in ops)

@@ -1,0 +1,312 @@
+"""The COO round's budget of element-wide indexed ops, and the exactness
+of what keeps it small (ISSUE 28).
+
+On the chip one gather or scatter over the element list costs 9-11 ms
+at config #4's size whatever it moves (PERF.md §5), so the number of
+them a round issues IS its cost.  The first half counts them in the
+jaxpr of one round and pins what `lmm_jax.fixpoint` reaches, so an
+edit that adds a pass fails here, on a CPU.  The second half holds the
+three things the count rests on: the bound block skipped when no bound
+binds, element liveness carried (and rebuilt from a mid-solve carry),
+and the `lax.cond` under `vmap`."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from bench import build_arrays
+from simgrid_tpu.ops import (SharingPolicy, lmm_jax, make_new_maxmin_system,
+                             opstats)
+from simgrid_tpu.ops.lmm_batch import solve_arrays_batch
+from simgrid_tpu.utils.config import config
+
+INDEXED = ("gather", "scatter", "scatter-add", "scatter_add", "scatter-min",
+           "scatter_min", "scatter-max", "scatter_max", "scatter-mul",
+           "scatter_mul")
+
+#: sizes no two of which coincide, so "element-wide" is a size test
+N_C, N_V, DEG = 64, 256, 3
+
+
+def system(dtype=np.float64, seed=3, bounds=None, fatpipe=False):
+    """A bench-class COO system; ``bounds`` = "bind" | "never" | None."""
+    rng = np.random.default_rng(seed)
+    a = build_arrays(rng, N_C, N_V, DEG, dtype)
+    if bounds == "bind":
+        a.v_bound[:N_V // 2] = rng.uniform(0.01, 0.5, N_V // 2)
+    elif bounds == "never":
+        a.v_bound[:N_V // 2] = 1e6
+    if fatpipe:
+        a.c_fatpipe[:N_C // 4] = True
+    return a
+
+
+# ---------------------------------------------------------------------------
+# the count
+# ---------------------------------------------------------------------------
+
+def sub_jaxprs(eqn):
+    for val in eqn.params.values():
+        for v in (val if isinstance(val, (tuple, list)) else (val,)):
+            if hasattr(v, "jaxpr"):       # ClosedJaxpr
+                yield v.jaxpr
+            elif hasattr(v, "eqns"):
+                yield v
+
+
+def whole(count):
+    """A count with the branches of its conds summed in."""
+    outside, conds = count
+    return outside + sum(map(sum, conds))
+
+
+def count_indexed(jaxpr, n_elem):
+    """(element-wide gathers and scatters outside any cond, the same
+    per branch of each cond met): conds are not summed in, so a block
+    that is skipped at run time is budgeted on its own."""
+    outside, conds = 0, []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in INDEXED:
+            sizes = [int(np.prod(v.aval.shape))
+                     for v in list(eqn.invars) + list(eqn.outvars)
+                     if hasattr(v, "aval")]
+            if max(sizes) >= n_elem:
+                outside += 1
+        elif name == "cond":
+            conds.append([whole(count_indexed(br.jaxpr, n_elem))
+                          for br in eqn.params["branches"]])
+        else:
+            for sub in sub_jaxprs(eqn):
+                o, c = count_indexed(sub, n_elem)
+                outside += o
+                conds += c
+    return outside, conds
+
+
+def round_and_entry(parallel_rounds, has_bounds, has_fatpipe):
+    a = system(bounds="bind" if has_bounds else None, fatpipe=has_fatpipe)
+    n_c, n_v = len(a.c_bound), len(a.v_penalty)
+    n_elem = len(a.e_var)
+    assert n_elem not in (n_c, n_v) and n_elem > 3 * n_c
+
+    def run(*args):
+        return lmm_jax.fixpoint(*args, jnp.asarray(1e-9, a.e_w.dtype), n_c,
+                                n_v, parallel_rounds=parallel_rounds,
+                                return_carry=True, has_bounds=has_bounds,
+                                has_fatpipe=has_fatpipe)
+
+    closed = jax.make_jaxpr(run)(a.e_var, a.e_cnst, a.e_w, a.c_bound,
+                                 a.c_fatpipe, a.v_penalty, a.v_bound)
+    loops = [e for e in closed.jaxpr.eqns if e.primitive.name == "while"]
+    assert len(loops) == 1
+    body = count_indexed(loops[0].params["body_jaxpr"].jaxpr, n_elem)
+    entry = count_indexed(closed.jaxpr, n_elem)[0] - body[0]
+    return body, entry
+
+
+#: (local rounds, bounds, FATPIPE) -> (ops of a round outside any cond,
+#: ops of the cond's [skipped, taken] branches or None, ops at entry).
+#: Local: neighmin 4 (gather by e_cnst, scatter to v, gather by e_var,
+#: scatter to c) + level 2 + update 2 (one gather, one 3-wide scatter).
+BUDGETS = {
+    (True, False, False): (8, None, 6),
+    (True, False, True): (9, None, 6),
+    (True, True, False): (8, [0, 8], 6),
+    (True, True, True): (9, [0, 8], 6),
+    (False, False, False): (4, None, 6),
+    (False, False, True): (5, None, 6),
+    (False, True, False): (4, None, 6),
+    (False, True, True): (5, None, 6),
+}
+
+
+@pytest.mark.parametrize("parallel_rounds,has_bounds,has_fatpipe",
+                         sorted(BUDGETS))
+def test_round_issues_no_more_indexed_ops_than_budgeted(
+        parallel_rounds, has_bounds, has_fatpipe):
+    outside, block, entry = BUDGETS[parallel_rounds, has_bounds, has_fatpipe]
+    (got_outside, conds), got_entry = round_and_entry(
+        parallel_rounds, has_bounds, has_fatpipe)
+    assert got_outside <= outside
+    assert got_entry <= entry
+    if block is None:
+        assert conds == []
+    else:
+        assert len(conds) == 1
+        assert sorted(conds[0])[0] <= block[0]
+        assert sorted(conds[0])[1] <= block[1]
+        # under vmap both sides run: no dearer than the 12 ops the
+        # bound block cost before it stood behind a cond
+        assert 2 + sum(conds[0]) <= 12
+
+
+# ---------------------------------------------------------------------------
+# exactness
+# ---------------------------------------------------------------------------
+
+PRECISIONS = [(np.float64, 1e-9), (np.float32, 1e-5)]
+
+
+def solve_counting(arrays, eps, local, chunk=None):
+    before = opstats.snapshot()
+    out = lmm_jax.solve_arrays(arrays, eps, parallel_rounds=local,
+                               chunk=chunk)
+    took = opstats.diff(before).get("fixpoint_bound_rounds", 0)
+    return [np.asarray(x) for x in out[:3]] + [int(out[3])], took
+
+
+@pytest.mark.parametrize("local", [True, False], ids=["local", "global"])
+@pytest.mark.parametrize("dtype,eps", PRECISIONS, ids=["f64", "f32"])
+@pytest.mark.parametrize("fatpipe", [False, True], ids=["shared", "fatpipe"])
+def test_bounds_that_never_bind_change_nothing(dtype, eps, local, fatpipe):
+    """has_bounds=True on a system whose bounds sit over every level
+    takes the bound-free side each round and gives has_bounds=False's
+    results bit for bit."""
+    free, took_free = solve_counting(system(dtype, fatpipe=fatpipe), eps,
+                                     local)
+    held, took_held = solve_counting(
+        system(dtype, bounds="never", fatpipe=fatpipe), eps, local)
+    assert took_free == took_held == 0
+    assert free[3] == held[3]
+    for f, h in zip(free[:3], held[:3]):
+        np.testing.assert_array_equal(f, h)
+
+
+def host_system(seed, n_cnst=20, n_var=60):
+    """A random host System whose variable bounds bind, and its exact
+    list-solver rates."""
+    rng = np.random.default_rng(seed)
+    s = make_new_maxmin_system(False)
+    cnsts = [s.constraint_new(None, float(rng.uniform(1, 100)))
+             for _ in range(n_cnst)]
+    for c in cnsts[:n_cnst // 5]:
+        c.sharing_policy = SharingPolicy.FATPIPE
+    for _ in range(n_var):
+        bound = float(rng.uniform(0.05, 5)) if rng.random() < 0.5 else -1.0
+        var = s.variable_new(None, float(rng.choice([0.5, 1.0, 2.0])),
+                             bound, 3)
+        for ci in rng.choice(n_cnst, size=3, replace=False):
+            s.expand(cnsts[int(ci)], var, float(rng.choice([0.5, 1.0, 2.0])))
+    return s
+
+
+@pytest.mark.parametrize("local", [True, False], ids=["local", "global"])
+@pytest.mark.parametrize("dtype,eps,rtol", [(np.float64, 1e-9, 1e-9),
+                                            (np.float32, 1e-5, 1e-4)],
+                         ids=["f64", "f32"])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_binding_bounds_match_the_host_solver_and_are_counted(
+        seed, dtype, eps, rtol, local):
+    s = host_system(seed)
+    arrays, vars_in_order = lmm_jax.flatten(
+        list(s.active_constraint_set), dtype)
+    got, took = solve_counting(arrays, eps, local)
+    s.solve()
+    want = np.array([v.value for v in vars_in_order])
+    np.testing.assert_allclose(got[0][:len(want)], want, rtol=rtol,
+                               atol=rtol)
+    assert 0 < took <= got[3]
+
+
+@pytest.mark.parametrize("chunk", [1, 3])
+@pytest.mark.parametrize("local", [True, False], ids=["local", "global"])
+@pytest.mark.parametrize("dtype,eps", PRECISIONS, ids=["f64", "f32"])
+def test_a_carry_handed_back_rebuilds_liveness(dtype, eps, local, chunk):
+    """Chunked solves re-enter `fixpoint` with a mid-solve carry: the
+    element liveness and live counts the loop keeps are rebuilt from
+    the carry's v_fixed, so every chunking gives the one-shot solve,
+    and the chunks' bound rounds add up to its count."""
+    arrays = system(dtype, bounds="bind", fatpipe=True)
+    assert arrays.n_elem < lmm_jax._COMPACT_MIN_ELEMS   # the carry alone
+    whole, took_whole = solve_counting(arrays, eps, local)
+    parts, took_parts = solve_counting(arrays, eps, local, chunk=chunk)
+    assert whole[3] == parts[3] > chunk
+    assert took_whole == took_parts > 0
+    for w, p in zip(whole[:3], parts[:3]):
+        np.testing.assert_array_equal(w, p)
+
+
+@pytest.mark.parametrize("local", [True, False], ids=["local", "global"])
+@pytest.mark.parametrize("dtype,eps", PRECISIONS, ids=["f64", "f32"])
+def test_vmapped_lanes_take_their_own_side_of_the_cond(dtype, eps, local):
+    """Under vmap the cond is a select: a lane whose bounds bind and a
+    lane whose bounds never do each get their solo results."""
+    lanes = [system(dtype, bounds="bind"), system(dtype, bounds="never")]
+    solo = [solve_counting(a, eps, local)[0] for a in lanes]
+    a = lanes[0]
+    vals, rem, use, rounds = solve_arrays_batch(
+        a.e_var, a.e_cnst, a.e_w,
+        np.stack([x.c_bound for x in lanes]), a.c_fatpipe,
+        np.stack([x.v_penalty for x in lanes]),
+        np.stack([x.v_bound for x in lanes]), eps, parallel_rounds=local)
+    for b, (v, r, u, n) in enumerate(solo):
+        assert int(rounds[b]) == n
+        np.testing.assert_array_equal(np.asarray(vals[b]), v)
+        np.testing.assert_array_equal(np.asarray(rem[b]), r)
+        np.testing.assert_array_equal(np.asarray(use[b]), u)
+    assert solo[0][3] != solo[1][3] or not np.array_equal(solo[0][0],
+                                                          solo[1][0])
+
+
+@pytest.mark.parametrize("local", [True, False], ids=["local", "global"])
+def test_a_bound_still_binds_where_the_level_overflows(local):
+    """A light constraint whose remaining/usage overflows f32 has rou
+    inf: its flow is saturated all the same and takes its bound.  The
+    level scatter must therefore tell "no processable element" from "a
+    processable element at inf" (`isfinite(level)` alone cannot)."""
+    n = 16
+    a = lmm_jax.LmmArrays(
+        e_var=np.zeros(n, np.int32), e_cnst=np.zeros(n, np.int32),
+        e_w=np.zeros(n, np.float32), c_bound=np.zeros(n, np.float32),
+        c_fatpipe=np.zeros(n, bool), v_penalty=np.zeros(n, np.float32),
+        v_bound=np.full(n, -1, np.float32), n_elem=2, n_cnst=2, n_var=2)
+    a.e_var[:2], a.e_cnst[:2], a.e_w[:2] = [0, 1], [0, 1], [1e-10, 1.0]
+    a.c_bound[:2], a.v_penalty[:2], a.v_bound[:2] = [1e30, 10], 1, [5, -1]
+    assert 1e30 / 1e-10 > float(np.finfo(np.float32).max)
+    got, took = solve_counting(a, 1e-5, local)
+    np.testing.assert_array_equal(got[0][:2], [5.0, 10.0])
+    assert took == 1
+
+
+def test_the_ell_layout_leaves_the_counter_alone():
+    """The ELL bodies do not count their bound rounds: a solve there,
+    its bounds binding, must leave `fixpoint_bound_rounds` absent ("not
+    counted"), not at a 0 that would read "skipped every round"."""
+    arrays = system(np.float64, bounds="bind")
+    opstats.reset()
+    config["lmm/layout"] = "ell"
+    try:
+        lmm_jax.solve_arrays(arrays, 1e-9, parallel_rounds=True)
+    finally:
+        config["lmm/layout"] = "auto"
+    assert opstats.snapshot()["fixpoint_rounds"] > 0
+    assert "fixpoint_bound_rounds" not in opstats.snapshot()
+    lmm_jax.solve_arrays(arrays, 1e-9, parallel_rounds=True)
+    assert opstats.snapshot()["fixpoint_bound_rounds"] > 0
+
+
+@pytest.mark.parametrize("dtype,fits", [(np.float32, False),
+                                        (np.float64, True)],
+                         ids=["f32", "f64"])
+def test_more_elements_than_the_dtype_counts_are_refused(dtype, fits):
+    """The fixed-element count rides the float scatter of d_rem/d_use:
+    exact only while a constraint cannot hold 2^24 elements in f32."""
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    def run(*args):
+        return lmm_jax.fixpoint(*args, jnp.asarray(1e-5, dtype), 8, 8,
+                                parallel_rounds=True, has_bounds=False,
+                                has_fatpipe=False)
+
+    args = (spec((1 << 24,), np.int32), spec((1 << 24,), np.int32),
+            spec((1 << 24,), dtype), spec((8,), dtype), spec((8,), bool),
+            spec((8,), dtype), spec((8,), dtype))
+    if fits:
+        jax.eval_shape(run, *args)
+    else:
+        with pytest.raises(ValueError, match="counts exactly"):
+            jax.eval_shape(run, *args)
