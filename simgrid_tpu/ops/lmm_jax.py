@@ -431,9 +431,11 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     between chunks (a non-converging f32 solve must come back to the
     host and raise, not spin inside one dispatch).  With
     ``return_carry`` the result is ``(values, remaining, usage, rounds,
-    carry, bound_rounds)``: the 6-tuple carry to hand back in, and the
-    number of this call's rounds that took the bound-first rule (the
-    local round's bound block, the global round's min-bound branch).
+    carry, bound_rounds, live_elem_rounds)``: the 6-tuple carry to hand
+    back in, the number of this call's rounds that took the bound-first
+    rule (the local round's bound block, the global round's min-bound
+    branch), and the live elements its rounds entered with, summed over
+    them (:func:`_live_elem_rounds` reads the pair).
     """
     dtype = e_w.dtype
     inf = jnp.array(jnp.inf, dtype)
@@ -508,7 +510,13 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
         fixes are live ones and next round's liveness is this round's
         minus them."""
         (v_value, v_fixed, remaining, usage, light, it,
-         e_live, n_live_c, bound_rounds) = state
+         e_live, n_live_c, bound_rounds, live_rounds) = state
+        # The live elements this round entered with, added to an exact
+        # [high, low] pair of int32 (low under 2^20): a chunk's sum
+        # passes 2^24 at config #4's width and 2^31 on a deep solve.
+        low = live_rounds[1] + jnp.sum(n_live_c, dtype=jnp.int32)
+        live_rounds = jnp.stack([live_rounds[0] + (low >> _LIVE_LOW_BITS),
+                                 low & ((1 << _LIVE_LOW_BITS) - 1)])
         with jax.named_scope("sg.lmm.update"):
             v_value = jnp.where(fix_now, new_value, v_value)
             v_fixed = v_fixed | fix_now
@@ -581,7 +589,7 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
             light = light & (n_live_c > 0)
         return (v_value, v_fixed, remaining, usage, light, it + 1,
                 e_live, n_live_c,
-                bound_rounds + took_bounds.astype(jnp.int32))
+                bound_rounds + took_bounds.astype(jnp.int32), live_rounds)
 
     def body_global(state):
         """One global bottleneck level per round (reference order,
@@ -729,13 +737,27 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
                                        None)
         return level2_v, fix_bound, any_low
 
-    state0 = (*carry, e_live0, n_live_c0, jnp.array(0, jnp.int32))
+    state0 = (*carry, e_live0, n_live_c0, jnp.array(0, jnp.int32),
+              jnp.zeros(2, jnp.int32))
     out = _run_rounds(cond, body_local if parallel_rounds else body_global,
                       state0, max_rounds, unroll)
     v_value, v_fixed, remaining, usage, light, rounds = out[:6]
     if return_carry:
-        return v_value, remaining, usage, rounds, out[:6], out[8]
+        return v_value, remaining, usage, rounds, out[:6], out[8], out[9]
     return v_value, remaining, usage, rounds
+
+
+#: ``fixpoint`` sums the live elements of its rounds in two int32:
+#: [whole multiples of 2^20, the rest].  Each half is exact as a
+#: float32 too (the chunk fetch ships its head in the solve's dtype)
+#: while the sum stays under 2^44: 2^24 elements over 2^20 rounds.
+_LIVE_LOW_BITS = 20
+
+
+def _live_elem_rounds(pair) -> int:
+    """The exact count behind ``fixpoint``'s ``live_elem_rounds`` pair
+    (a host array, of any number dtype)."""
+    return (int(pair[0]) << _LIVE_LOW_BITS) + int(pair[1])
 
 
 def _vc_round_body(vc_cnst, vc_w, vc_valid, v_penalty, c_bound,
@@ -1129,8 +1151,9 @@ def _solve_kernel_chunk(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty,
                         has_fatpipe: bool = True):
     """Run at most `chunk` more saturation rounds from `carry` (None =
     fresh start) and return (values, remaining, usage, rounds, carry,
-    bound_rounds): the last is how many of THIS dispatch's rounds took
-    the bound-first rule.  eps is static for the same reason as
+    bound_rounds, live_elem_rounds): how many of THIS dispatch's rounds
+    took the bound-first rule, and the live elements its rounds entered
+    with (fixpoint's pair).  eps is static for the same reason as
     _solve_ell_chunk's."""
     return fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty,
                     v_bound, jnp.asarray(eps, e_w.dtype), n_c, n_v,
@@ -1512,13 +1535,14 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
              ell.v_bound, ell.vc_w], device)
 
         def run_chunk(carry):
-            # the ELL bodies do not count their bound rounds: None, so
-            # the counter is not bumped and reads "not counted", not 0
+            # the ELL bodies count neither their bound rounds nor their
+            # live elements: None, so the counters are not bumped and
+            # read "not counted", not 0
             return (*_solve_ell_chunk(*args, carry, eps=eps_f,
                                       parallel_rounds=parallel_rounds,
                                       chunk=chunk, unroll=unroll,
                                       has_bounds=has_bounds,
-                                      has_fatpipe=has_fatpipe), None)
+                                      has_fatpipe=has_fatpipe), None, None)
     else:
         args = _device_args(
             "coo",
@@ -1537,30 +1561,33 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
 
     carry = None
     prev_progress = None
-    bound_rounds = 0
+    bound_rounds = live_elem_rounds = 0
     while True:
         with opstats.span("solve.chunk"):
-            values, remaining, usage, rounds, carry, n_bound = \
+            values, remaining, usage, rounds, carry, n_bound, n_live = \
                 run_chunk(carry)
             opstats.bump("dispatches")
             # ONE host sync per chunk: [rounds, light count, fixed
-            # count, and on the COO path bound rounds] AND the result
-            # vectors ride a single device->host transfer — a converged
-            # solve pays exactly one round-trip.  Counts are exact in
-            # f32 (< 2^24).
+            # count, and on the COO path bound rounds and the live
+            # elements' pair] AND the result vectors ride a single
+            # device->host transfer — a converged solve pays exactly
+            # one round-trip.  Counts are exact in f32 (< 2^24; the
+            # live elements come as two halves that are).
             rdt = values.dtype
             n_vc, n_cc = values.shape[0], remaining.shape[0]
             head = [rounds, jnp.count_nonzero(carry[4]),
                     jnp.count_nonzero(carry[1])]
             if n_bound is not None:
-                head.append(n_bound)
+                head += [n_bound, n_live[0], n_live[1]]
             n_h = len(head)
             fetched = opstats.timed_fetch(jnp.concatenate([
                 jnp.stack([h.astype(rdt) for h in head]),
                 values, remaining.astype(rdt), usage.astype(rdt)]))
         rounds, n_light, n_fixed = (int(fetched[0]), int(fetched[1]),
                                     int(fetched[2]))
-        bound_rounds += int(fetched[3:n_h].sum())
+        if n_bound is not None:
+            bound_rounds += int(fetched[3])
+            live_elem_rounds += _live_elem_rounds(fetched[4:6])
         if n_light == 0:
             values = fetched[n_h:n_h + n_vc]
             remaining = fetched[n_h + n_vc:n_h + n_vc + n_cc]
@@ -1595,6 +1622,7 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
     opstats.bump("fixpoint_rounds", rounds)
     if n_bound is not None:
         opstats.bump("fixpoint_bound_rounds", bound_rounds)
+        opstats.bump("fixpoint_live_elem_rounds", live_elem_rounds)
     merged = (compactor.merge(values, remaining, usage)
               if compactor is not None else None)
     if merged is not None:

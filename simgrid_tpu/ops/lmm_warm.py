@@ -683,7 +683,7 @@ class WarmSolver:
                     unroll=False, has_bounds=has_bounds,
                     has_fatpipe=has_fatpipe)
             else:
-                values, remaining, usage, rounds, carry, _ = \
+                values, remaining, usage, rounds, carry, _, _ = \
                     _solve_kernel_chunk(
                         *st.masters, carry, eps=eps_f, n_c=n_c, n_v=n_v,
                         parallel_rounds=parallel, chunk=chunk,

@@ -18,6 +18,17 @@ context object through the solver entry points:
                               bind — the block was skipped every round;
                               absent after ELL solves, which do not
                               count
+* ``fixpoint_live_elem_rounds`` — the live elements (valid, their
+                              variable not fixed yet) those same COO
+                              rounds ENTERED with, summed over the
+                              rounds: what an element-wide gather or
+                              scatter of the round had to work on.
+                              Over ``fixpoint_rounds`` x the element
+                              count it is the mean live share, and its
+                              inverse the most a perfect compaction
+                              can gain.  Exact: ``fixpoint`` carries it
+                              as two int32 halves, the chunk fetch
+                              ships both; absent after ELL solves
 * ``uploaded_bytes_full``   — host->device bytes shipped as whole
                               arrays (fresh ``device_put``)
 * ``uploaded_bytes_delta``  — host->device bytes shipped as indexed

@@ -9,6 +9,15 @@ spread over the hosts, flattens the LMM system, then times:
   - JAX solve_arrays (the production device path), warm, median of 3
 
 Prints a JSON line; append with --out.
+
+This is an unguarded probe: nothing holds its rates to a reference.
+The guarded twin of its system (same platform, ranks, placement and
+block size) is the benchmark cell ``dfly65k-alltoall.solve``:
+``benchmarks/configs/dfly65k-alltoall.json`` names this tool as the
+source of its shape, ``benchmarks/drivers/solve_alltoall.py`` posts the
+same 102,080 flows and times ``solve_arrays`` on the chip, and
+``benchmarks/tools/limits.py --cells dfly65k-alltoall.solve`` reads
+``rate_gap`` against the float64 reference and its bfloat16 control.
 """
 import argparse
 import json
