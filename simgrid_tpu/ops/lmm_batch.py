@@ -80,8 +80,8 @@ from . import opstats
 from .device import default_platform, solve_dtype
 from .lmm_jax import (_MAX_ROUNDS, SolveError, _solve_kernel_chunk_batched,
                       _solve_kernel_chunk_batched_fresh)
-from .lmm_drain import (_FLAG_BUDGET, _FLAG_OK, _FLAG_STALLED, _ZERO_BITS,
-                        _pos_group, _fused_step_program,
+from .lmm_drain import (_FLAG_BUDGET, _FLAG_OK, _FLAG_STALLED, _STATS_HEAD,
+                        _ZERO_BITS, _pos_group, _fused_step_program,
                         _superstep_program, _to2d)
 
 
@@ -1373,7 +1373,7 @@ class BatchDrainSim:
         n_v = self.n_v
         ring_n = (n_v + (k_max if self.has_tape else 0)
                   + (n_v if self.has_coll else 0))
-        o = 7
+        o = _STATS_HEAD
         stuck: List[int] = []
         deaths = 0
         fired = 0

@@ -94,8 +94,9 @@ from jax import lax
 
 from . import opstats
 from .device import default_platform, solve_dtype
-from .lmm_jax import (_MAX_ROUNDS, SolveError, _bucket, _pos_group,
-                      _stable_livefirst_perm, fixpoint)
+from .lmm_jax import (_MAX_ROUNDS, SolveError, _bucket, _live_elem_rounds,
+                      _pair_add, _pos_group, _stable_livefirst_perm,
+                      fixpoint)
 
 
 def _to2d(a: np.ndarray, group: int = 8) -> np.ndarray:
@@ -238,6 +239,11 @@ _drain_fused_step = functools.partial(
                               "has_bounds"))(_fused_step_program)
 
 
+#: scalars at the head of the superstep's packed vector: rounds,
+#: advances, events, clock, live flows, flag, live elements, and the
+#: elements its rounds indexed as fixpoint's [high, low] pair
+_STATS_HEAD = 9
+
 #: superstep completion flags (stats slot 5)
 _FLAG_OK = 0          # exited on k / live-count / natural completion
 _FLAG_STALLED = 1     # no flow holds bandwidth (dt not finite)
@@ -285,7 +291,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     stalled plan (dt = inf with a pending tape date is a wake-up, not
     a stall), mirroring how a Profile event re-arms an idle engine.
     With ``has_tape=False`` the tape arguments are ignored and the
-    loop state/HLO are exactly the legacy 12-tuple.
+    loop state is the plain 13-tuple.
 
     ``has_coll`` arms the COLLECTIVE SCHEDULE TAPE: the flow set is a
     compiled communication DAG (collectives.tape) whose dormant flows
@@ -341,9 +347,9 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                 & alive)
 
     def body(st):
-        idx = 12
+        idx = 13
         (pen_c, rem_c, t_sum, t_comp, ring_t, ring_id, adv_dt,
-         adv_nev, n_ev, adv, rounds, flag) = st[:12]
+         adv_nev, n_ev, adv, rounds, flag, worked) = st[:13]
         if has_tape:
             cb_c, tpos = st[idx], st[idx + 1]
             idx += 2
@@ -480,7 +486,8 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                       jnp.where(ok, adv_dt2, adv_dt),
                       jnp.where(ok, adv_nev2, adv_nev),
                       sel(n_new, n_ev),
-                      adv + ok.astype(jnp.int32), rounds + r, flag2)
+                      adv + ok.astype(jnp.int32), rounds + r, flag2,
+                      _pair_add(worked, out[7][1], out[7][0]))
             if has_tape:
                 out_st = out_st + (jnp.where(ok, cb2, cb_c),
                                    jnp.where(ok, tpos2, tpos))
@@ -500,15 +507,15 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     st0 = (pen, rem) + clk0 + (
            jnp.zeros(ring_n, dtype), jnp.zeros(ring_n, jnp.int32),
            jnp.zeros(k_max, dtype), jnp.zeros(k_max, jnp.int32),
-           zero, zero, zero, zero)
+           zero, zero, zero, zero, jnp.zeros(2, jnp.int32))
     if has_tape:
         st0 = st0 + (c_bound, jnp.asarray(tape_pos, jnp.int32))
     if has_coll:
         st0 = st0 + (coll_pred, coll_ready)
     st = lax.while_loop(cond, body, st0)
     (pen_o, rem_o, t_sum, t_comp_o, ring_t, ring_id, adv_dt, adv_nev,
-     n_ev, adv, rounds, flag) = st[:12]
-    idx = 12
+     n_ev, adv, rounds, flag, worked) = st[:13]
+    idx = 13
     if has_tape:
         cb_o, tpos_o = st[idx], st[idx + 1]
         idx += 2
@@ -528,7 +535,8 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         stats = jnp.stack([rounds.astype(dtype), adv.astype(dtype),
                            n_ev.astype(dtype), t_sum,
                            n_live.astype(dtype), flag.astype(dtype),
-                           live_elems.astype(dtype)])
+                           live_elems.astype(dtype),
+                           *worked.astype(dtype)])
         packed = jnp.concatenate([stats, adv_dt, adv_nev.astype(dtype),
                                   ring_t, ring_id.astype(dtype)])
     return pen_o, rem_o, cb_o, tpos_o, pred_o, ready_o, clk_o, packed
@@ -1347,6 +1355,8 @@ class DrainSim:
 
             self.rounds += rounds
             opstats.bump("fixpoint_rounds", rounds)
+            opstats.bump("fixpoint_worked_elem_rounds",
+                         _live_elem_rounds(p[7:9]))
             self.advances += adv
             with opstats.span("drain.demux"):
                 batches, fired = self._demux(p, adv, tok.k_max, t_sum)
@@ -1387,7 +1397,7 @@ class DrainSim:
         collective streams) and the f64 master clock; returns the
         per-advance ``(dt, [flow ids])`` batches and how many fault
         entries fired."""
-        o = 7
+        o = _STATS_HEAD
         adv_dt = p[o:o + k_max]
         adv_nev = p[o + k_max:o + 2 * k_max].astype(np.int64)
         o += 2 * k_max

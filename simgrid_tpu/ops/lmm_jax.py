@@ -431,11 +431,34 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     between chunks (a non-converging f32 solve must come back to the
     host and raise, not spin inside one dispatch).  With
     ``return_carry`` the result is ``(values, remaining, usage, rounds,
-    carry, bound_rounds, live_elem_rounds)``: the 6-tuple carry to hand
-    back in, the number of this call's rounds that took the bound-first
-    rule (the local round's bound block, the global round's min-bound
-    branch), and the live elements its rounds entered with, summed over
-    them (:func:`_live_elem_rounds` reads the pair).
+    carry, bound_rounds, live_elem_rounds, worked_elem_rounds,
+    partitions)``: the 6-tuple carry to hand back in, the number of this
+    call's rounds that took the bound-first rule (the local round's
+    bound block, the global round's min-bound branch), the live
+    elements its rounds entered with and the elements they indexed
+    (the rung's size), each summed over them (:func:`_live_elem_rounds`
+    reads either pair), and the partitions the ladder ran.
+
+    THE LADDER.  An element-wide gather or scatter costs by the index,
+    live or dead (PERF.md §5), so the round loop is a ladder of round
+    loops over element lists of falling static size
+    (:func:`_ladder_sizes`): rung ``s`` runs today's round over its own
+    list while more elements are live than rung ``s + 1`` holds, then
+    the list is put live-first, STABLY, and its head kept
+    (:func:`_livefirst_head`).  Survivors keep their order, so every
+    segment reduction sees its live terms in the order of the single
+    loop, and a dead element only ever contributed an identity (0.0 to
+    the sums and maxes, inf to the mins): the results are the single
+    loop's bit for bit.  The n_c- and n_v-wide state is never
+    renumbered, so nothing is merged at the end.  A stage also ends on
+    convergence or on ``max_rounds``; every later stage's condition is
+    then false as well, so NO ROUND EVER RUNS ON A LIST THAT WAS CUT
+    WHILE MORE WERE LIVE THAN IT HOLDS (such a list is only a slice
+    nobody reads), and the caller's carry is the 6-tuple from which the
+    next call rebuilds liveness at full width and walks down again.  A
+    list of up to ``2 * _LADDER_MIN_ELEMS`` has one rung: the program it
+    lowered to before the ladder.  ``unroll=True`` keeps the single
+    loop.
     """
     dtype = e_w.dtype
     inf = jnp.array(jnp.inf, dtype)
@@ -443,7 +466,8 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     # apply_fixes counts a round's fixed elements per constraint in
     # `dtype`: exact while no constraint can hold more elements than
     # the dtype has consecutive integers (2^24 in f32).
-    n_elems = e_var.size * (lax.psum(1, axis) if axis else 1)
+    n_shards = lax.psum(1, axis) if axis else 1
+    n_elems = e_var.size * n_shards
     if n_elems >= 2 ** (jnp.finfo(dtype).nmant + 1):
         raise ValueError(
             f"{n_elems} elements: more than {dtype.name} counts exactly "
@@ -498,25 +522,33 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     if max_rounds is None:
         max_rounds = _MAX_ROUNDS
 
+    def live_elems(e_live, n_live_c):
+        """What the ladder's rung test reads: the live elements of the
+        list (of the fullest shard's under ``axis``, so that all shards
+        step down together)."""
+        if axis:
+            return allmax(jnp.count_nonzero(e_live).astype(jnp.int32))
+        return jnp.sum(n_live_c, dtype=jnp.int32)
+
     def cond(state):
         light, it = state[4], state[5]
         return (jnp.any(light) & (it < _MAX_ROUNDS)
                 & (it - start_it < max_rounds))
 
-    def apply_fixes(state, fix_now, new_value, took_bounds):
+    def apply_fixes(elems, state, fix_now, new_value, took_bounds):
         """Shared round tail: write fixed values, batched double_update of
         every touched constraint, epsilon-based light-set pruning.
         ``fix_now`` only ever holds unfixed variables, so the elements it
         fixes are live ones and next round's liveness is this round's
         minus them."""
-        (v_value, v_fixed, remaining, usage, light, it,
-         e_live, n_live_c, bound_rounds, live_rounds) = state
-        # The live elements this round entered with, added to an exact
-        # [high, low] pair of int32 (low under 2^20): a chunk's sum
-        # passes 2^24 at config #4's width and 2^31 on a deep solve.
-        low = live_rounds[1] + jnp.sum(n_live_c, dtype=jnp.int32)
-        live_rounds = jnp.stack([live_rounds[0] + (low >> _LIVE_LOW_BITS),
-                                 low & ((1 << _LIVE_LOW_BITS) - 1)])
+        e_var, e_cnst, e_w, e_upen = elems
+        (v_value, v_fixed, remaining, usage, light, it, e_live, n_live_c,
+         _, bound_rounds, live_rounds, worked_rounds) = state
+        # The live elements this round entered with, and the elements
+        # its indexed ops run over: the rung's size.
+        live_rounds = _pair_add(live_rounds,
+                                jnp.sum(n_live_c, dtype=jnp.int32))
+        worked_rounds = _pair_add(worked_rounds, e_var.size * n_shards)
         with jax.named_scope("sg.lmm.update"):
             v_value = jnp.where(fix_now, new_value, v_value)
             v_fixed = v_fixed | fix_now
@@ -588,12 +620,14 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
             # constraint until _MAX_ROUNDS.
             light = light & (n_live_c > 0)
         return (v_value, v_fixed, remaining, usage, light, it + 1,
-                e_live, n_live_c,
-                bound_rounds + took_bounds.astype(jnp.int32), live_rounds)
+                e_live, n_live_c, live_elems(e_live, n_live_c),
+                bound_rounds + took_bounds.astype(jnp.int32), live_rounds,
+                worked_rounds)
 
-    def body_global(state):
+    def body_global(elems, state):
         """One global bottleneck level per round (reference order,
         maxmin.cpp:560-680)."""
+        e_var, e_cnst = elems[:2]
         v_value, v_fixed, remaining, usage, light, it, e_live = state[:7]
 
         with jax.named_scope("sg.lmm.neighmin"):
@@ -631,15 +665,16 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
                 new_value = jnp.where(
                     use_bounds, v_bound,
                     min_usage / jnp.where(v_enabled, v_penalty, 1.0))
-        return apply_fixes(state, fix_now, new_value, use_bounds)
+        return apply_fixes(elems, state, fix_now, new_value, use_bounds)
 
-    def body_local(state):
+    def body_local(elems, state):
         """Fix every local-minimum constraint per round.  Exact: a
         constraint's rou = remaining/usage only ever increases when other
         variables are fixed (fixing removes a below-average contribution),
         so a constraint whose rou is minimal among every constraint it
         shares a live variable with already sits at its final level, no
         matter in which order the rest of the graph saturates."""
+        e_var, e_cnst = elems[:2]
         v_value, v_fixed, remaining, usage, light, it, e_live = state[:7]
 
         with jax.named_scope("sg.lmm.neighmin"):
@@ -671,17 +706,18 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
                 any_low = jnp.array(False, jnp.bool_)
             else:
                 level2_v, fix_bound, any_low = bounded_level(
-                    e_live, e_rou, e_proc, processable, nmin_v)
+                    elems, e_live, e_rou, e_proc, processable, nmin_v)
                 fix_level = jnp.isfinite(level2_v) & ~v_fixed & ~fix_bound
                 fix_now = fix_bound | fix_level
                 new_value = jnp.where(
                     fix_bound, v_bound,
                     level2_v / jnp.where(v_enabled, v_penalty, 1.0))
-        return apply_fixes(state, fix_now, new_value, any_low)
+        return apply_fixes(elems, state, fix_now, new_value, any_low)
 
-    def bounded_level(e_live, e_rou, e_proc, processable, level_v):
+    def bounded_level(elems, e_live, e_rou, e_proc, processable, level_v):
         """The local round's level under variable bounds: (level2_v,
         fix_bound, whether the bound block ran)."""
+        e_var, e_cnst = elems[:2]
         # The bound-free level first: it is the answer whenever no bound
         # binds, and it tells the saturated variables.  A processable
         # element sends its rou capped at the largest finite number, so
@@ -737,26 +773,133 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
                                        None)
         return level2_v, fix_bound, any_low
 
-    state0 = (*carry, e_live0, n_live_c0, jnp.array(0, jnp.int32),
-              jnp.zeros(2, jnp.int32))
-    out = _run_rounds(cond, body_local if parallel_rounds else body_global,
-                      state0, max_rounds, unroll)
-    v_value, v_fixed, remaining, usage, light, rounds = out[:6]
+    body = body_local if parallel_rounds else body_global
+    elems = (e_var, e_cnst, e_w, e_upen)
+    state = (*carry, e_live0, n_live_c0, live_elems(e_live0, n_live_c0),
+             jnp.array(0, jnp.int32), jnp.zeros(2, jnp.int32),
+             jnp.zeros(2, jnp.int32))
+    partitions = jnp.array(0, jnp.int32)
+    sizes = [e_var.size] if unroll else _ladder_sizes(e_var.shape)
+    for size, below in zip(sizes, sizes[1:]):
+        entered = state[5]
+        state = lax.while_loop(
+            lambda st: cond(st) & (st[8] > below),
+            functools.partial(body, elems), state)
+        # Rounds are left to run (else the next list is never read): put
+        # this one live-first and keep its head.  One that came out of a
+        # partition and saw no round since is live-first already, and
+        # entry's padding is dead from the start, so rungs skipped in
+        # one step (random pairs fall from 100 % live to 4 % in a round)
+        # cost one partition and a slice each.
+        part = cond(state) & ((state[5] > entered) | (size == sizes[0]))
+        with jax.named_scope("sg.lmm.partition"):
+            *elems, e_live = lax.cond(
+                part, functools.partial(_livefirst_head, n_keep=below),
+                lambda *lists: tuple(_head(a, below) for a in lists),
+                *elems, state[6])
+        state = (*state[:6], e_live, *state[7:])
+        partitions = partitions + part.astype(jnp.int32)
+    state = _run_rounds(cond, functools.partial(body, tuple(elems)), state,
+                        max_rounds, unroll)
+    v_value, v_fixed, remaining, usage, light, rounds = state[:6]
     if return_carry:
-        return v_value, remaining, usage, rounds, out[:6], out[8], out[9]
+        return (v_value, remaining, usage, rounds, state[:6], state[9],
+                state[10], state[11], partitions)
     return v_value, remaining, usage, rounds
 
 
-#: ``fixpoint`` sums the live elements of its rounds in two int32:
-#: [whole multiples of 2^20, the rest].  Each half is exact as a
-#: float32 too (the chunk fetch ships its head in the solve's dtype)
-#: while the sum stays under 2^44: 2^24 elements over 2^20 rounds.
+#: Every rung of the ladder holds MORE than this many elements.  Down
+#: there a round stops going by its indices (``tools/coo_round_probe.py
+#: --only ladder``, PERF.md §5: 4.97 ms on 32,768 elements in a 1-D
+#: list against 4.78 on 65,536, its 3-wide scatter 3.42 against 1.54;
+#: the drain's [E / 8, 8] list still halves, 5.19 ms on 77,608 to 2.72
+#: on 38,808), and every rung is one more copy of the round for XLA to
+#: compile, ~8 s each at config #4's width.  A list of up to twice
+#: this has one rung: the single loop.
+_LADDER_MIN_ELEMS = 1 << 15
+#: Each rung holds this share of the one above (rounded up to whole
+#: index groups), so a round indexes under ``_LADDER_RATIO`` times its
+#: live elements.  A finer ratio would index less (sqrt 2: 44 % of
+#: rounds x n_elem in the alltoall where 2 reads 54 %) in twice the
+#: rungs.
+_LADDER_RATIO = 2
+
+
+def _ladder_sizes(shape) -> List[int]:
+    """The static element counts of ``fixpoint``'s rungs for an element
+    list of ``shape`` ([E], or the drain's [E / g, g]), the list's own
+    first.  Each is a whole number of scatter index groups, so a rung
+    keeps the shape convention of the list it was cut from."""
+    size = int(np.prod(shape))
+    group = shape[-1] if len(shape) == 2 else _pos_group(size)
+    sizes = [size]
+    while True:
+        below = -(-sizes[-1] // (_LADDER_RATIO * group)) * group
+        if not _LADDER_MIN_ELEMS < below < sizes[-1]:
+            return sizes
+        sizes.append(below)
+
+
+def _head(a, n: int):
+    """The first ``n`` elements of an element list, in its shape
+    convention."""
+    return a[:n] if a.ndim == 1 else a[:n // a.shape[1]]
+
+
+def _livefirst_head(*lists_and_live, n_keep: int):
+    """Element lists and their liveness (last), partitioned live-first
+    and cut to the first ``n_keep``: stable, so the survivors keep their
+    relative order (see :func:`_stable_livefirst_perm`).  One scatter
+    over the list builds the permutation and ONE gather over the kept
+    head moves every list, side by side as rows of int32 words: a
+    gather costs by the index, not by what it moves.  The liveness of
+    the head needs none: its first ``n_live`` are the live ones.
+
+    On the chip, 2,097,152 -> 1,048,576 elements: 16.4 ms so, 62.7 ms
+    with a gather a list; one stable ``lax.sort`` carrying the lists as
+    payload runs in 6.8 ms, but with a sort a rung the program takes
+    1.6-1.7x as long to compile, and a solve partitions twice
+    (``tools/coo_round_probe.py --only ladder``, PERF.md §5)."""
+    *lists, e_live = lists_and_live
+    live = e_live.reshape(-1)
+    group = e_live.shape[-1] if e_live.ndim == 2 else _pos_group(live.size)
+    keep = _head(_stable_livefirst_perm(live, group).reshape(e_live.shape),
+                 n_keep)
+    words = [lax.bitcast_convert_type(a.reshape(-1), jnp.int32)
+             .reshape(live.size, -1) for a in lists]
+    rows = jnp.take(jnp.concatenate(words, axis=1), keep, axis=0)
+    heads, at = [], 0
+    for a, w in zip(lists, words):
+        n = w.shape[1]
+        mine = rows[..., at:at + n] if n > 1 else rows[..., at]
+        heads.append(lax.bitcast_convert_type(mine, a.dtype))
+        at += n
+    head_live = (lax.iota(jnp.int32, n_keep).reshape(keep.shape)
+                 < jnp.count_nonzero(live))
+    return (*heads, head_live)
+
+
+#: ``fixpoint`` sums the live and the indexed elements of its rounds in
+#: two int32 each: [whole multiples of 2^20, the rest].  Each half is
+#: exact as a float32 too (the chunk fetch ships its head in the
+#: solve's dtype) while the sum stays under 2^44: 2^24 elements over
+#: 2^20 rounds.
 _LIVE_LOW_BITS = 20
 
 
+def _pair_add(pair, low, high=0):
+    """``low`` (under 2^30) and ``high`` x 2^20 added to an exact
+    [high, low] pair of int32 whose low half stays under 2^20: a chunk's
+    sum of elements passes 2^24 at config #4's width and 2^31 on a deep
+    solve."""
+    low = pair[1] + low
+    return jnp.stack([pair[0] + high + (low >> _LIVE_LOW_BITS),
+                      low & ((1 << _LIVE_LOW_BITS) - 1)])
+
+
 def _live_elem_rounds(pair) -> int:
-    """The exact count behind ``fixpoint``'s ``live_elem_rounds`` pair
-    (a host array, of any number dtype)."""
+    """The exact count behind ``fixpoint``'s ``live_elem_rounds`` or
+    ``worked_elem_rounds`` pair (a host array, of any number dtype)."""
     return (int(pair[0]) << _LIVE_LOW_BITS) + int(pair[1])
 
 
@@ -1151,10 +1294,11 @@ def _solve_kernel_chunk(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty,
                         has_fatpipe: bool = True):
     """Run at most `chunk` more saturation rounds from `carry` (None =
     fresh start) and return (values, remaining, usage, rounds, carry,
-    bound_rounds, live_elem_rounds): how many of THIS dispatch's rounds
-    took the bound-first rule, and the live elements its rounds entered
-    with (fixpoint's pair).  eps is static for the same reason as
-    _solve_ell_chunk's."""
+    bound_rounds, live_elem_rounds, worked_elem_rounds, partitions): how
+    many of THIS dispatch's rounds took the bound-first rule, the live
+    elements its rounds entered with and the elements they indexed
+    (fixpoint's pairs), and the partitions of its ladder.  eps is static
+    for the same reason as _solve_ell_chunk's."""
     return fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty,
                     v_bound, jnp.asarray(eps, e_w.dtype), n_c, n_v,
                     axis=None, parallel_rounds=parallel_rounds,
@@ -1536,13 +1680,13 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
 
         def run_chunk(carry):
             # the ELL bodies count neither their bound rounds nor their
-            # live elements: None, so the counters are not bumped and
+            # elements: no counts, so the counters are not bumped and
             # read "not counted", not 0
             return (*_solve_ell_chunk(*args, carry, eps=eps_f,
                                       parallel_rounds=parallel_rounds,
                                       chunk=chunk, unroll=unroll,
                                       has_bounds=has_bounds,
-                                      has_fatpipe=has_fatpipe), None, None)
+                                      has_fatpipe=has_fatpipe), *[None] * 4)
     else:
         args = _device_args(
             "coo",
@@ -1561,24 +1705,25 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
 
     carry = None
     prev_progress = None
-    bound_rounds = live_elem_rounds = 0
+    bound_rounds = live_elem_rounds = worked_elem_rounds = partitions = 0
     while True:
         with opstats.span("solve.chunk"):
-            values, remaining, usage, rounds, carry, n_bound, n_live = \
-                run_chunk(carry)
+            (values, remaining, usage, rounds, carry, n_bound, n_live,
+             n_worked, n_parts) = run_chunk(carry)
             opstats.bump("dispatches")
             # ONE host sync per chunk: [rounds, light count, fixed
-            # count, and on the COO path bound rounds and the live
-            # elements' pair] AND the result vectors ride a single
-            # device->host transfer — a converged solve pays exactly
-            # one round-trip.  Counts are exact in f32 (< 2^24; the
-            # live elements come as two halves that are).
+            # count, and on the COO path bound rounds, the live and the
+            # indexed elements' pairs and the partitions] AND the
+            # result vectors ride a single device->host transfer — a
+            # converged solve pays exactly one round-trip.  Counts are
+            # exact in f32 (< 2^24; the element sums come as two halves
+            # that are).
             rdt = values.dtype
             n_vc, n_cc = values.shape[0], remaining.shape[0]
             head = [rounds, jnp.count_nonzero(carry[4]),
                     jnp.count_nonzero(carry[1])]
             if n_bound is not None:
-                head += [n_bound, n_live[0], n_live[1]]
+                head += [n_bound, *n_live, *n_worked, n_parts]
             n_h = len(head)
             fetched = opstats.timed_fetch(jnp.concatenate([
                 jnp.stack([h.astype(rdt) for h in head]),
@@ -1588,6 +1733,8 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
         if n_bound is not None:
             bound_rounds += int(fetched[3])
             live_elem_rounds += _live_elem_rounds(fetched[4:6])
+            worked_elem_rounds += _live_elem_rounds(fetched[6:8])
+            partitions += int(fetched[8])
         if n_light == 0:
             values = fetched[n_h:n_h + n_vc]
             remaining = fetched[n_h + n_vc:n_h + n_vc + n_cc]
@@ -1623,6 +1770,8 @@ def solve_arrays(arrays: LmmArrays, eps: float, device=None,
     if n_bound is not None:
         opstats.bump("fixpoint_bound_rounds", bound_rounds)
         opstats.bump("fixpoint_live_elem_rounds", live_elem_rounds)
+        opstats.bump("fixpoint_worked_elem_rounds", worked_elem_rounds)
+        opstats.bump("fixpoint_partitions", partitions)
     merged = (compactor.merge(values, remaining, usage)
               if compactor is not None else None)
     if merged is not None:

@@ -683,12 +683,12 @@ class WarmSolver:
                     unroll=False, has_bounds=has_bounds,
                     has_fatpipe=has_fatpipe)
             else:
-                values, remaining, usage, rounds, carry, _, _ = \
+                values, remaining, usage, rounds, carry = \
                     _solve_kernel_chunk(
                         *st.masters, carry, eps=eps_f, n_c=n_c, n_v=n_v,
                         parallel_rounds=parallel, chunk=chunk,
                         unroll=False, has_bounds=has_bounds,
-                        has_fatpipe=has_fatpipe)
+                        has_fatpipe=has_fatpipe)[:5]
             opstats.bump("dispatches")
             rdt = values.dtype
             fetched = np.asarray(jnp.concatenate([

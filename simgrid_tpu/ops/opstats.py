@@ -29,6 +29,21 @@ context object through the solver entry points:
                               can gain.  Exact: ``fixpoint`` carries it
                               as two int32 halves, the chunk fetch
                               ships both; absent after ELL solves
+* ``fixpoint_worked_elem_rounds`` — the elements those rounds INDEXED:
+                              the size of the ladder rung each ran on
+                              (``lmm_jax.fixpoint``), summed over the
+                              rounds.  ``fixpoint_rounds`` x the padded
+                              element count for a list under the
+                              ladder's floor; over the live count above
+                              it says how closely the rungs follow the
+                              live set.  The same exact pair, through
+                              the chunk fetch and, for the drain,
+                              through the superstep's packed stats
+* ``fixpoint_partitions``   — live-first partitions of the element list
+                              those ``solve_arrays`` solves ran between
+                              rungs (a descent over several rungs is
+                              one partition and a slice a rung); 0
+                              under the floor
 * ``uploaded_bytes_full``   — host->device bytes shipped as whole
                               arrays (fresh ``device_put``)
 * ``uploaded_bytes_delta``  — host->device bytes shipped as indexed

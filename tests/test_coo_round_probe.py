@@ -13,16 +13,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOL = os.path.join(ROOT, "tools", "coo_round_probe.py")
 
 
-@pytest.fixture(scope="module")
-def records(tmp_path_factory):
-    """Every reading at 128 hosts, from a process of its own (the tool
-    puts ``benchmarks/`` on ``sys.path``, as the benchmark's tests do),
-    through ``readings``: the part of the tool under ``main``'s device
-    check, writing nothing."""
-    code = ("import json, sys; sys.path.insert(0, sys.argv[1]); "
-            "import coo_round_probe as p; "
-            "p.readings(lambda **r: print(json.dumps(r)), None, "
-            "'tiny128-random', reps=1)")
+def probe(tmp_path_factory, code):
     done = subprocess.run(
         [sys.executable, "-c", code, os.path.dirname(TOOL)],
         cwd=tmp_path_factory.mktemp("probe"),
@@ -30,6 +21,32 @@ def records(tmp_path_factory):
         text=True, timeout=300)
     assert done.returncode == 0, done.stderr[-2000:]
     return [json.loads(line) for line in done.stdout.splitlines()]
+
+
+@pytest.fixture(scope="module")
+def records(tmp_path_factory):
+    """Every reading at 128 hosts, from a process of its own (the tool
+    puts ``benchmarks/`` on ``sys.path``, as the benchmark's tests do),
+    through ``readings``: the part of the tool under ``main``'s device
+    check, writing nothing."""
+    return probe(tmp_path_factory, (
+        "import json, sys; sys.path.insert(0, sys.argv[1]); "
+        "import coo_round_probe as p; "
+        "p.readings(lambda **r: print(json.dumps(r)), None, "
+        "'tiny128-random', reps=1)"))
+
+
+@pytest.fixture(scope="module")
+def ladder_records(tmp_path_factory):
+    """The ladder's readings at 128 hosts, the floor brought down HERE
+    so that these 5,811 elements have rungs to step down."""
+    return probe(tmp_path_factory, (
+        "import json, sys; sys.path.insert(0, sys.argv[1]); "
+        "import coo_round_probe as p; "
+        "from simgrid_tpu.ops import lmm_jax; "
+        "lmm_jax._LADDER_MIN_ELEMS = 256; "
+        "p.readings(lambda **r: print(json.dumps(r)), 'ladder', "
+        "'tiny128-random', reps=1)"))
 
 
 def test_without_a_tpu_it_measures_nothing(tmp_path):
@@ -71,3 +88,30 @@ def test_every_op_kind_has_a_price_in_both_layouts(records):
     ops = [r for r in records if r["what"] == "op"]
     assert len({(r["layout"], r["op"]) for r in ops}) == len(ops) == 20
     assert all(r["ms_per_op"] > 0 for r in ops)
+
+
+@pytest.mark.parametrize("layout", ["solve_pow2", "drain_2d"])
+def test_the_ladders_readings_rung_by_rung(ladder_records, records, layout):
+    """Every rung has its round and its 3-wide scatter priced, and so
+    has the size under the floor, half the last rung; every size but
+    that one its partition down to the next, three ways that agree."""
+    read = [r for r in ladder_records
+            if r["what"] == "ladder" and r["layout"] == layout]
+    sizes = [r["elems"] for r in read]
+    assert len(read) == read[0]["rungs"] + 1 >= 5
+    assert [r["rung"] for r in read] == [True] * (len(read) - 1) + [False]
+    assert sizes == sorted(sizes, reverse=True)
+    assert sizes[-1] <= 256 < sizes[-2] < 2 * sizes[-1] + 16
+    assert [r["kept"] for r in read[:-1]] == sizes[1:]
+    for r in read:
+        # (a list cut this short may converge before its fifth round)
+        assert r["scatter_add3_ms"] > 0 and "round_ms" in r
+        assert r["rounds_run"][0] == 1 < r["rounds_run"][1] <= 5
+    for r in read[:-1]:
+        assert r["agree"] is True
+        assert min(r["partition_ms"], r["sort_ms"], r["packed_ms"]) > 0
+    assert "kept" not in read[-1]
+    # under the floor as it stands: one rung, nothing to partition
+    whole = [r for r in records
+             if r["what"] == "ladder" and r["layout"] == layout]
+    assert len(whole) == 1 and "kept" not in whole[0] and whole[0]["rung"]

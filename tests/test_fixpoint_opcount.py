@@ -86,7 +86,8 @@ def count_indexed(jaxpr, n_elem):
     return outside, conds
 
 
-def round_and_entry(parallel_rounds, has_bounds, has_fatpipe):
+def traced(parallel_rounds, has_bounds, has_fatpipe):
+    """(jaxpr of one `fixpoint` call, its element count)"""
     a = system(bounds="bind" if has_bounds else None, fatpipe=has_fatpipe)
     n_c, n_v = len(a.c_bound), len(a.v_penalty)
     n_elem = len(a.e_var)
@@ -100,10 +101,21 @@ def round_and_entry(parallel_rounds, has_bounds, has_fatpipe):
 
     closed = jax.make_jaxpr(run)(a.e_var, a.e_cnst, a.e_w, a.c_bound,
                                  a.c_fatpipe, a.v_penalty, a.v_bound)
-    loops = [e for e in closed.jaxpr.eqns if e.primitive.name == "while"]
+    return closed.jaxpr, n_elem
+
+
+def round_loops(jaxpr):
+    return [e for e in jaxpr.eqns if e.primitive.name == "while"]
+
+
+def round_and_entry(parallel_rounds, has_bounds, has_fatpipe):
+    jaxpr, n_elem = traced(parallel_rounds, has_bounds, has_fatpipe)
+    loops = round_loops(jaxpr)
+    # under the ladder's floor: exactly one round loop, no partition
     assert len(loops) == 1
+    assert not [e for e in jaxpr.eqns if e.primitive.name == "cond"]
     body = count_indexed(loops[0].params["body_jaxpr"].jaxpr, n_elem)
-    entry = count_indexed(closed.jaxpr, n_elem)[0] - body[0]
+    entry = count_indexed(jaxpr, n_elem)[0] - body[0]
     return body, entry
 
 
@@ -122,16 +134,24 @@ BUDGETS = {
     (False, True, True): (5, None, 6),
 }
 
+#: what one partition may issue over the list it cuts ([not taken: a
+#: slice, taken]) as (indexed ops, sorts): the scatter that builds the
+#: live-first permutation and ONE gather of the kept head, e_var,
+#: e_cnst, e_w and e_upen side by side in its rows (the head's liveness
+#: needs none); no sort (6.8 ms against 16.4 on the chip, but 1.6-1.7x
+#: the program's compile time: PERF.md §5)
+PARTITION_BUDGET = [(0, 0), (2, 0)]
 
-@pytest.mark.parametrize("parallel_rounds,has_bounds,has_fatpipe",
-                         sorted(BUDGETS))
-def test_round_issues_no_more_indexed_ops_than_budgeted(
-        parallel_rounds, has_bounds, has_fatpipe):
-    outside, block, entry = BUDGETS[parallel_rounds, has_bounds, has_fatpipe]
-    (got_outside, conds), got_entry = round_and_entry(
-        parallel_rounds, has_bounds, has_fatpipe)
+
+def count_sorts(jaxpr):
+    return sum(e.primitive.name == "sort" for e in jaxpr.eqns) + sum(
+        count_sorts(sub) for e in jaxpr.eqns for sub in sub_jaxprs(e))
+
+
+def within(got, outside, block):
+    """One round body's count against its budget."""
+    got_outside, conds = got
     assert got_outside <= outside
-    assert got_entry <= entry
     if block is None:
         assert conds == []
     else:
@@ -141,6 +161,45 @@ def test_round_issues_no_more_indexed_ops_than_budgeted(
         # under vmap both sides run: no dearer than the 12 ops the
         # bound block cost before it stood behind a cond
         assert 2 + sum(conds[0]) <= 12
+
+
+@pytest.mark.parametrize("parallel_rounds,has_bounds,has_fatpipe",
+                         sorted(BUDGETS))
+def test_round_issues_no_more_indexed_ops_than_budgeted(
+        parallel_rounds, has_bounds, has_fatpipe):
+    outside, block, entry = BUDGETS[parallel_rounds, has_bounds, has_fatpipe]
+    got, got_entry = round_and_entry(parallel_rounds, has_bounds,
+                                     has_fatpipe)
+    within(got, outside, block)
+    assert got_entry <= entry
+
+
+@pytest.mark.parametrize("parallel_rounds,has_bounds,has_fatpipe",
+                         sorted(BUDGETS))
+def test_every_rung_holds_the_rounds_budget_and_a_partition_its_own(
+        parallel_rounds, has_bounds, has_fatpipe, monkeypatch):
+    """The ladder (its floor brought down to these thousand elements)
+    is one round loop a rung, each body within the round's budget at
+    ITS size, and between two rungs one cond: a slice, or a partition
+    within `PARTITION_BUDGET`.  Entry is what it was."""
+    monkeypatch.setattr(lmm_jax, "_LADDER_MIN_ELEMS", 32)
+    outside, block, entry = BUDGETS[parallel_rounds, has_bounds, has_fatpipe]
+    jaxpr, n_elem = traced(parallel_rounds, has_bounds, has_fatpipe)
+    sizes = lmm_jax._ladder_sizes((n_elem,))
+    loops = round_loops(jaxpr)
+    assert len(loops) == len(sizes) >= 3
+    in_loops = 0
+    for loop, size in zip(loops, sizes):
+        body = loop.params["body_jaxpr"].jaxpr
+        got = count_indexed(body, size)
+        within(got, outside, block)
+        in_loops += got[0]
+    assert count_indexed(jaxpr, sizes[-1])[0] - in_loops <= entry
+    cuts = [sorted((whole(count_indexed(br.jaxpr, sizes[-1])),
+                    count_sorts(br.jaxpr)) for br in e.params["branches"])
+            for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert cuts == [PARTITION_BUDGET] * (len(sizes) - 1)
+    assert count_sorts(jaxpr) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -2,7 +2,7 @@
 """What one element-wide gather or scatter of the COO round costs on the
 chip, at config #4's sizes, and what a whole round costs:
 
-    chiprun -- python tools/coo_round_probe.py [--only ops|rounds]
+    chiprun -- python tools/coo_round_probe.py [--only ops|rounds|ladder]
 
 Builds the 65,536-host dragonfly's max-min system of 100,000 random
 flows with the benchmark's numpy reference (no engine: seconds), in
@@ -20,7 +20,19 @@ flows with the benchmark's numpy reference (no engine: seconds), in
   behind the round's ``lax.cond`` RUNS (rates held to the reference's,
   the taken round priced against the skipped one); those two as the
   lanes of one ``vmap`` (the cond a select, both sides run); and as
-  the drain's superstep runs it (unit penalty, no bounds, [E/8, 8]).
+  the drain's superstep runs it (unit penalty, no bounds, [E/8, 8]);
+* the ladder of ``fixpoint`` (ISSUE 30), rung by rung in both layouts:
+  the live-first partition down to the next rung as the program does
+  it (``lmm_jax._livefirst_head``: cumsum, one scatter, ONE gather of
+  the lists as rows: ``packed_ms``), with a gather a list
+  (``partition_ms``), and as one stable ``lax.sort`` carrying the lists
+  as payload (``sort_ms``; the three must agree), one bound-free
+  round of the single loop over a list of that size (5 rounds less
+  1, so entry drops out), and one
+  3-wide scatter-add of that many indices, all of it once more at half
+  the last rung's size, which the ladder's floor refuses: does a round
+  go by its indices down to 2^15, and where does its fixed part start
+  to show.
 
 One JSON line per reading on stdout, all of them appended to
 ``chiprun_out/coo_round_probe.jsonl``.  Its readings are device times:
@@ -251,9 +263,100 @@ def readings(emit, only=None, config="dfly65k-random", reps=3):
         emit(what="round", **rec)
         return rec["round_ms"]
 
+    def head_live(e_live, n_keep):
+        shape = lmm_jax._head(e_live, n_keep).shape
+        return (lax.iota(jnp.int32, n_keep).reshape(shape)
+                < jnp.count_nonzero(e_live))
+
+    def scatter_head(*lists_and_live, n_keep):
+        """`lmm_jax._livefirst_head` with a gather of the kept head a
+        list in place of its one gather of rows: is a gather priced by
+        the index here too?"""
+        *lists, e_live = lists_and_live
+        live = e_live.reshape(-1)
+        group = (e_live.shape[-1] if e_live.ndim == 2
+                 else lmm_jax._pos_group(live.size))
+        keep = lmm_jax._head(lmm_jax._stable_livefirst_perm(
+            live, group).reshape(e_live.shape), n_keep)
+        return (*(jnp.take(a.reshape(-1), keep) for a in lists),
+                head_live(e_live, n_keep))
+
+    def sort_head(*lists_and_live, n_keep):
+        """`lmm_jax._livefirst_head` as ONE stable sort on "dead", the
+        lists its payload: no indexed op at all."""
+        *lists, e_live = lists_and_live
+        dead = (~e_live).reshape(-1).astype(jnp.int32)
+        _, *out = lax.sort([dead] + [a.reshape(-1) for a in lists],
+                           num_keys=1, is_stable=True)
+        shape = lmm_jax._head(e_live, n_keep).shape
+        return (*(o[:n_keep].reshape(shape) for o in out),
+                head_live(e_live, n_keep))
+
+    def ladder(pow2):
+        name, E, C, V, el = layout(pow2)
+        lists = (el(solve_sys.e_var, np.int32), el(solve_sys.e_cnst, np.int32),
+                 el(solve_sys.e_w, dtype))
+        c_bound = jnp.asarray(padded(solve_sys.c_bound.astype(dtype), C))
+        v_pen = jnp.asarray(padded(solve_sys.v_penalty.astype(dtype), V))
+        sizes = lmm_jax._ladder_sizes(lists[0].shape)
+        rungs = len(sizes)
+        if rungs > 1:
+            # and the size the floor refuses, half the last rung: does
+            # a round still go by its indices down there?
+            group = (lists[0].shape[-1] if lists[0].ndim == 2
+                     else lmm_jax._pos_group(E))
+            sizes = sizes + [-(-sizes[-1] // (2 * group)) * group]
+        scatter3 = op_loops(jnp, lax, dtype)["scatter_add3_f32"](C)
+        # the single loop over each cut list: the floor out of reach
+        floor, lmm_jax._LADDER_MIN_ELEMS = lmm_jax._LADDER_MIN_ELEMS, E
+
+        def rounds(n, e_var, e_cnst, e_w):
+            out = lmm_jax.fixpoint(
+                e_var, e_cnst, e_w, c_bound, jnp.zeros(C, bool), v_pen,
+                jnp.full(V, -1, dtype), jnp.asarray(eps, dtype), C, V,
+                parallel_rounds=True, max_rounds=n, return_carry=True,
+                has_bounds=False, has_fatpipe=False)
+            return out[0], out[3]
+
+        try:
+            for size, below in zip(sizes, sizes[1:] + [None]):
+                cut = tuple(lmm_jax._head(a, size) for a in lists)
+                rec = dict(layout=name, elems=size, rungs=rungs,
+                           rung=size in sizes[:rungs])
+                run = jax.jit(rounds)
+                one, (_, r1) = timed(run, jnp.int32(1), *cut)
+                five, (_, r5) = timed(run, jnp.int32(5), *cut)
+                if int(r5) > int(r1):
+                    rec["round_ms"] = 1e3 * (five - one) / (int(r5) - int(r1))
+                rec["rounds_run"] = [int(r1), int(r5)]
+                sec, _ = timed(jax.jit(scatter3),
+                               jnp.abs(cut[2]) + 1.0, cut[1])
+                rec["scatter_add3_ms"] = 1e3 * sec / K
+                if below is not None:
+                    rng = np.random.default_rng(size)
+                    live = jnp.asarray(rng.random(cut[0].shape) < 0.4)
+                    heads = []
+                    for how, fn in (("packed_ms", lmm_jax._livefirst_head),
+                                    ("partition_ms", scatter_head),
+                                    ("sort_ms", sort_head)):
+                        fn = jax.jit(lambda *a, fn=fn: fn(*a, n_keep=below))
+                        sec, out = timed(fn, *cut, cut[2] * 0.5, live)
+                        rec[how] = 1e3 * sec
+                        heads.append([np.asarray(x) for x in out])
+                    rec["kept"] = below
+                    rec["agree"] = all(
+                        np.array_equal(a, b) for other in heads[1:]
+                        for a, b in zip(heads[0], other))
+                emit(what="ladder", **rec)
+        finally:
+            lmm_jax._LADDER_MIN_ELEMS = floor
+
     if only in (None, "ops"):
         op_prices(False)
         op_prices(True)
+    if only in (None, "ladder"):
+        ladder(True)
+        ladder(False)
     if only in (None, "rounds"):
         from configs import dragonfly_lv08 as ref
 
@@ -270,7 +373,8 @@ def readings(emit, only=None, config="dfly65k-random", reps=3):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--only", choices=("ops", "rounds"), default=None)
+    ap.add_argument("--only", choices=("ops", "rounds", "ladder"),
+                    default=None)
     only = ap.parse_args(argv).only
 
     import jax
