@@ -322,7 +322,6 @@ def stage_churn(n_v: int, seed: int, cpu: bool, mode: str,
 
     churn_n = max(1, int(n_flows * churn))
     walls, rounds, b_full, b_delta, dirt = [], [], [], [], []
-    donated = int(d.get("donated_buffers", 0))
     for step in range(steps):
         ks = rng.integers(0, clusters, size=churn_n)
         for k in ks:
@@ -340,7 +339,6 @@ def stage_churn(n_v: int, seed: int, cpu: bool, mode: str,
         rounds.append(int(d.get("fixpoint_rounds", 0)))
         b_full.append(int(d.get("uploaded_bytes_full", 0)))
         b_delta.append(int(d.get("uploaded_bytes_delta", 0)))
-        donated += int(d.get("donated_buffers", 0))
         ws = s.warm_solver
         dirt.append(ws.last_dirty_slots if ws else -1)
         log(f"[stage churn/{mode}] step {step}: {walls[-1]:.1f} ms, "
@@ -351,11 +349,6 @@ def stage_churn(n_v: int, seed: int, cpu: bool, mode: str,
                bytes_full_med=int(np.median(b_full)),
                bytes_delta_med=int(np.median(b_delta)),
                dirty_slots_med=int(np.median(dirt)),
-               # carried-state buffers handed to XLA for in-place
-               # reuse over the whole stage (0 on solve-only paths —
-               # only donating drain dispatches bump it; recorded so
-               # churn rows compose with drain-stage rows downstream)
-               donated_buffers=donated,
                warm_solves=(s.warm_solver.warm_solves
                             if s.warm_solver else 0))
     return out

@@ -351,8 +351,7 @@ def leg_drain(ctx: Ctx) -> dict:
     events = [(t, int(ctx.slot_flow[fid])) for t, fid in sim.events]
     assert events and (sim.advances == window
                        or len(events) == a.n_var), sim.advances
-    assert sim.supersteps >= 2 and stats.get("donated_buffers", 0) >= 2, \
-        "the donating steady-state dispatch never ran"
+    assert sim.supersteps >= 2, sim.supersteps
     ref, ref_info = drain_native(a, ctx.slot_flow, FLOW_BYTES,
                                  min_events=len(events))
     census = compare_events(ref, events)
@@ -556,7 +555,7 @@ def leg_compile(ctx: Ctx) -> dict:
         t0 = time.perf_counter()
         spec.jitted.lower(*args, **statics).compile()
         rows[spec.name] = round(time.perf_counter() - t0, 3)
-    assert len(rows) == 13, sorted(rows)
+    assert len(rows) == 11, sorted(rows)
     return dict(leg="compile", ok=True, programs=len(rows),
                 compile_s=rows)
 

@@ -4,7 +4,7 @@
 subpackage guards the *compiled* programs.  Every invariant the
 bit-identity contract actually rests on — f64 event ordering, the
 FMA-contraction pinning in ``_rounded_product``, "one ring fetch per
-superstep", donated steady-state carries, jit-cache-flat shapes —
+superstep", jit-cache-flat shapes —
 lives in the lowered jaxpr/StableHLO, where an innocuous weak-typed
 scalar or a dtype-promoting op can rewrite the program without
 touching any lintable syntax.

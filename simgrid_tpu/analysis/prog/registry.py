@@ -10,8 +10,8 @@ programs the factory does not re-derive the argument assembly — it
 builds a real (tiny) sim and *captures* the driver's own dispatch by
 swapping the module-level jit wrapper for a raiser, so the registry
 can never drift out of sync with the issue paths.  The warm-solver
-and fleet-fused programs take flat array arguments with no driver
-state, so their factories construct arguments directly.
+programs take flat array arguments with no driver state, so their
+factories construct arguments directly.
 
 ``scale`` selects one of two example geometries (the retrace-surface
 rule lowers both and diffs the closed-over constants); everything is
@@ -36,16 +36,14 @@ _F64_WHY = ("Kahan clock pair, f64 base clock, fault-tape dates and "
 _COMMON = ("int32", "int64", "bool", "uint32")
 
 
-def _drain_contract(solve_dtype: str, donated=("pen", "rem"),
-                    outputs=8) -> ProgramContract:
+def _drain_contract(solve_dtype: str) -> ProgramContract:
     allowed = (solve_dtype, "float64") + _COMMON
     why = {"float64": _F64_WHY} if solve_dtype != "float64" else {}
     return ProgramContract(
         solve_dtype=solve_dtype,
         allowed_dtypes=tuple(dict.fromkeys(allowed)),
         dtype_why=why,
-        expected_outputs=outputs,
-        donated=tuple(donated),
+        expected_outputs=8,
         fma_pinned=True)
 
 
@@ -154,17 +152,8 @@ def _solo_superstep(scale: int, dtype, tape=False, coll=False):
         pen[0] = 1.0
         kw["penalty"] = pen
     sim = ld.DrainSim(e_var, e_cnst, e_w, c_bound, sizes, **kw)
-    return _capture(ld, "_drain_superstep_donate",
-                    lambda: sim.superstep_batch(k=1, donate=True))
-
-
-def _solo_fused(scale: int, dtype):
-    from simgrid_tpu.ops import lmm_drain as ld
-
-    e_var, e_cnst, e_w, c_bound, sizes = _arrays(scale, dtype)
-    sim = ld.DrainSim(e_var, e_cnst, e_w, c_bound, sizes, eps=1e-9,
-                      dtype=dtype, fused=True, repack_min=1 << 62)
-    return _capture(ld, "_drain_fused_step", sim.advance)
+    return _capture(ld, "_drain_superstep",
+                    lambda: sim.superstep_batch(k=1))
 
 
 def _solo_chunk(scale: int, dtype):
@@ -173,7 +162,7 @@ def _solo_chunk(scale: int, dtype):
     e_var, e_cnst, e_w, c_bound, sizes = _arrays(scale, dtype)
     sim = ld.DrainSim(e_var, e_cnst, e_w, c_bound, sizes, eps=1e-9,
                       dtype=dtype, repack_min=1 << 62)
-    return _capture(ld, "_drain_solve_chunk", sim.advance)
+    return _capture(ld, "_drain_solve_chunk", sim.solve_rates)
 
 
 # ---------------------------------------------------------------------------
@@ -198,29 +187,8 @@ def _fleet_superstep(scale: int, dtype, tape=False, coll=False):
         kw["penalty"] = pen
     sim = lb.BatchDrainSim(e_var, e_cnst, e_w, c_bound, sizes,
                            overrides, **kw)
-    return _capture(lb, "_batch_superstep_donate",
+    return _capture(lb, "_batch_superstep",
                     lambda: sim.superstep_all())
-
-
-def _fleet_fused(scale: int, dtype):
-    from simgrid_tpu.ops.lmm_drain import _ZERO_BITS, _to2d
-
-    e_var, e_cnst, e_w, c_bound, sizes = _arrays(scale, dtype)
-    n_c, n_v = _geometry(scale)
-    B = 2
-    args = (_to2d(e_var.astype(np.int32)),
-            _to2d(e_cnst.astype(np.int32)),
-            _to2d(e_w.astype(dtype)),
-            np.broadcast_to(c_bound, (B, n_c)).astype(dtype),
-            np.full(n_v, -1.0, dtype),
-            np.ones((B, n_v), dtype),
-            np.broadcast_to(sizes, (B, n_v)).astype(dtype),
-            (1e-4 * np.broadcast_to(sizes, (B, n_v))).astype(dtype),
-            np.ones(B, bool),
-            _ZERO_BITS)
-    statics = dict(eps=1e-9, n_c=n_c, n_v=n_v, chunk=8,
-                   has_bounds=False, batch_w=False)
-    return args, statics
 
 
 # ---------------------------------------------------------------------------
@@ -265,32 +233,26 @@ def iter_programs() -> List[ProgramSpec]:
     from simgrid_tpu.ops import lmm_warm as lw
 
     f64, f32 = np.float64, np.float32
-    # the solve/fused surfaces: (carry..., stats) — measured from the
-    # programs' return tuples, pinned so growth is a finding
+    # the solve surface: (carry..., stats) — measured from the
+    # program's return tuple, pinned so growth is a finding
     chunk_out = 7      # fixpoint carry legs + stats
-    fused_out = 9      # pen, rem, solve carry legs, stats
     specs = [
         ProgramSpec(
-            "drain/superstep", ld._drain_superstep_donate,
+            "drain/superstep", ld._drain_superstep,
             ld._superstep_program, _drain_contract("float64"),
             lambda s, dt=f64: _solo_superstep(s, dt)),
         ProgramSpec(
-            "drain/superstep_f32", ld._drain_superstep_donate,
+            "drain/superstep_f32", ld._drain_superstep,
             ld._superstep_program, _drain_contract("float32"),
             lambda s, dt=f32: _solo_superstep(s, dt)),
         ProgramSpec(
-            "drain/superstep_tape", ld._drain_superstep_donate,
+            "drain/superstep_tape", ld._drain_superstep,
             ld._superstep_program, _drain_contract("float64"),
             lambda s, dt=f64: _solo_superstep(s, dt, tape=True)),
         ProgramSpec(
-            "drain/superstep_coll", ld._drain_superstep_donate,
+            "drain/superstep_coll", ld._drain_superstep,
             ld._superstep_program, _drain_contract("float64"),
             lambda s, dt=f64: _solo_superstep(s, dt, coll=True)),
-        ProgramSpec(
-            "drain/fused_step", ld._drain_fused_step,
-            ld._fused_step_program,
-            _drain_contract("float64", donated=(), outputs=fused_out),
-            lambda s, dt=f64: _solo_fused(s, dt)),
         ProgramSpec(
             "drain/solve_chunk", ld._drain_solve_chunk,
             ld._solve_chunk_program,
@@ -301,26 +263,21 @@ def iter_programs() -> List[ProgramSpec]:
                 donated=(), fma_pinned=False),
             lambda s, dt=f64: _solo_chunk(s, dt)),
         ProgramSpec(
-            "fleet/superstep", lb._batch_superstep_donate,
+            "fleet/superstep", lb._batch_superstep,
             lb._batch_superstep_program, _drain_contract("float64"),
             lambda s, dt=f64: _fleet_superstep(s, dt)),
         ProgramSpec(
-            "fleet/superstep_f32", lb._batch_superstep_donate,
+            "fleet/superstep_f32", lb._batch_superstep,
             lb._batch_superstep_program, _drain_contract("float32"),
             lambda s, dt=f32: _fleet_superstep(s, dt)),
         ProgramSpec(
-            "fleet/superstep_tape", lb._batch_superstep_donate,
+            "fleet/superstep_tape", lb._batch_superstep,
             lb._batch_superstep_program, _drain_contract("float64"),
             lambda s, dt=f64: _fleet_superstep(s, dt, tape=True)),
         ProgramSpec(
-            "fleet/superstep_coll", lb._batch_superstep_donate,
+            "fleet/superstep_coll", lb._batch_superstep,
             lb._batch_superstep_program, _drain_contract("float64"),
             lambda s, dt=f64: _fleet_superstep(s, dt, coll=True)),
-        ProgramSpec(
-            "fleet/fused_fresh", lb._batch_fused_fresh,
-            lb._batch_fused_fresh.__wrapped__,
-            _drain_contract("float64", donated=(), outputs=fused_out),
-            lambda s, dt=f64: _fleet_fused(s, dt)),
         ProgramSpec(
             "warm/warm_init", lw._warm_init,
             lw._warm_init.__wrapped__,

@@ -291,6 +291,10 @@ class DrainFastPath:
         from .lmm_drain import DrainSim
 
         dtype = solve_dtype(config["lmm/dtype"], "lmm/dtype")
+        superstep = int(config["drain/superstep"])
+        if superstep < 1:
+            raise ValueError(f"drain/superstep:{superstep} (expected "
+                             "K >= 1 advances per dispatch)")
         absorbing = self._transitions_enabled()
         plan = _plan_inputs(self.model, dtype, allow_latency=absorbing)
         if plan is None:
@@ -323,7 +327,7 @@ class DrainFastPath:
             eps=config["maxmin/precision"], done_eps=done_eps,
             dtype=dtype, done_mode=done_mode,
             v_bound=snap.v_bound,
-            superstep=int(config["drain/superstep"]),
+            superstep=superstep,
             penalty=pen, remains=rem,
             # device repacks would detach the replay snapshot from the
             # element tables; plans are rebuilt often enough that the

@@ -2,7 +2,7 @@
 a fleet of independent simulations in ONE device program.
 
 The paper's hot spot — the max-min fixpoint — is already fast for one
-simulation (fused/superstepped drains, warm-started selective solves),
+simulation (superstepped drains, warm-started selective solves),
 but the north star is serving *fleets* of scenarios: Monte Carlo fault
 campaigns, parameter sweeps, per-user what-ifs.  Run solo, each replica
 pays its own dispatches and uploads, a per-transfer cost that does not
@@ -81,8 +81,8 @@ from .device import default_platform, solve_dtype
 from .lmm_jax import (_MAX_ROUNDS, SolveError, _solve_kernel_chunk_batched,
                       _solve_kernel_chunk_batched_fresh)
 from .lmm_drain import (_FLAG_BUDGET, _FLAG_OK, _FLAG_STALLED, _STATS_HEAD,
-                        _ZERO_BITS, _pos_group, _fused_step_program,
-                        _superstep_program, _to2d)
+                        _ZERO_BITS, _pos_group, _superstep_program,
+                        _to2d)
 
 
 #: the mesh axis name the replica dimension shards over
@@ -529,78 +529,10 @@ def _batch_superstep_program(e_var, e_cnst, e_w, c_bound, v_bound,
         tape_pos, coll_pred, coll_ready, coll_clk, t0, e_w)
 
 
-_BATCH_SUPERSTEP_STATICS = ("eps", "n_c", "n_v", "k_max", "group",
-                            "has_bounds", "batch_w", "has_tape",
-                            "has_coll")
-
 _batch_superstep = functools.partial(
-    jax.jit,
-    static_argnames=_BATCH_SUPERSTEP_STATICS)(_batch_superstep_program)
-
-#: the donating twin (see ops.lmm_drain._drain_superstep_donate):
-#: committed-state fleet dispatches reuse the [B, n_v] (pen, rem)
-#: buffers in place.  Dispatched under its own plan-cache kind
-#: ("superstep_donate") so AOT artifacts never alias the non-donating
-#: executable, and NEVER under a watchdog — a retried dispatch would
-#: replay over inputs the first attempt already consumed.
-_batch_superstep_donate = functools.partial(
-    jax.jit, static_argnames=_BATCH_SUPERSTEP_STATICS,
-    donate_argnames=("pen", "rem"))(_batch_superstep_program)
-
-
-def _batch_fused_lane(e_var, e_cnst, ew_l, cb, v_bound, pen_l, rem_l,
-                      th_l, carry_l, act, zero_bits, eps, n_c, n_v,
-                      chunk, has_bounds):
-    pen2, rem2, carry2, stats = _fused_step_program(
-        e_var, e_cnst, ew_l, cb, v_bound, pen_l, rem_l, th_l, carry_l,
-        zero_bits, eps=eps, n_c=n_c, n_v=n_v, chunk=chunk,
-        has_bounds=has_bounds)
-    sel = lambda a, b: jnp.where(act, a, b)  # noqa: E731
-    if carry_l is None:
-        carry_out = carry2
-    else:
-        carry_out = tuple(sel(n, o) for n, o in zip(carry2, carry_l))
-    return (sel(pen2, pen_l), sel(rem2, rem_l), carry_out,
-            jnp.where(act, stats, jnp.zeros_like(stats)))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("eps", "n_c", "n_v", "chunk",
-                                    "has_bounds", "batch_w"))
-def _batch_fused_fresh(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
-                       thresh, active, zero_bits, eps: float, n_c: int,
-                       n_v: int, chunk: int, has_bounds: bool = False,
-                       batch_w: bool = False):
-    """Fleet fused solve+advance, fresh fixpoint start.  Inactive lanes
-    still trace through the math but every output is frozen to the
-    input state, so only `active` replicas advance."""
-    def lane(cb, pen_l, rem_l, th_l, act, ew_l):
-        return _batch_fused_lane(e_var, e_cnst, ew_l, cb, v_bound,
-                                 pen_l, rem_l, th_l, None, act,
-                                 zero_bits, eps, n_c, n_v, chunk,
-                                 has_bounds)
-    return jax.vmap(lane, in_axes=(0, 0, 0, 0, 0,
-                                   0 if batch_w else None))(
-        c_bound, pen, rem, thresh, active, e_w)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("eps", "n_c", "n_v", "chunk",
-                                    "has_bounds", "batch_w"))
-def _batch_fused_cont(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
-                      thresh, carry, active, zero_bits, eps: float,
-                      n_c: int, n_v: int, chunk: int,
-                      has_bounds: bool = False, batch_w: bool = False):
-    """Continuation flavor: resume per-replica fixpoint carries (rare —
-    only when a solve needs more than one chunk of rounds)."""
-    def lane(cb, pen_l, rem_l, th_l, carry_l, act, ew_l):
-        return _batch_fused_lane(e_var, e_cnst, ew_l, cb, v_bound,
-                                 pen_l, rem_l, th_l, carry_l, act,
-                                 zero_bits, eps, n_c, n_v, chunk,
-                                 has_bounds)
-    return jax.vmap(lane, in_axes=(0, 0, 0, 0, 0, 0,
-                                   0 if batch_w else None))(
-        c_bound, pen, rem, thresh, carry, active, e_w)
+    jax.jit, static_argnames=("eps", "n_c", "n_v", "k_max", "group",
+                              "has_bounds", "batch_w", "has_tape",
+                              "has_coll"))(_batch_superstep_program)
 
 
 # ---------------------------------------------------------------------------
@@ -1227,12 +1159,11 @@ class BatchDrainSim:
                              alive=None, cb=None, tpos=None, t0=None,
                              round_budget: int = 0,
                              pred=None, ready=None,
-                             clk=None,
-                             donate: bool = False) -> "FleetToken":
+                             clk=None) -> "FleetToken":
         """Dispatch ONE fleet superstep without touching the committed
         state: chains from `(pen, rem)` (default: committed) under the
         CURRENT alive mask (or an explicit `alive` restriction — the
-        tape-aware rescue); inputs/outputs ride the returned token
+        budget rescue); inputs/outputs ride the returned token
         (see ops.lmm_drain — same issue/collect speculation protocol,
         one [B, ·] ring per token).  With a fault tape the dispatch
         chains per-lane bounds/cursors (`cb`, `tpos`) and [B] f64 base
@@ -1259,18 +1190,9 @@ class BatchDrainSim:
         pred_in = self._coll_pred if pred is None else pred
         ready_in = self._coll_ready if ready is None else ready
         clk_in = self._coll_clk if clk is None else clk
-        # donation gate: only non-speculative dispatches chained from
-        # the COMMITTED state may consume their inputs, and never
-        # under a watchdog (its retry would replay over buffers the
-        # first attempt already consumed — dispatches stop being pure)
-        donate = (donate and not speculative
-                  and pen is None and rem is None
-                  and self._watchdog is None)
-        kind, fn = (("superstep_donate", _batch_superstep_donate)
-                    if donate else ("superstep", _batch_superstep))
         (pen_out, rem_out, cb_out, tpos_out, pred_out, ready_out,
          clk_out, packed) = self._call_plan(
-            kind, fn,
+            "superstep", _batch_superstep,
             (*self._dev, cb_in, self._vb, pen_in, rem_in,
              self._thresh, self._ids_dev,
              self._put_mask(alive), np.int32(k),
@@ -1281,13 +1203,6 @@ class BatchDrainSim:
                  group=group, has_bounds=self.has_bounds,
                  batch_w=self.batch_w, has_tape=self.has_tape,
                  has_coll=self.has_coll))
-        if donate:
-            # the committed buffers are gone: adopt the outputs NOW
-            # (collect re-adopts them, a no-op) and strip the dead
-            # inputs from the token so misuse fails loudly
-            self._pen, self._rem = pen_out, rem_out
-            pen_in = rem_in = None
-            opstats.bump("donated_buffers", 2)
         t0_out = None
         if self.has_tape:
             # derive the post-dispatch base clocks DEVICE-side with the
@@ -1356,7 +1271,7 @@ class BatchDrainSim:
         ``(n_alive, clean)`` — clean False when processing this ring
         mutated the fleet (a lane died, a tape event fired, or a
         rescue ran), so in-flight speculative successors must be
-        discarded.  With ``rescue=True`` (the tape-aware rescue's own
+        discarded.  With ``rescue=True`` (the budget rescue's own
         collect — the dispatch already ran with the FULL round budget)
         still-stuck lanes are converted to non-convergence deaths
         instead of re-rescued."""
@@ -1484,16 +1399,9 @@ class BatchDrainSim:
                     f"event(s) — the frozen-lane invariant is broken")
         if stuck:
             # the round budget expired inside a replica's FIRST solve:
-            # finish exactly one advance for those lanes.  Tape- or
-            # collective-armed fleets must stay on the superstep path
-            # (the fused rescue is tape-blind and would step over
-            # events); otherwise the chunked fused program (converges
-            # across dispatches), the batched mirror of the solo run()
-            # rescue.
-            if self.has_tape or self.has_coll:
-                self._rescue_superstep(stuck)
-            else:
-                self._rescue_fused(stuck)
+            # finish exactly one advance for those lanes, the batched
+            # mirror of the solo run() rescue
+            self._rescue_superstep(stuck)
         if tok.speculative:
             self.spec_committed += 1
             opstats.bump("speculations_committed")
@@ -1505,7 +1413,7 @@ class BatchDrainSim:
         ONE [B, ·] fetch; commits per-replica events and clocks.
         Returns the number of still-live replicas."""
         n_alive, _clean = self._superstep_collect_all(
-            self._superstep_issue_all(k, donate=True))
+            self._superstep_issue_all(k))
         return n_alive
 
     # -- mid-flight lane admission (serving) -------------------------------
@@ -1661,87 +1569,18 @@ class BatchDrainSim:
         self.admitted += 1
         opstats.bump("lanes_admitted")
 
-    def _rescue_fused(self, stuck: List[int]) -> None:
-        self.rescues += 1
-        active = np.zeros(self.B_padded, bool)
-        active[stuck] = True
-        chunk = 16 if self._dev[0].size >= 1 << 20 else 64
-        carry = None
-        k_live = 4 + self.n_v
-        while True:
-            if carry is None:
-                self._pen, self._rem, carry, stats = _batch_fused_fresh(
-                    *self._dev, self._cb, self._vb, self._pen,
-                    self._rem, self._thresh, self._put_mask(active),
-                    _ZERO_BITS, eps=self.eps, n_c=self.n_c,
-                    n_v=self.n_v, chunk=chunk,
-                    has_bounds=self.has_bounds, batch_w=self.batch_w)
-            else:
-                self._pen, self._rem, carry, stats = _batch_fused_cont(
-                    *self._dev, self._cb, self._vb, self._pen,
-                    self._rem, self._thresh, carry,
-                    self._put_mask(active), _ZERO_BITS, eps=self.eps,
-                    n_c=self.n_c, n_v=self.n_v, chunk=chunk,
-                    has_bounds=self.has_bounds, batch_w=self.batch_w)
-            opstats.bump("dispatches")
-            st = self._fetch(stats)[:, :k_live]
-            for b in list(stuck):
-                if not active[b]:
-                    continue
-                # rounds (st[b,0]) is the lane's TOTAL fixpoint
-                # iteration count across chunks — count it once, at
-                # commit/error time, like the solo _advance_fused
-                rounds, n_light = int(st[b, 0]), int(st[b, 1])
-                if n_light:
-                    if rounds >= _MAX_ROUNDS:
-                        self._quarantine(b, "non_convergence",
-                                         "drain solve did not converge")
-                        active[b] = False
-                        self.rounds += rounds
-                        opstats.bump("fixpoint_rounds", rounds)
-                    continue
-                self.rounds += rounds
-                opstats.bump("fixpoint_rounds", rounds)
-                rep = self.replicas[b]
-                dt, n_live = float(st[b, 2]), int(st[b, 3])
-                done = st[b, 4:] > 0
-                if np.isnan(dt):
-                    self._quarantine(
-                        b, "nan_solve",
-                        "drain solve produced a non-finite clock "
-                        "advance (NaN)")
-                    active[b] = False
-                    continue
-                if not np.isfinite(dt):
-                    self._quarantine(b, *self._stall_cause(b, n_live))
-                    active[b] = False
-                    continue
-                rep.t += dt
-                rep.advances += 1
-                for fid in np.flatnonzero(done):
-                    rep.events.append((rep.t, int(fid)))
-                if n_live == 0:
-                    rep.alive = False
-                    self._alive[b] = False
-                active[b] = False
-            if not active.any():
-                break
-        self._pen = self._pin(self._pen)
-        self._rem = self._pin(self._rem)
-
     def _rescue_superstep(self, stuck: List[int]) -> None:
-        """The tape-aware budget rescue: re-dispatch the stuck lanes
-        only (restricted alive mask — every other lane runs k=0 and is
+        """The budget rescue: re-dispatch the stuck lanes only
+        (restricted alive mask — every other lane runs k=0 and is
         frozen bit-for-bit) for ONE advance with the FULL round budget.
         Collecting with rescue=True converts lanes that still cannot
         converge into non-convergence deaths, the fleet mirror of the
-        solo tape rescue raising "did not converge"."""
+        solo rescue raising "did not converge"."""
         self.rescues += 1
         restricted = np.zeros(self.B_padded, bool)
         restricted[stuck] = True
         tok = self._superstep_issue_all(k=1, alive=restricted,
-                                        round_budget=_MAX_ROUNDS,
-                                        donate=True)
+                                        round_budget=_MAX_ROUNDS)
         self._superstep_collect_all(tok, rescue=True)
 
     def _run_pipelined(self, max_supersteps: int,
@@ -1779,8 +1618,7 @@ class BatchDrainSim:
                     inflight.append(self._superstep_issue_all(
                         pen=pen, rem=rem, speculative=spec,
                         cb=cb, tpos=tpos, t0=t0,
-                        pred=pred, ready=ready, clk=clk,
-                        donate=not spec))
+                        pred=pred, ready=ready, clk=clk))
                 tok = inflight.popleft()
                 _n_alive, clean = self._superstep_collect_all(tok)
                 left -= 1

@@ -11,20 +11,14 @@ rank has posted all sends/receives and the maestro's loop degenerates to
 (reference: surf_solve + Model::update_actions_state,
 src/kernel/resource/Model.cpp:40-101).  The reference executes that loop
 one C++ step at a time; this executor keeps ALL solver and flow state
-device-resident across advances and offers three dispatch shapes:
-
-* **unfused** (legacy): one dispatch for the solve chunks, one for the
-  dt/retire step — >= 2 host syncs per advance;
-* **fused** (``fused=True``): the fixpoint chunk AND the dt/retire step
-  run in ONE jitted dispatch whose single fetch carries the stats and
-  the completion mask — 1 sync per advance;
-* **supersteps** (``superstep=K``): a ``lax.while_loop`` over
-  (solve -> dt -> retire) executes up to K advances per dispatch,
-  logging completions into a fixed-size device ring buffer
-  ``(time, flow_id)`` fetched in ONE transfer — amortized syncs drop to
-  ~1/K per advance.  A per-dispatch round budget bounds one
-  dispatch's run time (same reasoning as lmm_jax._CHUNK_ROUNDS_ACCEL:
-  a spinning solve must return to the host and raise).
+device-resident across advances and runs the loop as **supersteps**:
+a ``lax.while_loop`` over (solve -> dt -> retire) executes up to K
+advances per dispatch (``superstep=K``), logging completions into a
+fixed-size device ring buffer ``(time, flow_id)`` fetched in ONE
+transfer — amortized syncs are ~1/K per advance, and K = 1 is one
+advance a dispatch.  A per-dispatch round budget bounds one dispatch's
+run time (same reasoning as lmm_jax._CHUNK_ROUNDS_ACCEL: a spinning
+solve must return to the host and raise).
 
 Completion grouping is RELATIVE by default (``rem2 <= done_eps * size``,
 the reference's sg_maxmin_precision/sg_surf_precision semantics,
@@ -46,17 +40,15 @@ the whole run.
 
 Python bookkeeping is O(completed flows) per advance (recording events),
 not O(system).  When the live flow population halves, the element list
-is repacked: host-side (one re-upload) on the unfused/fused paths, and
-ON DEVICE on the superstep path — a stable live-first partition (the
-same machinery as lmm_jax's compaction chain) dispatched without any
-host round-trip, so halving the live set costs one kernel launch
-instead of a fetch + re-upload.
+is repacked ON DEVICE — a stable live-first partition (the same
+machinery as lmm_jax's compaction chain) dispatched without any host
+round-trip, so halving the live set costs one kernel launch instead of
+a fetch + re-upload.
 
-The kernel programs (`_solve_chunk_program`, `_fused_step_program`,
-`_superstep_program`) double as the LANE bodies of the batched
-multi-replica executor (ops.lmm_batch), which vmaps them over a
-leading replica axis to drain whole scenario fleets per dispatch —
-keep them pure functions of their arguments.
+The superstep program (`_superstep_program`) doubles as the LANE body
+of the batched multi-replica executor (ops.lmm_batch), which vmaps it
+over a leading replica axis to drain whole scenario fleets per
+dispatch — keep it a pure function of its arguments.
 
 Speculative pipelining (``pipeline=D``): JAX dispatch is ASYNC — only
 the completion-ring fetch blocks the host — so the superstep driver
@@ -110,12 +102,12 @@ def _to2d(a: np.ndarray, group: int = 8) -> np.ndarray:
     return a.reshape(-1, group)
 
 
-# The three kernel *programs* below are defined as plain functions and
+# The kernel *programs* below are defined as plain functions and
 # jitted by assignment so the batched executor (ops.lmm_batch) can vmap
-# the raw programs over a leading replica axis: one device program then
-# solves/advances a whole scenario fleet, amortizing the per-dispatch
-# cost across replicas.  Keep them functional (no global
-# state) — both the solo jits and the vmapped jits share them.
+# the raw superstep program over a leading replica axis: one device
+# program then advances a whole scenario fleet, amortizing the
+# per-dispatch cost across replicas.  Keep them functional (no global
+# state) — the solo jit and the vmapped jit share one program.
 
 def _solve_chunk_program(e_var, e_cnst, e_w, c_bound, v_penalty, v_bound,
                          carry, eps: float, n_c: int, n_v: int, chunk: int,
@@ -187,56 +179,6 @@ def _advance_math(pen, rem, thresh, values, zero_bits=None):
     pen2 = jnp.where(done, 0.0, pen)
     rem2 = jnp.where(done, 0.0, rem2)
     return dt, pen2, rem2, done
-
-
-@jax.jit
-def _drain_advance(v_penalty, rem, thresh, values, zero_bits):
-    """One time advance from solved rates (unfused path)."""
-    dtype = rem.dtype
-    dt, pen2, rem2, done = _advance_math(v_penalty, rem, thresh, values,
-                                         zero_bits)
-    n_live = jnp.count_nonzero(pen2 > 0)
-    head = jnp.stack([dt.astype(dtype), n_live.astype(dtype)])
-    return pen2, rem2, jnp.concatenate([head, done.astype(dtype)])
-
-
-def _fused_step_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
-                        thresh, carry, zero_bits, eps: float, n_c: int,
-                        n_v: int, chunk: int, has_bounds: bool = False):
-    """Fused solve+advance: run up to `chunk` more saturation rounds
-    and — if the fixpoint converged inside this dispatch — the dt/retire
-    step too, all in ONE dispatch whose single fetch returns
-    [rounds, n_light, dt, n_live] + the completion mask.  When the
-    solve needs more rounds the flow state is returned unchanged and
-    the caller re-dispatches with the carry (rare: local-rounds drains
-    converge in O(10) rounds)."""
-    opstats.bump("retraces")      # trace-time only; see _superstep
-    dtype = e_w.dtype
-    out = fixpoint(e_var, e_cnst, e_w, c_bound,
-                   jnp.zeros(n_c, bool), pen, v_bound,
-                   jnp.asarray(eps, dtype), n_c, n_v,
-                   parallel_rounds=True, carry=carry, max_rounds=chunk,
-                   return_carry=True, has_bounds=has_bounds,
-                   has_fatpipe=False)
-    carry2 = out[4]
-    n_light = jnp.count_nonzero(carry2[4])
-    converged = n_light == 0
-    dt, pen2, rem2, done = _advance_math(pen, rem, thresh, carry2[0],
-                                         zero_bits)
-    ok = converged & jnp.isfinite(dt)
-    pen_out = jnp.where(ok, pen2, pen)
-    rem_out = jnp.where(ok, rem2, rem)
-    done = done & ok
-    n_live = jnp.count_nonzero(pen_out > 0)
-    head = jnp.stack([out[3].astype(dtype), n_light.astype(dtype),
-                      dt.astype(dtype), n_live.astype(dtype)])
-    return pen_out, rem_out, carry2, \
-        jnp.concatenate([head, done.astype(dtype)])
-
-
-_drain_fused_step = functools.partial(
-    jax.jit, static_argnames=("eps", "n_c", "n_v", "chunk",
-                              "has_bounds"))(_fused_step_program)
 
 
 #: scalars at the head of the superstep's packed vector: rounds,
@@ -547,18 +489,6 @@ _drain_superstep = functools.partial(
                               "has_bounds", "has_tape",
                               "has_coll"))(_superstep_program)
 
-#: the donating twin: steady-state dispatches that chain from the
-#: COMMITTED flow state hand their (pen, rem) buffers to XLA for
-#: in-place reuse — the inputs are dead the moment the outputs are
-#: adopted, so the only cost is that the dispatch may never be
-#: retried or replayed from those inputs (see _superstep_issue's
-#: donate gate).  Donation is an aliasing hint, not a numeric change:
-#: the program text is identical, so events/clocks are bit-identical.
-_drain_superstep_donate = functools.partial(
-    jax.jit, static_argnames=("eps", "n_c", "n_v", "k_max", "group",
-                              "has_bounds", "has_tape", "has_coll"),
-    donate_argnames=("pen", "rem"))(_superstep_program)
-
 
 #: transition-payload field order (index = the static target code in
 #: the payload layout); the first three scatter into the 2D element
@@ -727,12 +657,11 @@ class DrainSim:
     (``done_mode="abs"``, bit-matching the engine's generic
     double_update path in f64).
 
-    `fused=True` runs solve+advance in one dispatch (1 sync/advance);
-    `superstep=K` batches up to K advances per dispatch (~1/K
+    `superstep=K` (>= 1) batches up to K advances per dispatch (~1/K
     syncs/advance) with on-device repacks.  `v_bound` optionally caps
     per-flow rates (TCP-gamma windows etc.).
 
-    `pipeline=D` (superstep mode only) keeps up to D speculative
+    `pipeline=D` keeps up to D speculative
     supersteps in flight beyond the one being collected: the host
     processes ring N while the device executes ring N+1, hiding the
     dispatch round trip.  Results are bit-identical to `pipeline=0` —
@@ -746,7 +675,7 @@ class DrainSim:
                  dtype=np.float32, solve_chunk: int = 0,
                  repack_at: float = 0.5, device=None,
                  v_bound=None, done_mode: str = "rel",
-                 fused: bool = False, superstep: int = 0,
+                 superstep: int = 16,
                  superstep_rounds: int = 0, repack_min: int = 1024,
                  penalty=None, remains=None, pipeline: int = 0,
                  tape=None, collective=None):
@@ -769,30 +698,28 @@ class DrainSim:
         # the repack kernels at small scale
         self.repack_min = int(repack_min)
         self.device = device
-        self.fused = bool(fused)
         self.superstep_k = int(superstep)
-        if self.superstep_k:
-            if not superstep_rounds:
-                # Per-dispatch round budget, the bound on one
-                # dispatch's run time: on an accelerator a superstep
-                # may burn at most what a few solve chunks would; on
-                # CPU the budget just has to cover K advances of
-                # O(10-100)-round solves.
-                platform = (device.platform if device is not None
-                            else default_platform())
-                if platform == "cpu":
-                    superstep_rounds = self.superstep_k * 512
-                else:
-                    superstep_rounds = self.solve_chunk * 4
-            self.superstep_rounds = int(superstep_rounds)
-        else:
-            self.superstep_rounds = 0
+        if self.superstep_k < 1:
+            raise ValueError(f"DrainSim(superstep={superstep}): the "
+                             "drain runs as supersteps of K >= 1 "
+                             "advances a dispatch")
+        if not superstep_rounds:
+            # Per-dispatch round budget, the bound on one dispatch's
+            # run time: on an accelerator a superstep may burn at most
+            # what a few solve chunks would; on CPU the budget just has
+            # to cover K advances of O(10-100)-round solves.
+            platform = (device.platform if device is not None
+                        else default_platform())
+            if platform == "cpu":
+                superstep_rounds = self.superstep_k * 512
+            else:
+                superstep_rounds = self.solve_chunk * 4
+        self.superstep_rounds = int(superstep_rounds)
 
         with opstats.span("drain.init"):
-            self._host: Optional[dict] = dict(
-                e_var=np.asarray(e_var, np.int32),
-                e_cnst=np.asarray(e_cnst, np.int32),
-                e_w=np.asarray(e_w, self.dtype))
+            elems = (np.asarray(e_var, np.int32),
+                     np.asarray(e_cnst, np.int32),
+                     np.asarray(e_w, self.dtype))
             self.n_c = len(c_bound)
             self.n_v = len(sizes)
             self._c_bound = np.asarray(c_bound, self.dtype)
@@ -801,12 +728,6 @@ class DrainSim:
                 raise ValueError(
                     "flow ids beyond 2^24 are not exact in the f32 "
                     "single-transfer fetch; use float64 or shard the drain")
-            # flow slot -> original flow id (survives repacks); host mirror
-            # may go stale after an on-device repack and is refetched
-            # lazily (_host_ids)
-            self._ids = np.arange(self.n_v)
-            self._ids_stale = False
-
             if done_mode == "rel":
                 thresh = self.done_eps * self._sizes
             else:
@@ -822,8 +743,7 @@ class DrainSim:
             self._thresh = jax.device_put(thresh.astype(self.dtype), device)
             self._ids_dev = jax.device_put(
                 np.arange(self.n_v, dtype=np.int32), device)
-            self._dev = [jax.device_put(_to2d(self._host[k]), device)
-                         for k in ("e_var", "e_cnst", "e_w")]
+            self._dev = [jax.device_put(_to2d(a), device) for a in elems]
             self._cb = jax.device_put(self._c_bound, device)
             if v_bound is not None:
                 vb = np.asarray(v_bound, self.dtype)
@@ -854,9 +774,6 @@ class DrainSim:
                     raise ValueError("tape dates must be time-sorted")
                 if np.any((ts < 0) | (ts >= self.n_c)):
                     raise ValueError("tape slot out of range")
-                if not superstep:
-                    raise ValueError("tape= needs superstep=K (faults fire "
-                                     "inside the superstep loop)")
                 self.has_tape = True
                 self._tape = tuple(jax.device_put(a, device)
                                    for a in (tt, ts, tv))
@@ -895,9 +812,6 @@ class DrainSim:
                 if len(ces) != len(ced):
                     raise ValueError("collective edge arrays must have "
                                      "equal length")
-                if not superstep:
-                    raise ValueError("collective= needs superstep=K (the "
-                                     "DAG walks inside the superstep loop)")
                 if self.dtype != np.float64:
                     raise ValueError("collective= needs dtype=float64 (the "
                                      "carried Kahan clock must match the "
@@ -936,9 +850,6 @@ class DrainSim:
                        if penalty is not None else self.n_v)
 
         self.pipeline = int(pipeline)
-        if self.pipeline and not self.superstep_k:
-            raise ValueError("pipeline=D needs superstep=K (speculation "
-                             "is a property of the superstep driver)")
 
         self.t = 0.0              # f64 master clock (host-accumulated)
         self.events: list = []   # (time, original flow id), completion order
@@ -959,51 +870,7 @@ class DrainSim:
         #: point, for both the pipelined and synchronous drivers.
         self.on_batches = None
 
-    # -- host-side helpers -------------------------------------------------
-
-    def _host_ids(self) -> np.ndarray:
-        """The slot -> original-flow-id mirror, refetched after an
-        on-device repack made it stale (one transfer, counted)."""
-        if self._ids_stale:
-            self._ids = opstats.timed_fetch(
-                self._ids_dev).astype(np.int64)
-            self.syncs += 1
-            self._ids_stale = False
-        return self._ids
-
-    def _repack_host(self) -> None:
-        """Drop retired flows' elements and rows (host-side, one
-        re-upload).  Live relative order is preserved, so reduction
-        order over survivors — and therefore event ordering — is
-        unchanged.  Unfused/fused paths only; the superstep path
-        repacks on device."""
-        pen = opstats.timed_fetch(self._pen)
-        rem = opstats.timed_fetch(self._rem)
-        thresh = opstats.timed_fetch(self._thresh)
-        self.syncs += 1
-        live = pen > 0
-        keep = np.flatnonzero(live)
-        old2new = np.full(self.n_v, -1, np.int32)
-        old2new[keep] = np.arange(len(keep), dtype=np.int32)
-        emask = live[self._host["e_var"]]
-        self._host = dict(
-            e_var=old2new[self._host["e_var"][emask]],
-            e_cnst=self._host["e_cnst"][emask],
-            e_w=self._host["e_w"][emask])
-        self._ids = self._host_ids()[keep]
-        self._sizes = self._sizes[keep]
-        self.n_v = len(keep)
-        self._pen = jax.device_put(pen[keep], self.device)
-        self._rem = jax.device_put(rem[keep], self.device)
-        self._thresh = jax.device_put(thresh[keep], self.device)
-        self._ids_dev = jax.device_put(
-            self._ids.astype(np.int32), self.device)
-        self._vb = jax.device_put(
-            opstats.timed_fetch(self._vb)[keep], self.device)
-        self._dev = [jax.device_put(_to2d(self._host[k]), self.device)
-                     for k in ("e_var", "e_cnst", "e_w")]
-        self._live0 = self.n_v
-        self.repacks += 1
+    # -- repack ------------------------------------------------------------
 
     def _repack_device(self, n_live: int, live_elems: int) -> bool:
         """Halve the device arrays in place with the stable live-first
@@ -1029,8 +896,6 @@ class DrainSim:
         self._ids_dev = ids
         self.n_v = vh
         self._live0 = n_live
-        self._ids_stale = True
-        self._host = None        # host mirrors no longer meaningful
         self.repacks += 1
         return True
 
@@ -1038,80 +903,7 @@ class DrainSim:
         return bool(n_live and n_live <= self._live0 * self.repack_at
                     and n_live >= self.repack_min)
 
-    # -- per-advance paths -------------------------------------------------
-
-    def advance(self) -> int:
-        """One solve + time advance; returns the remaining live count.
-        Uses the fused single-dispatch kernel when `fused=True`, the
-        legacy two-dispatch shape otherwise."""
-        if self.fused:
-            return self._advance_fused()
-        carry = None
-        while True:
-            carry, stats = _drain_solve_chunk(
-                *self._dev, self._cb, self._pen, self._vb, carry,
-                eps=self.eps, n_c=self.n_c, n_v=self.n_v,
-                chunk=self.solve_chunk, has_bounds=self.has_bounds)
-            st = opstats.timed_fetch(stats)
-            self.syncs += 1
-            rounds, n_light = int(st[0]), int(st[1])
-            if n_light == 0:
-                break
-            if rounds >= _MAX_ROUNDS:
-                raise SolveError("drain solve did not converge")
-        self.rounds += rounds
-        opstats.bump("dispatches")
-        opstats.bump("fixpoint_rounds", rounds)
-
-        self._pen, self._rem, out = _drain_advance(
-            self._pen, self._rem, self._thresh, carry[0], _ZERO_BITS)
-        out = opstats.timed_fetch(out)
-        self.syncs += 1
-        dt, n_live = float(out[0]), int(out[1])
-        done = out[2:] > 0
-        return self._commit_advance(dt, n_live, done)
-
-    def _advance_fused(self) -> int:
-        carry = None
-        while True:
-            self._pen, self._rem, carry, stats = _drain_fused_step(
-                *self._dev, self._cb, self._vb, self._pen, self._rem,
-                self._thresh, carry, _ZERO_BITS, eps=self.eps,
-                n_c=self.n_c, n_v=self.n_v, chunk=self.solve_chunk,
-                has_bounds=self.has_bounds)
-            st = opstats.timed_fetch(stats)
-            self.syncs += 1
-            rounds, n_light = int(st[0]), int(st[1])
-            if n_light == 0:
-                break
-            if rounds >= _MAX_ROUNDS:
-                raise SolveError("drain solve did not converge")
-        self.rounds += rounds
-        opstats.bump("dispatches")
-        opstats.bump("fixpoint_rounds", rounds)
-        dt, n_live = float(st[2]), int(st[3])
-        done = st[4:] > 0
-        return self._commit_advance(dt, n_live, done)
-
-    def _commit_advance(self, dt: float, n_live: int,
-                        done: np.ndarray) -> int:
-        if not np.isfinite(dt):
-            raise SolveError(
-                f"drain stalled: no flow holds bandwidth "
-                f"({n_live} live)")
-        # f64 host accumulation of the (dtype-precision) dt values
-        self.t += dt
-        self.advances += 1
-        ids = self._host_ids()
-        for fid in ids[np.flatnonzero(done)]:
-            self.events.append((self.t, int(fid)))
-        if self._should_repack(n_live):
-            if self._host is not None:
-                self._repack_host()
-            else:
-                # a previous device repack dropped the host mirrors
-                self._repack_device(n_live, self._live_elems())
-        return n_live
+    # -- solve-only and forced-advance paths (engine fast path) ------------
 
     def solve_rates(self) -> np.ndarray:
         """Solve the CURRENT flow state to convergence and fetch the
@@ -1175,7 +967,6 @@ class DrainSim:
             group=self._dev[0].shape[1])
         self._dev = list(out[:3])
         (self._cb, self._pen, self._rem, self._thresh, self._vb) = out[3:]
-        self._host = None      # host element mirrors are stale now
         opstats.bump("dispatches")
         opstats.bump("uploaded_bytes_delta", payload.nbytes)
         return slots
@@ -1215,21 +1006,13 @@ class DrainSim:
         done = np.flatnonzero(out[1:] > 0)
         return done, n_live
 
-    def _live_elems(self) -> int:
-        pen = np.asarray(self._pen)
-        ew = np.asarray(self._dev[2]).reshape(-1)
-        ev = np.asarray(self._dev[0]).reshape(-1)
-        self.syncs += 1
-        return int(np.count_nonzero((ew > 0) & (pen[ev] > 0)))
-
     # -- superstep path ----------------------------------------------------
 
     def _superstep_issue(self, k: Optional[int] = None, pen=None,
                          rem=None, speculative: bool = False,
                          stop_live: int = 0, cb=None, tpos=None,
                          t0=None, round_budget: int = 0,
-                         pred=None, ready=None, clk=None,
-                         donate: bool = False
+                         pred=None, ready=None, clk=None
                          ) -> SuperstepToken:
         """Dispatch ONE superstep of up to `k` advances WITHOUT
         touching the committed flow state: the dispatch chains from
@@ -1241,26 +1024,10 @@ class DrainSim:
         constraint bounds and tape cursor (`cb`, `tpos`) and needs the
         f64 base clock `t0` the dispatch starts from (default: the
         committed ``self.t``); speculative issues derive all three
-        from their predecessor's token.
-
-        ``donate=True`` hands the committed (pen, rem) buffers to XLA
-        for in-place reuse and adopts the outputs as the committed
-        state IMMEDIATELY (the inputs are deleted by the dispatch, so
-        leaving ``self._pen`` pointing at them would be a landmine).
-        Only honored on non-speculative issues chained from the
-        committed state: speculative issues must leave their inputs
-        alive for the mispredict replay, and explicit (pen, rem)
-        chains belong to callers (fastpath/replay) that snapshot
-        them."""
-        if not self.superstep_k and k is None:
-            raise ValueError("superstep_batch needs superstep=K "
-                             "(constructor) or an explicit k")
-        k_max = self.superstep_k or int(k)
-        if k is None:
-            k = k_max
-        k = min(int(k), k_max)
-        budget = (int(round_budget) or self.superstep_rounds
-                  or k_max * 512)
+        from their predecessor's token."""
+        k_max = self.superstep_k
+        k = k_max if k is None else min(int(k), k_max)
+        budget = int(round_budget) or self.superstep_rounds
         want_stop = (stop_live if stop_live
                      else (int(self._live0 * self.repack_at)
                            if self._live0 * self.repack_at
@@ -1274,13 +1041,10 @@ class DrainSim:
         pred_in = self._coll[0] if pred is None else pred
         ready_in = self._coll[1] if ready is None else ready
         clk_in = self._coll_clk if clk is None else clk
-        donate = (donate and not speculative
-                  and pen is None and rem is None)
-        step = _drain_superstep_donate if donate else _drain_superstep
         seq = self.supersteps
         with opstats.span("drain.issue", id=seq):
             (pen_out, rem_out, cb_out, tpos_out, pred_out, ready_out,
-             clk_out, packed) = step(
+             clk_out, packed) = _drain_superstep(
                 *self._dev, cb_in, self._vb, pen_in, rem_in,
                 self._thresh, self._ids_dev,
                 np.int32(k), np.int32(budget), np.int32(want_stop),
@@ -1289,14 +1053,6 @@ class DrainSim:
                 eps=self.eps, n_c=self.n_c, n_v=self.n_v,
                 k_max=k_max, group=group, has_bounds=self.has_bounds,
                 has_tape=self.has_tape, has_coll=self.has_coll)
-        if donate:
-            # the dispatch consumed the committed buffers: adopt the
-            # outputs NOW so no reachable reference is left deleted
-            # (collect re-adopts them, a no-op), and strip the dead
-            # inputs from the token so misuse fails loudly
-            self._pen, self._rem = pen_out, rem_out
-            pen_in = rem_in = None
-            opstats.bump("donated_buffers", 2)
         self.supersteps += 1
         opstats.bump("dispatches")
         if speculative:
@@ -1460,21 +1216,16 @@ class DrainSim:
 
     def superstep_batch(self, k: Optional[int] = None,
                         fetch: bool = True, stop_live: int = 0,
-                        round_budget: int = 0,
-                        donate: bool = False):
+                        round_budget: int = 0):
         """Dispatch ONE superstep of up to `k` advances and (optionally)
         fetch its packed result — a single transfer.
 
         Returns (n_live, batches) where batches is a list of
         (dt, [original flow ids]) per executed advance; with
         fetch=False nothing is transferred (replay) and (None, None) is
-        returned.  Events/clock/counters are committed on fetch.
-        ``donate=True`` (steady-state drivers only — never replay
-        paths that keep a batch-start snapshot) lets the dispatch
-        reuse the committed (pen, rem) buffers in place."""
+        returned.  Events/clock/counters are committed on fetch."""
         tok = self._superstep_issue(k, stop_live=stop_live,
-                                    round_budget=round_budget,
-                                    donate=donate)
+                                    round_budget=round_budget)
         if not fetch:
             self._pen, self._rem = tok.pen_out, tok.rem_out
             if self.has_tape:
@@ -1543,8 +1294,7 @@ class DrainSim:
                     inflight.append(self._superstep_issue(
                         k, pen=pen, rem=rem, speculative=spec,
                         cb=cb, tpos=tpos, t0=t0,
-                        pred=pred, ready=ready, clk=clk,
-                        donate=not spec))
+                        pred=pred, ready=ready, clk=clk))
                     issued_k += k
                 tok = inflight.popleft()
                 issued_k -= tok.k
@@ -1572,10 +1322,8 @@ class DrainSim:
                     if (n or self._coll_open()) \
                             and self.advances == before:
                         # the round budget expired inside the first
-                        # solve: finish ONE advance (full-budget
-                        # superstep when a tape is armed — the fused
-                        # rescue path cannot see tape events — else
-                        # the chunked fused path)
+                        # solve: finish ONE advance with the full
+                        # round budget
                         after = self.advances
                         n = self._rescue_one()
                         budget -= 1
@@ -1592,17 +1340,11 @@ class DrainSim:
 
     def _rescue_one(self) -> int:
         """Finish ONE advance after the superstep round budget expired
-        inside its first solve.  With a fault tape the rescue must stay
-        on the superstep path (the fused kernel would step straight
-        over a tape event): re-dispatch k=1 with the FULL round budget
-        — its collect raises "did not converge" if even that fails.
-        Without a tape, the chunked fused path (which converges across
-        dispatches) is cheaper."""
-        if self.has_tape or self.has_coll:
-            n, _ = self.superstep_batch(k=1, round_budget=_MAX_ROUNDS,
-                                        donate=True)
-            return n
-        return self._advance_fused()
+        inside its first solve: re-dispatch k=1 with the FULL round
+        budget — its collect raises "did not converge" if even that
+        fails."""
+        n, _ = self.superstep_batch(k=1, round_budget=_MAX_ROUNDS)
+        return n
 
     def _coll_open(self) -> bool:
         """True while an armed collective schedule still owes
@@ -1611,31 +1353,35 @@ class DrainSim:
         drivers must keep dispatching until every DAG flow completed."""
         return self.has_coll and len(self.events) < self._coll_total
 
+    def advance(self) -> int:
+        """One solve + time advance, as one K = 1 dispatch and one
+        fetch; returns the remaining live count."""
+        before = self.advances
+        n, _ = self.superstep_batch(k=1)
+        if (n or self._coll_open()) and self.advances == before:
+            n = self._rescue_one()
+        return n
+
     def run(self, max_advances: int = 10_000_000) -> None:
-        n = self.n_v
-        if self.superstep_k and self.pipeline:
+        if self.pipeline:
             self._run_pipelined(max_advances)
             return
-        if self.superstep_k:
-            while (n or self._coll_open()) and max_advances > 0:
-                before = self.advances
-                k = min(self.superstep_k, max_advances)
-                n, _ = self.superstep_batch(k=k, donate=True)
-                max_advances -= self.advances - before
-                if (n or self._coll_open()) and self.advances == before:
-                    # the round budget expired inside the first solve:
-                    # finish ONE advance, then resume
-                    n = self._rescue_one()
-                    max_advances -= 1
-                    if self.advances == before and self._coll_open():
-                        # no live flow, no pending activation, but the
-                        # schedule still owes completions: a cyclic or
-                        # truncated DAG would spin here forever
-                        raise SolveError(
-                            "collective schedule deadlocked: "
-                            f"{len(self.events)}/{self._coll_total} "
-                            "flows completed and nothing is pending")
-            return
-        while n and max_advances:
-            n = self.advance()
-            max_advances -= 1
+        n = self.n_v
+        while (n or self._coll_open()) and max_advances > 0:
+            before = self.advances
+            k = min(self.superstep_k, max_advances)
+            n, _ = self.superstep_batch(k=k)
+            max_advances -= self.advances - before
+            if (n or self._coll_open()) and self.advances == before:
+                # the round budget expired inside the first solve:
+                # finish ONE advance, then resume
+                n = self._rescue_one()
+                max_advances -= 1
+                if self.advances == before and self._coll_open():
+                    # no live flow, no pending activation, but the
+                    # schedule still owes completions: a cyclic or
+                    # truncated DAG would spin here forever
+                    raise SolveError(
+                        "collective schedule deadlocked: "
+                        f"{len(self.events)}/{self._coll_total} "
+                        "flows completed and nothing is pending")

@@ -94,14 +94,6 @@ context object through the solver entry points:
                               device results; the overlap fraction of
                               the pipelined drain is
                               1 - host_block_ms/phase wall)
-* ``donated_buffers``       — carried-state device buffers handed to
-                              XLA for in-place reuse by donating
-                              superstep dispatches (ops.lmm_drain /
-                              ops.lmm_batch ``donate=``: one bump per
-                              donated argument, so steady-state drains
-                              add 2 — pen and rem — per superstep);
-                              the donation win proglint's ``donation``
-                              rule verifies in the lowered IR
 * ``speculations_issued`` / ``speculations_committed`` /
   ``speculations_rolled_back`` — speculative supersteps dispatched
                               in-flight by the pipelined drain

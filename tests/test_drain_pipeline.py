@@ -77,7 +77,7 @@ class TestSoloPipelineBitIdentity:
         assert sim.t == ref.t
 
     def test_budget_rescue_mispredict(self, drain_system):
-        """A starved round budget forces _FLAG_BUDGET exits and fused
+        """A starved round budget forces _FLAG_BUDGET exits and K = 1
         rescues between supersteps — the rescue mutates flow state, so
         in-flight speculation is discarded; the replayed drain must
         match the unpipelined one bit-for-bit."""
@@ -92,7 +92,8 @@ class TestSoloPipelineBitIdentity:
     def test_ring_saturation_rescue(self):
         """The ring-saturation shape (whole drain in one superstep)
         under a starved budget: partial batches + rescue advances
-        replay to the unfused event stream with pipelining on."""
+        replay to the per-advance (K = 1) event stream with
+        pipelining on."""
         groups, per = 6, 40
         n_v = groups * per
         e_var, e_cnst, e_w = [], [], []
@@ -106,7 +107,7 @@ class TestSoloPipelineBitIdentity:
         sizes = np.repeat(1e6 * (1.0 + np.arange(groups)), per)
         args = (np.array(e_var, np.int32), np.array(e_cnst, np.int32),
                 np.array(e_w), c_bound, sizes)
-        ref = DrainSim(*args, eps=1e-9, dtype=np.float64,
+        ref = DrainSim(*args, eps=1e-9, dtype=np.float64, superstep=1,
                        repack_min=1 << 62)
         ref.run()
         sim = DrainSim(*args, eps=1e-9, dtype=np.float64, superstep=K,
@@ -118,8 +119,8 @@ class TestSoloPipelineBitIdentity:
 
     def test_pipeline_requires_superstep(self, drain_system):
         ev, ec, ew, cb, sizes = drain_system
-        with pytest.raises(ValueError):
-            DrainSim(ev, ec, ew, cb, sizes, pipeline=1)
+        with pytest.raises(ValueError, match="superstep=0"):
+            DrainSim(ev, ec, ew, cb, sizes, superstep=0, pipeline=1)
 
 
 class TestFleetPipeline:

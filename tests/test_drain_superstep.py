@@ -1,16 +1,16 @@
 """Superstepped device-resident drain (ISSUE 2): relative-precision
-completion grouping, fused solve+advance, K-advance supersteps with the
-completion ring buffer, on-device repacks, and the engine's drain
-fast path.
+completion grouping, K-advance supersteps with the completion ring
+buffer, on-device repacks, and the engine's drain fast path.
 
 The seeded 1k-flow FAT-TREE drain is the tier-1 anchor: the flow set is
 built through the real platform/routing stack (cluster fat-tree, d-mod-k
-routing), flattened once, then drained by every executor shape.  The
-acceptance contract (ISSUE 2):
+routing), flattened once, then drained at every dispatch grouping.
+The per-advance reference is K = 1: one advance a dispatch, the f64
+clock summed on the host.  The acceptance contract (ISSUE 2):
 
   (a) f32 relative-grouping event order == the f64 oracle order,
   (b) DrainSim.syncs <= advances/K + repacks + 2 under supersteps,
-  (c) fused-dispatch results bit-identical to the unfused path on CPU.
+  (c) an ``advance()`` loop bit-identical to ``run()`` at K = 1 on CPU.
 """
 
 import os
@@ -113,38 +113,43 @@ def fat_tree_drain(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def drained(fat_tree_drain):
-    """Every executor shape drained ONCE over the same seeded system;
-    the parity tests below share these (each drain costs hundreds of
-    dispatches — the tier-1 suite is wall-clock-bound)."""
+    """Every dispatch grouping drained ONCE over the same seeded
+    system; the parity tests below share these (each drain costs
+    hundreds of dispatches — the tier-1 suite is wall-clock-bound).
+    ``u64`` is driven one ``advance()`` at a time, the rest by
+    ``run()``."""
     arrays, sizes, _ = fat_tree_drain
     sims = {}
     for label, dtype, eps, kw in (
-            ("u64", np.float64, 1e-9, {}),
-            ("f64", np.float64, 1e-9, dict(fused=True)),
+            ("u64", np.float64, 1e-9, dict(superstep=1)),
+            ("k64", np.float64, 1e-9, dict(superstep=1)),
             ("s64", np.float64, 1e-9, dict(superstep=K)),
-            ("f32", np.float32, 1e-5, dict(fused=True)),
+            ("k32", np.float32, 1e-5, dict(superstep=1)),
             ("s32", np.float32, 1e-5, dict(superstep=K))):
         sim = make_sim(arrays, sizes, dtype, eps, **kw)
-        sim.run()
+        if label == "u64":
+            while sim.advance():
+                pass
+        else:
+            sim.run()
         sims[label] = sim
     return sims
 
 
 class TestFatTreeDrainParity:
     """ISSUE 2 acceptance: identical completion-event order across
-    {f64 unfused, f32 fused, f32 superstep K=16} on the seeded 1k-flow
+    {f64 K=1, f32 K=1, f32 superstep K=16} on the seeded 1k-flow
     fat-tree drain, and syncs-per-advance < 0.2 under supersteps."""
 
     def test_order_and_sync_budget(self, fat_tree_drain, drained):
         arrays, _, _ = fat_tree_drain
-        s64, f32_fused, f32_ss = (drained["u64"], drained["f32"],
-                                  drained["s32"])
+        s64, f32_k1, f32_ss = (drained["u64"], drained["k32"],
+                               drained["s32"])
         assert len(s64.events) == arrays.n_var
         order64 = [f for _, f in s64.events]
-        assert [f for _, f in f32_fused.events] == order64
-        # fused = 1 dispatch+fetch per advance (modulo rare re-chunks)
-        assert f32_fused.syncs <= f32_fused.advances \
-            + f32_fused.repacks + 2
+        assert [f for _, f in f32_k1.events] == order64
+        # K = 1: 1 dispatch+fetch per advance (modulo rare rescues)
+        assert f32_k1.syncs <= f32_k1.advances + f32_k1.repacks + 2
         assert [f for _, f in f32_ss.events] == order64
         # (b) the superstep sync budget: ~1/K syncs per advance
         assert f32_ss.syncs <= f32_ss.advances / K + f32_ss.repacks + 2
@@ -153,13 +158,17 @@ class TestFatTreeDrainParity:
         # contract that broke the round-5 TPU drain)
         assert f32_ss.advances == s64.advances
 
-    def test_fused_bit_identical_to_unfused(self, drained):
-        """(c) the fused dispatch is the same math in one kernel: the
-        event stream (times AND ids) must match bit-for-bit."""
-        assert drained["u64"].events == drained["f64"].events
-        assert drained["f64"].syncs < drained["u64"].syncs
+    def test_advance_loop_bit_identical_to_run_k1(self, drained):
+        """(c) ``advance()`` is the K = 1 dispatch ``run()`` issues:
+        the event stream (times AND ids), the clock and the dispatch
+        census must match bit-for-bit."""
+        a, b = drained["u64"], drained["k64"]
+        assert a.events == b.events
+        assert a.t == b.t
+        assert (a.advances, a.supersteps, a.syncs, a.repacks) == \
+            (b.advances, b.supersteps, b.syncs, b.repacks)
 
-    def test_superstep_f64_matches_unfused_order(self, drained):
+    def test_superstep_f64_matches_k1_order(self, drained):
         a, b = drained["u64"], drained["s64"]
         assert [f for _, f in a.events] == [f for _, f in b.events]
         # the superstep clock is Kahan-compensated per dispatch and
@@ -179,8 +188,8 @@ class TestRelativeGrouping:
         e_w = np.ones(n)
         c_bound = np.full(n, 1e6)
         sizes = np.full(n, 1e6)
-        for dtype, eps, kw in ((np.float64, 1e-9, {}),
-                               (np.float32, 1e-5, dict(fused=True)),
+        for dtype, eps, kw in ((np.float64, 1e-9, dict(superstep=1)),
+                               (np.float32, 1e-5, dict(superstep=1)),
                                (np.float32, 1e-5, dict(superstep=K))):
             sim = DrainSim(idx, idx, e_w.astype(dtype),
                            c_bound.astype(dtype), sizes, eps=eps,
@@ -194,10 +203,10 @@ class TestRelativeGrouping:
         rng = np.random.default_rng(11)
         arrays = build_arrays(rng, 64, 300, 2, np.float64)
         sizes = rng.uniform(1e5, 2e6, 300)
-        rel = make_sim(arrays, sizes, np.float64, 1e-9, fused=True)
+        rel = make_sim(arrays, sizes, np.float64, 1e-9, superstep=1)
         rel.run()
         ab = make_sim(arrays, sizes, np.float64, 1e-9, done_mode="abs",
-                      fused=True)
+                      superstep=1)
         ab.run()
         assert len(ab.events) == 300
         # relative grouping only merges near-ties: per-flow completion
@@ -213,7 +222,7 @@ class TestSuperstepSaturation:
     """ISSUE 4 satellite: the superstep's two partial-batch exits —
     the round budget expiring mid-superstep (_FLAG_BUDGET) and the
     completion ring filling to capacity in one dispatch — must both
-    replay to the exact unfused event order."""
+    replay to the exact per-advance (K = 1) event order."""
 
     @staticmethod
     def _chain_system(groups=6, per=40):
@@ -240,7 +249,8 @@ class TestSuperstepSaturation:
     def test_ring_at_capacity_single_superstep(self):
         ev, ec, ew, cb, sizes, n_v = self._chain_system()
         ref = DrainSim(ev, ec, ew, cb, sizes, eps=1e-9,
-                       dtype=np.float64, repack_min=1 << 62)
+                       dtype=np.float64, superstep=1,
+                       repack_min=1 << 62)
         ref.run()
         sim = DrainSim(ev, ec, ew, cb, sizes, eps=1e-9,
                        dtype=np.float64, superstep=K,
@@ -256,11 +266,12 @@ class TestSuperstepSaturation:
         """A tiny per-dispatch round budget forces _FLAG_BUDGET exits
         inside (and between) advances: the partial-batch handling —
         committing only completed advances, then finishing one advance
-        via the chunked fused rescue — must reproduce the unfused
-        event stream bit-for-bit."""
+        via the full-budget K = 1 rescue — must reproduce the
+        per-advance event stream bit-for-bit."""
         ev, ec, ew, cb, sizes, n_v = self._chain_system()
         ref = DrainSim(ev, ec, ew, cb, sizes, eps=1e-9,
-                       dtype=np.float64, repack_min=1 << 62)
+                       dtype=np.float64, superstep=1,
+                       repack_min=1 << 62)
         ref.run()
         sim = DrainSim(ev, ec, ew, cb, sizes, eps=1e-9,
                        dtype=np.float64, superstep=K,
@@ -272,9 +283,9 @@ class TestSuperstepSaturation:
         assert sim.events == ref.events
         assert sim.t == ref.t
 
-    def test_budget_batch_fleet_matches_unfused(self):
+    def test_budget_batch_fleet_matches_k1(self):
         """The BATCHED executor under the same budget pressure: every
-        replica's partial-batch rescue replays to its own solo unfused
+        replica's partial-batch rescue replays to its own solo K = 1
         order (the fleet-level mirror of the test above)."""
         from simgrid_tpu.parallel.campaign import Campaign, ScenarioSpec
 
@@ -287,10 +298,81 @@ class TestSuperstepSaturation:
         for b, spec in enumerate(specs):
             scb = cb * spec.bw_scale
             ref = DrainSim(ev, ec, ew, scb, sizes, eps=1e-9,
-                           dtype=np.float64, repack_min=1 << 62)
+                           dtype=np.float64, superstep=1,
+                           repack_min=1 << 62)
             ref.run()
             assert results[b].events == ref.events
             assert results[b].t == ref.t
+
+
+class TestOneDrainProgram:
+    """The drain has one device program, the superstep: ``advance()``
+    and the budget rescue are K = 1 dispatches of it."""
+
+    def test_advance_is_one_dispatch_and_one_fetch(self):
+        from simgrid_tpu.ops import opstats
+
+        ev, ec, ew, cb, sizes, n_v = \
+            TestSuperstepSaturation._chain_system()
+        sim = DrainSim(ev, ec, ew, cb, sizes, eps=1e-9,
+                       dtype=np.float64, repack_min=1 << 62)
+        before = opstats.snapshot()
+        n = sim.advance()
+        d = opstats.diff(before)
+        assert (sim.supersteps, sim.syncs, sim.advances) == (1, 1, 1)
+        assert d.get("dispatches", 0) == 1 and d.get("fetches", 0) == 1
+        # one tie group of 40 retired; the live count comes back
+        assert n == n_v - 40 == n_v - len(sim.events)
+        while n:
+            n = sim.advance()
+        assert sim.supersteps == sim.syncs == sim.advances == 6
+
+    def test_starved_budget_on_a_plain_sim_is_rescued_by_k1(self):
+        """No tape, no collective: a budget that expires inside the
+        first solve is finished by a full-budget K = 1 superstep, which
+        reports the element rows its rounds worked like any other."""
+        from bench import build_arrays
+        from simgrid_tpu.ops import opstats
+
+        rng = np.random.default_rng(11)
+        arrays = build_arrays(rng, 64, 300, 2, np.float64)
+        sizes = rng.uniform(1e5, 2e6, 300)
+        ref = make_sim(arrays, sizes, np.float64, 1e-9, superstep=1)
+        ref.run()
+        sim = make_sim(arrays, sizes, np.float64, 1e-9, superstep=K,
+                       superstep_rounds=2)
+        rescued, n = 0, sim.n_v
+        while n:
+            before, s0, a0 = opstats.snapshot(), sim.supersteps, \
+                sim.advances
+            elems = sim._dev[0].size
+            n = sim.advance()
+            d = opstats.diff(before)
+            assert sim.advances == a0 + 1
+            if sim.supersteps == s0 + 2:
+                # the starved K = 1 dispatch, then its rescue: both
+                # report every element row their rounds indexed (one
+                # rung at this size)
+                rescued += 1
+                assert d["dispatches"] == d["fetches"] == 2
+                assert d["fixpoint_rounds"] > 2
+                assert d["fixpoint_worked_elem_rounds"] == \
+                    d["fixpoint_rounds"] * elems
+        assert rescued > 0
+        assert sim.events == ref.events and sim.t == ref.t
+
+    def test_superstep_below_one_refused_by_name(self, tmp_path):
+        ev, ec, ew, cb, sizes, _ = \
+            TestSuperstepSaturation._chain_system()
+        with pytest.raises(ValueError, match=r"superstep=0\b"):
+            DrainSim(ev, ec, ew, cb, sizes, superstep=0)
+        with pytest.raises(ValueError, match="drain/superstep:0"):
+            _run_engine_drain(
+                str(tmp_path),
+                ["lmm/backend:jax", "network/optim:Full",
+                 "network/maxmin-selective-update:no",
+                 "drain/fastpath:auto", "drain/min-flows:64",
+                 "drain/superstep:0"])
 
 
 class TestRetraceSentinel:

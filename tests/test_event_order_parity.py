@@ -14,13 +14,11 @@ from simgrid_tpu.ops.lmm_drain import DrainSim
 
 def drain_events(arrays, sizes, dtype, eps):
     E = arrays.n_elem
-    # fused solve+advance: halves the dispatches per advance and is
-    # bit-identical to the unfused path (pinned by
-    # tests/test_drain_superstep.py::test_fused_bit_identical_to_unfused)
+    # K = 1: one advance a dispatch, the f64 clock summed on the host
     sim = DrainSim(arrays.e_var[:E], arrays.e_cnst[:E],
                    arrays.e_w[:E].astype(dtype),
                    arrays.c_bound[:arrays.n_cnst].astype(dtype),
-                   sizes, eps=eps, dtype=dtype, fused=True)
+                   sizes, eps=eps, dtype=dtype, superstep=1)
     sim.run()
     return sim.events
 
@@ -90,6 +88,6 @@ def test_equal_flows_complete_in_one_tie_group():
         sim = DrainSim(arrays.e_var[:E], arrays.e_cnst[:E],
                        arrays.e_w[:E].astype(dtype),
                        arrays.c_bound[:arrays.n_cnst].astype(dtype),
-                       sizes, eps=eps, dtype=dtype, fused=True)
+                       sizes, eps=eps, dtype=dtype, superstep=1)
         sim.run()
         assert len(sim.events) == 1000

@@ -100,9 +100,10 @@ def test_tape_fires_at_exact_dates_and_clamps_dt():
         == (sim.events, sim.t, sim.fault_events)
 
 
-def test_tape_requires_superstep_mode():
-    with pytest.raises(ValueError, match="superstep"):
-        _hand_sim(_HAND_TAPE, superstep=0)
+def test_superstep_below_one_refused_by_name():
+    for k in (0, -1):
+        with pytest.raises(ValueError, match=rf"superstep={k}\b"):
+            _hand_sim(_HAND_TAPE, superstep=k)
 
 
 def test_tape_validates_slots_and_order():

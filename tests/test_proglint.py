@@ -294,15 +294,23 @@ class TestShapeDiscipline:
 class TestRegistry:
     def test_every_registered_program_stages_and_passes(self):
         specs = iter_programs()
-        assert len(specs) >= 12
+        assert len(specs) == 11
         findings = lint_programs(specs)
         assert findings == [], "\n".join(
             f"{f.path}: [{f.rule}] {f.message}" for f in findings)
 
-    def test_superstep_contracts_require_donated_carries(self):
-        by_name = {s.name: s for s in iter_programs()}
-        for name in ("drain/superstep", "fleet/superstep"):
-            assert by_name[name].contract.donated == ("pen", "rem")
+    def test_no_registered_program_aliases_an_input(self):
+        """Every dispatch leaves its inputs alive (speculative issues
+        and replays re-read them): no contract donates and no lowered
+        module carries an aliasing attribute."""
+        from simgrid_tpu.analysis.prog.rules import (_DONATION_ATTRS,
+                                                     stage)
+        for spec in iter_programs():
+            assert spec.contract.donated == (), spec.name
+            ir = stage(spec)
+            assert not any(ir.donated_flags), spec.name
+            assert not any(a in ir.lowered_text
+                           for a in _DONATION_ATTRS), spec.name
 
     def test_rule_filter(self):
         spec = iter_programs()[0]

@@ -14,10 +14,10 @@ opstats registry) against the checked-in
 tools/simlint_baseline.json.
 Run directly (exit 1 on violations) or through tests/test_determinism_lint.py.
 
-``--runtime-drain`` additionally executes the drain executor's three
-dispatch shapes (unfused, fused, superstep) twice each on a seeded
-system and verifies (a) run-to-run bit-reproducibility and (b)
-cross-mode completion-order equality — the dynamic counterpart of the
+``--runtime-drain`` additionally executes the drain executor at two
+dispatch groupings (superstep K=1, superstep K=k) twice each on a
+seeded system and verifies (a) run-to-run bit-reproducibility and (b)
+cross-grouping completion-order equality — the dynamic counterpart of the
 static lint for the superstep path, whose ring-buffer event extraction
 must stay deterministic.
 
@@ -184,9 +184,9 @@ def collect_simlint_problems(repo_root: str) -> List[str]:
 
 def check_drain_runtime(seed: int = 13, n_c: int = 128, n_v: int = 800,
                         k: int = 8) -> List[str]:
-    """Dynamic determinism of the drain executor incl. the superstep
-    path: two runs per mode must be bit-identical (events, advance
-    count, clock) and all modes must agree on completion ORDER.
+    """Dynamic determinism of the drain executor: two runs per
+    dispatch grouping must be bit-identical (events, advance count,
+    clock) and both groupings must agree on completion ORDER.
     Returns a list of problem descriptions (empty = OK)."""
     import numpy as np
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -210,19 +210,17 @@ def check_drain_runtime(seed: int = 13, n_c: int = 128, n_v: int = 800,
 
     problems: List[str] = []
     streams = {}
-    for label, kw in (("unfused", {}), ("fused", dict(fused=True)),
-                      ("superstep", dict(superstep=k))):
+    for label, kw in (("superstep K=1", dict(superstep=1)),
+                      (f"superstep K={k}", dict(superstep=k))):
         a, b = run(**kw), run(**kw)
         if a.events != b.events or a.advances != b.advances \
                 or a.t != b.t:
             problems.append(f"{label}: two identical runs diverged "
                             f"({a.advances} vs {b.advances} advances)")
         streams[label] = [f for _, f in a.events]
-    base = streams["unfused"]
-    for label in ("fused", "superstep"):
-        if streams[label] != base:
-            problems.append(
-                f"{label}: completion order differs from unfused")
+    if streams[f"superstep K={k}"] != streams["superstep K=1"]:
+        problems.append(f"superstep K={k}: completion order differs "
+                        "from superstep K=1")
     return problems
 
 
@@ -416,7 +414,7 @@ def check_pipeline_runtime(seed: int = 29, n_c: int = 64, n_v: int = 400,
         # forced mispredict (the in-flight superstep ran on the
         # un-repacked arrays and must be discarded + replayed)
         "repack": dict(repack_min=32),
-        # starved round budget: _FLAG_BUDGET exits + fused rescues,
+        # starved round budget: _FLAG_BUDGET exits + K=1 rescues,
         # the other mispredict class
         "budget": dict(repack_min=1 << 62, superstep_rounds=3),
     }
@@ -1588,7 +1586,7 @@ def main(argv: List[str]) -> int:
                 print(f"  {p}")
             return 1
         print("check_determinism: drain runtime OK "
-              "(unfused/fused/superstep bit-reproducible, orders agree)")
+              "(superstep K=1 and K=k bit-reproducible, orders agree)")
         argv = [a for a in argv if a != "--runtime-drain"]
     repo_root = argv[1] if len(argv) > 1 else os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))

@@ -16,13 +16,12 @@ Workloads:
 Usage:
   python tools/e2e_drain.py --backend native|jax [--platform cpu|tpu]
          [--workload random|alltoall] [--flows 100000] [--ranks 320]
-         [--fused] [--superstep K]
+         [--superstep K] [--pipeline D]
          [--out FILE.jsonl] [--events-out FILE.npz]
 
-`--fused` runs the jax drain with the single-dispatch solve+advance
-kernel (1 sync/advance); `--superstep K` batches K advances per
-dispatch with the device completion ring (~1/K syncs/advance) and
-on-device repacks; `--pipeline D` additionally keeps D speculative
+`--superstep K` batches K advances per dispatch with the device
+completion ring (~1/K syncs/advance; K = 1 is one advance a dispatch)
+and on-device repacks; `--pipeline D` additionally keeps D speculative
 supersteps in flight (double-buffered rings: the host processes ring
 N while the device runs ring N+1 — bit-identical results, and the
 row carries the blocking-fetch split + speculation commit counters).  `--phase-stats` prints, per phase (build/route,
@@ -217,7 +216,7 @@ def compare_events(ref, got, window=2e-4):
 
 
 def drain_jax(arrays, slot_flow, size, platform=None, done_eps=1e-4,
-              fused=False, superstep=0, pipeline=0):
+              superstep=16, pipeline=0):
     import numpy as np
     if platform:
         import jax
@@ -235,15 +234,14 @@ def drain_jax(arrays, slot_flow, size, platform=None, done_eps=1e-4,
                    arrays.c_bound[:arrays.n_cnst].astype(dtype),
                    np.full(arrays.n_var, float(size)),
                    eps=1e-5, done_eps=done_eps, dtype=dtype,
-                   fused=fused, superstep=superstep,
-                   pipeline=pipeline)
+                   superstep=superstep, pipeline=pipeline)
     # warm the jits on the first advance before timing?  No: honest
     # end-to-end wall-clock includes compiles once per shape; report
     # both (first advance separately).
     fetch_mark = opstats.snapshot()
     t0 = time.perf_counter()
     n = sim.n_v
-    if superstep and pipeline:
+    if pipeline:
         # the pipelined driver owns the loop (speculative in-flight
         # supersteps; progress reported per collected ring)
         last = [time.perf_counter()]
@@ -259,29 +257,20 @@ def drain_jax(arrays, slot_flow, size, platform=None, done_eps=1e-4,
         sim.on_batches = report
         sim.run()
         n = 0
-    elif superstep:
+    else:
         while n:
             before = sim.advances
             n, _ = sim.superstep_batch()
             if n and sim.advances == before:
-                n = sim._advance_fused()
+                n = sim._rescue_one()
             print(f"[drain] superstep {sim.supersteps}: "
                   f"advances {sim.advances}, live {n}, "
                   f"t_sim {sim.t:.4f}, syncs {sim.syncs}, "
                   f"wall {time.perf_counter()-t0:.0f}s", flush=True)
-    else:
-        while n:
-            n = sim.advance()
-            if sim.advances % 50 == 0 or sim.advances <= 2:
-                print(f"[drain] advance {sim.advances}: live {n}, "
-                      f"t_sim {sim.t:.4f}, "
-                      f"wall {time.perf_counter()-t0:.0f}s", flush=True)
     wall = time.perf_counter() - t0
     fetch_stats = opstats.diff(fetch_mark)
     events = [(t, int(slot_flow[fid])) for t, fid in sim.events]
-    mode = ("pipeline" if superstep and pipeline else
-            "superstep" if superstep else
-            "fused" if fused else "unfused")
+    mode = "pipeline" if pipeline else "superstep"
     rec = dict(advances=sim.advances, wall_s=round(wall, 1),
                t_sim=sim.t, rounds=sim.rounds, syncs=sim.syncs,
                repacks=sim.repacks, jax_platform=dev.platform,
@@ -312,15 +301,13 @@ def main() -> None:
     ap.add_argument("--flows", type=int, default=100_000)
     ap.add_argument("--ranks", type=int, default=320)
     ap.add_argument("--size", type=float, default=1e6)
-    ap.add_argument("--fused", action="store_true",
-                    help="jax: fused solve+advance, 1 sync/advance")
-    ap.add_argument("--superstep", type=int, default=0, metavar="K",
+    ap.add_argument("--superstep", type=int, default=16, metavar="K",
                     help="jax: K advances per dispatch (~1/K "
                          "syncs/advance, on-device repacks)")
     ap.add_argument("--pipeline", type=int, default=0, metavar="D",
                     help="jax: keep D speculative supersteps in "
-                         "flight (requires --superstep; bit-identical "
-                         "results, blocking-fetch split on the row)")
+                         "flight (bit-identical results, "
+                         "blocking-fetch split on the row)")
     ap.add_argument("--phase-stats", action="store_true",
                     help="report per-phase dispatch count, uploaded "
                          "bytes (full vs delta) and fixpoint rounds; "
@@ -350,7 +337,7 @@ def main() -> None:
         events, stats = drain_native(arrays, slot_flow, args.size)
     else:
         events, stats = drain_jax(arrays, slot_flow, args.size,
-                                  args.platform, fused=args.fused,
+                                  args.platform,
                                   superstep=args.superstep,
                                   pipeline=args.pipeline)
     rec.update(stats)

@@ -39,8 +39,7 @@ sys.path.insert(0, REPO_ROOT)
 #: docstring would still parse
 CORE_COUNTERS = ("dispatches", "fetches", "fetched_bytes",
                  "blocking_fetches", "host_block_ms", "retraces",
-                 "donated_buffers", "plan_cache_hits",
-                 "plan_cache_misses")
+                 "plan_cache_hits", "plan_cache_misses")
 
 
 def simlint_problems(root: str) -> List[str]:
