@@ -455,7 +455,9 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     then false as well, so NO ROUND EVER RUNS ON A LIST THAT WAS CUT
     WHILE MORE WERE LIVE THAN IT HOLDS (such a list is only a slice
     nobody reads), and the caller's carry is the 6-tuple from which the
-    next call rebuilds liveness at full width and walks down again.  A
+    next call rebuilds liveness at full width, for one gather and one
+    scatter over the whole list (:func:`_entry`, what a cold call pays
+    too), and walks down again.  A
     list of up to ``2 * _LADDER_MIN_ELEMS`` has one rung: the program it
     lowered to before the ladder.  ``unroll=True`` keeps the single
     loop.
@@ -483,41 +485,31 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
         return lax.pmin(x, axis) if axis else x
 
     with jax.named_scope("sg.lmm.init"):
-        v_enabled = v_penalty > 0
-        e_valid = (e_w > 0) & jnp.take(v_enabled, e_var, fill_value=False)
-        safe_pen = jnp.where(v_enabled, v_penalty, 1.0)
-        e_upen = jnp.where(e_valid, e_w / jnp.take(safe_pen, e_var), 0.0)
-
-        # Initial usage per constraint: sum for SHARED, max for FATPIPE.
-        usage_sum = allsum(jnp.zeros(n_c, dtype).at[e_cnst].add(e_upen))
-        usage_max = allmax(jnp.zeros(n_c, dtype).at[e_cnst].max(e_upen))
-        usage0 = jnp.where(c_fatpipe, usage_max, usage_sum)
-
-        remaining0 = c_bound
-        # Initial light set: usage strictly positive (exact,
-        # maxmin.cpp:545) and remaining above the relative epsilon
-        # (maxmin.cpp:524).
-        light0 = (remaining0 > c_bound * eps) & (usage0 > 0)
-
-        # Derive the initial carry from the inputs (not fresh constants)
-        # so its varying-manual-axes match the loop output under
-        # shard_map+vmap.  Parked variables carry penalty=inf and
-        # inf*0.0 is NaN, so sanitize.
-        v_value0 = jnp.where(jnp.isfinite(v_penalty), v_penalty, 0.0) * 0.0
-        v_fixed0 = v_penalty < 0
-
-        if carry is None:
-            carry = (v_value0, v_fixed0, remaining0, usage0, light0,
-                     jnp.array(0, jnp.int32))
-
         # Element liveness and the live count per constraint ride the
         # loop state, so no round gathers v_fixed again.  Both are
-        # rebuilt HERE from the carry's v_fixed (fresh, or a mid-solve
-        # carry handed back by a chunked caller) and dropped at exit:
-        # the public carry stays the 6-tuple.
-        e_live0 = e_valid & ~jnp.take(carry[1], e_var)
-        n_live_c0 = allsum(jnp.zeros(n_c, jnp.int32).at[e_cnst].add(
-            e_live0.astype(jnp.int32)))
+        # rebuilt HERE (from the carry's v_fixed when a chunked caller
+        # hands a mid-solve carry back) and dropped at exit: the public
+        # carry stays the 6-tuple.
+        _, e_upen, e_live0, n_live_c0, usage0 = _entry(
+            e_var, e_cnst, e_w, c_fatpipe, v_penalty,
+            None if carry is None else carry[1], n_c, has_fatpipe, allsum,
+            allmax)
+        if carry is None:
+            remaining0 = c_bound
+            # Initial light set: usage strictly positive (exact,
+            # maxmin.cpp:545) and remaining above the relative epsilon
+            # (maxmin.cpp:524).
+            light0 = (remaining0 > c_bound * eps) & (usage0 > 0)
+
+            # Derive the initial carry from the inputs (not fresh
+            # constants) so its varying-manual-axes match the loop
+            # output under shard_map+vmap.  Parked variables carry
+            # penalty=inf and inf*0.0 is NaN, so sanitize.
+            v_value0 = jnp.where(jnp.isfinite(v_penalty), v_penalty,
+                                 0.0) * 0.0
+            carry = (v_value0, v_penalty < 0, remaining0, usage0, light0,
+                     jnp.array(0, jnp.int32))
+    v_enabled = v_penalty > 0
     start_it = carry[5]
     if max_rounds is None:
         max_rounds = _MAX_ROUNDS
@@ -806,6 +798,49 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
         return (v_value, remaining, usage, rounds, state[:6], state[9],
                 state[10], state[11], partitions)
     return v_value, remaining, usage, rounds
+
+
+def _entry(e_var, e_cnst, e_w, c_fatpipe, v_penalty, v_fixed, n_c: int,
+           has_fatpipe: bool, allsum, allmax):
+    """What ``fixpoint`` derives from the element list before its first
+    round (maxmin.cpp's start): ``(e_valid, e_upen, e_live, n_live_c,
+    usage)`` — the elements that count (positive weight on an enabled
+    variable), their weight over the variable's penalty, those whose
+    variable is not fixed yet, their count per constraint, and the
+    initial usage per constraint (sum for SHARED, max for FATPIPE).
+    ``v_fixed`` is None on a cold call and a carry's on a carried one,
+    whose usage the carry brings (``usage`` is then None).
+
+    An element-wide gather or scatter costs by the index whatever it
+    moves (PERF.md §5), so this issues ONE gather by ``e_var`` and ONE
+    scatter by ``e_cnst`` (a cold FATPIPE call one more, the max)."""
+    dtype = e_w.dtype
+    # The gather carries all that entry needs of a variable: its penalty
+    # where it is enabled (else 0) and the carry's v_fixed in the sign.
+    # A cold call's fixed variables (v_penalty < 0) are not enabled, so
+    # there liveness is validity.
+    pen = jnp.where(v_penalty > 0, v_penalty, 0.0)
+    if v_fixed is not None:
+        pen = jnp.where(v_fixed, -pen, pen)
+    e_pen = jnp.take(pen, e_var, fill_value=0)
+    e_valid = (e_w > 0) & (e_pen != 0)
+    e_live = (e_w > 0) & (e_pen > 0)
+    e_upen = jnp.where(
+        e_valid, e_w / jnp.where(e_valid, jnp.abs(e_pen), 1.0), 0.0)
+    if v_fixed is not None:
+        n_live_c = allsum(jnp.zeros(n_c, jnp.int32).at[e_cnst].add(
+            e_live.astype(jnp.int32)))
+        return e_valid, e_upen, e_live, n_live_c, None
+    # The usage sum and the live count ride one 2-wide scatter-add, as
+    # apply_fixes' three columns do; the count is exact in `dtype` under
+    # the element limit fixpoint checks.
+    sums = allsum(jnp.zeros((n_c, 2), dtype).at[e_cnst].add(
+        jnp.stack([e_upen, e_valid.astype(dtype)], axis=-1)))
+    usage = sums[:, 0]
+    if has_fatpipe:
+        usage_max = allmax(jnp.zeros(n_c, dtype).at[e_cnst].max(e_upen))
+        usage = jnp.where(c_fatpipe, usage_max, usage)
+    return e_valid, e_upen, e_live, sums[:, 1].astype(jnp.int32), usage
 
 
 #: Every rung of the ladder holds MORE than this many elements.  Down

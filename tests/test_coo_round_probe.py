@@ -86,8 +86,19 @@ def test_the_round_readings_at_128_hosts(records):
 
 def test_every_op_kind_has_a_price_in_both_layouts(records):
     ops = [r for r in records if r["what"] == "op"]
-    assert len({(r["layout"], r["op"]) for r in ops}) == len(ops) == 20
+    assert len({(r["layout"], r["op"]) for r in ops}) == len(ops) == 26
     assert all(r["ms_per_op"] > 0 for r in ops)
+    # entry's 2-wide scatter-add beside the 1-wide and the round's 3-wide
+    # one, in both layouts and into the alltoall's 16,384 rows
+    wide = [(r["layout"], r["op"]) for r in ops if "scatter_add" in r["op"]
+            and "i32" not in r["op"]]
+    assert wide == [(lay, f"scatter_add{w}_f32_{side}")
+                    for lay, sides in (("drain_2d", ["to_c"]),
+                                       ("solve_pow2", ["to_c", "to_c16k"]))
+                    for side in sides for w in ("", 2, 3)]
+    same = [r for r in records if r["what"] == "column0"]
+    assert [r["layout"] for r in same] == ["drain_2d", "solve_pow2"]
+    assert all(r["equal"] is True for r in same)
 
 
 @pytest.mark.parametrize("layout", ["solve_pow2", "drain_2d"])

@@ -10,9 +10,14 @@ flows with the benchmark's numpy reference (no engine: seconds), in
 
 * each indexed op kind alone, K times inside one ``lax.fori_loop`` of
   one dispatch (gather by ``e_var`` / by ``e_cnst``, scatter to the
-  variables / to the constraints, 1-wide and 3-wide), at the drain's
-  padding ([E/8, 8], 1,241,664) and at ``solve_arrays``' pow2 padding
-  (1-D, 2,097,152);
+  variables / to the constraints, 1-wide, 2-wide as ``fixpoint``'s
+  entry issues it (ISSUE 32) and 3-wide as its round does), at the
+  drain's padding ([E/8, 8], 1,241,664) and at ``solve_arrays``' pow2
+  padding (1-D, 2,097,152), there also into the 16,384 constraint rows
+  of the alltoall's padded system (``to_c16k``: the same list, its
+  constraint numbers scaled down, still non-decreasing); and whether
+  a sum scattered as column 0 of a 2-wide window keeps the 1-wide
+  scatter's bits (``column0``);
 * ``lmm_jax.fixpoint`` to convergence, wall over rounds, the device
   being busy all of it: as the solve cell runs it (LV08 penalties and
   window bounds, which never bind; pow2 padding); the same system with
@@ -54,6 +59,7 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmarks")]
 OUT = os.path.join(ROOT, "chiprun_out", "coo_round_probe.jsonl")
 
 K = 20          # ops per dispatch in the op-price loops
+ALLTOALL_ROWS = 16_384      # dfly65k-alltoall's 8,724 constraints, padded
 
 
 def system(config: str, unit_penalty: bool):
@@ -116,6 +122,8 @@ def op_loops(jnp, lax, dtype):
         return lambda n: run
 
     def scatter(kind):
+        wide = {"add2": 2, "add3": 3}.get(kind)
+
         def of(n):
             def run(x, ix):
                 def step(e, acc):
@@ -125,15 +133,17 @@ def op_loops(jnp, lax, dtype):
                     if kind == "max_bool":
                         out = jnp.zeros(n, bool).at[ix].max(e > 0.5)
                         return jnp.where(out[0], e, 1.0 - e), acc | out
-                    if kind == "add3":
-                        out = jnp.zeros((n, 3), dtype).at[ix].add(
-                            jnp.stack([e, e * 2, e * 3], axis=-1))
+                    if wide:
+                        out = jnp.zeros((n, wide), dtype).at[ix].add(
+                            jnp.stack([e, e * 2, e * 3][:wide], axis=-1))
                         return e + out[0, 0] * 0, acc + out
                     out = jnp.zeros(n, kind).at[ix].add(e.astype(kind))
                     return e + out[0].astype(dtype) * 0.0, acc + out
-                acc0 = {"min": jnp.full(n, jnp.inf, dtype),
-                        "max_bool": jnp.zeros(n, bool),
-                        "add3": jnp.zeros((n, 3), dtype)}.get(kind)
+                if wide:
+                    acc0 = jnp.zeros((n, wide), dtype)
+                else:
+                    acc0 = {"min": jnp.full(n, jnp.inf, dtype),
+                            "max_bool": jnp.zeros(n, bool)}.get(kind)
                 return loop(step, x,
                             jnp.zeros(n, kind) if acc0 is None else acc0)
             return run
@@ -144,6 +154,7 @@ def op_loops(jnp, lax, dtype):
             "scatter_add_f32": scatter(dtype),
             "scatter_add_i32": scatter(jnp.int32),
             "scatter_max_bool": scatter("max_bool"),
+            "scatter_add2_f32": scatter("add2"),
             "scatter_add3_f32": scatter("add3")}
 
 
@@ -197,7 +208,14 @@ def readings(emit, only=None, config="dfly65k-random", reps=3):
         v_x = jnp.asarray(rng.random(V, dtype))
         c_x = jnp.asarray(rng.random(C, dtype))
         loops = op_loops(jnp, lax, dtype)
-        for kind, side, x, ix, n in (
+        into_16k = []
+        if pow2:
+            e_c16k = el(solve_sys.e_cnst.astype(np.int64) * ALLTOALL_ROWS
+                        // n_c, np.int32)
+            into_16k = [(kind, "to_c16k", e_x, e_c16k, ALLTOALL_ROWS)
+                        for kind in ("scatter_add_f32", "scatter_add_i32",
+                                     "scatter_add2_f32", "scatter_add3_f32")]
+        for kind, side, x, ix, n in [
                 ("gather_f32", "by_e_var", v_x, e_var, None),
                 ("gather_f32", "by_e_cnst", c_x, e_cnst, None),
                 ("gather_bool", "by_e_var", v_x, e_var, None),
@@ -207,10 +225,23 @@ def readings(emit, only=None, config="dfly65k-random", reps=3):
                 ("scatter_add_f32", "to_c", e_x, e_cnst, C),
                 ("scatter_add_i32", "to_c", e_x, e_cnst, C),
                 ("scatter_max_bool", "to_c", e_x, e_cnst, C),
-                ("scatter_add3_f32", "to_c", e_x, e_cnst, C)):
+                ("scatter_add2_f32", "to_c", e_x, e_cnst, C),
+                ("scatter_add3_f32", "to_c", e_x, e_cnst, C)] + into_16k:
             s, _ = timed(jax.jit(loops[kind](n)), x, ix)
             emit(what="op", layout=name, op=f"{kind}_{side}", elems=E,
                  ms_per_op=1e3 * s / K, ns_per_index=1e9 * s / K / E)
+
+        @jax.jit
+        def column0(e, ix):
+            """Does a sum keep its bits as column 0 of a 2-wide window?
+            Entry's usage rides one (ISSUE 32)."""
+            narrow = jnp.zeros(C, dtype).at[ix].add(e)
+            wide = jnp.zeros((C, 2), dtype).at[ix].add(
+                jnp.stack([e, (e > 0.5).astype(dtype)], axis=-1))
+            return jnp.array_equal(narrow, wide[:, 0])
+
+        emit(what="column0", layout=name, elems=E, rows=C,
+             equal=bool(column0(e_x, e_cnst)))
 
     def round_ms(label, lanes, pow2, has_bounds, wants=None, skipped=None):
         """``fixpoint`` to convergence on ``lanes`` (systems alike but
