@@ -20,6 +20,7 @@ deterministic arithmetic — no RNG, no wallclock.
 
 from __future__ import annotations
 
+import inspect
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Tuple
 
@@ -169,14 +170,36 @@ def _solo_chunk(scale: int, dtype):
 # Factories: fleet programs (captured from BatchDrainSim drivers)
 # ---------------------------------------------------------------------------
 
-def _fleet_superstep(scale: int, dtype, tape=False, coll=False):
+#: the fleet program's arguments a driver builds in the solve dtype
+_FLEET_SOLVE_ARGS = ("e_w", "c_bound", "v_bound", "pen", "rem", "thresh",
+                     "tape_val")
+
+
+def _fleet_superstep(scale: int, dtype, tape=False, coll=False,
+                     device=None):
     from simgrid_tpu.ops import lmm_batch as lb
 
+    if coll and np.dtype(dtype) != np.float64:
+        # BatchDrainSim still refuses a float32 collective by name (its
+        # collect reads the ring's dates in the solve dtype: ROADMAP
+        # reach A.1); the PROGRAM has a float32 form since the solo
+        # tape got one.  Stage the float64 dispatch (on the host's CPU
+        # backend, where float64 is allowed whatever the default device
+        # is) with the arguments a driver would have built in the solve
+        # dtype narrowed.
+        import jax
+        args, statics = _fleet_superstep(scale, np.float64, tape, coll,
+                                         device=jax.devices("cpu")[0])
+        names = inspect.signature(lb._batch_superstep_program).parameters
+        return tuple(np.asarray(a, dtype if name in _FLEET_SOLVE_ARGS
+                                else None)
+                     for name, a in zip(names, args)), statics
     e_var, e_cnst, e_w, c_bound, sizes = _arrays(scale, dtype)
     n_c, n_v = _geometry(scale)
     overrides = [lb.ReplicaOverrides(),
                  lb.ReplicaOverrides(bw_scale=1.25)]
-    kw: Dict[str, Any] = dict(eps=1e-9, dtype=dtype, superstep=2)
+    kw: Dict[str, Any] = dict(eps=1e-9, dtype=dtype, superstep=2,
+                              device=device)
     if tape:
         tt, ts, tv = _tape(n_c)
         kw["tapes"] = [(tt, ts, tv), (tt, ts, tv * 0.5)]
@@ -253,6 +276,10 @@ def iter_programs() -> List[ProgramSpec]:
             "drain/superstep_coll", ld._drain_superstep,
             ld._superstep_program, _drain_contract("float64"),
             lambda s, dt=f64: _solo_superstep(s, dt, coll=True)),
+        ProgramSpec(
+            "drain/superstep_coll_f32", ld._drain_superstep,
+            ld._superstep_program, _drain_contract("float32"),
+            lambda s, dt=f32: _solo_superstep(s, dt, coll=True)),
         ProgramSpec(
             "drain/solve_chunk", ld._drain_solve_chunk,
             ld._solve_chunk_program,

@@ -8,7 +8,8 @@ no host involvement: ops/lmm_drain.DrainSim(collective=...) solo,
 ops/lmm_batch.BatchDrainSim(collective=...) for fleets.
 
 Layering: schedule (per-rank op IR + DAG builder + generators) ->
-topology (route/constraint lowering) -> tape (DeviceCollective, the
+topology (route/constraint lowering: three synthetic flavors and
+the routed one, built from a loaded platform) -> tape (DeviceCollective, the
 compiled arrays) -> maestro (the host-driven bit-identity oracle) ->
 spec (the campaign/serving sweep dimension).
 """
@@ -21,12 +22,12 @@ from .schedule import (CollectiveSchedule, CommRec, GENERATORS, Prog,
                        seq_bcast_binomial, seq_reduce_flat)
 from .spec import CollectiveSpec
 from .tape import DeviceCollective
-from .topology import FLAVORS, Topology
+from .topology import FLAVORS, RoutedTopology, Topology
 
 __all__ = [
     "CollectiveSchedule", "CollectiveSpec", "CommRec",
     "DeviceCollective", "FLAVORS", "GENERATORS", "HostMaestro",
-    "Prog", "Topology", "build_schedule", "generate",
+    "Prog", "RoutedTopology", "Topology", "build_schedule", "generate",
     "seq_allreduce_lr", "seq_allreduce_rdb", "seq_allreduce_redbcast",
     "seq_alltoall_bruck", "seq_alltoall_pairwise",
     "seq_bcast_binomial", "seq_reduce_flat",

@@ -16,8 +16,8 @@ the exact per-advance recurrence of ops.lmm_drain._superstep_program
 
 * rates from the same ``fixpoint`` program over the same device
   arrays;
-* ``dt_plan = min(rem / rate)`` in f64 (elementwise IEEE division and
-  min match the device reduction);
+* ``dt_plan = min(rem / rate)`` in the solve dtype (elementwise IEEE
+  division and min match the device reduction);
 * the event peek: ``next_t = min(fault date, min(ready))``, fire iff
   ``next_t <= now + dt_plan`` (ties to the event), dt clamped to land
   exactly on the date;
@@ -27,6 +27,12 @@ the exact per-advance recurrence of ops.lmm_drain._superstep_program
 * the clock accumulated by the same compensated (Kahan) pair, one
   python-float step per advance — grouping K advances per dispatch
   leaves the recurrence unchanged, which is the whole invariant.
+
+The solve dtype is the one ``lmm/dtype:auto`` resolves, as the tape's:
+float64 on the CPU, where all of the above is bit for bit, float32 on
+the TPU, where the dates and the clock pair stay float64 and only the
+rates, the remains and ``dt`` are narrow (the program's own split) and
+the device's divide is not numpy's to the bit.
 
 Event streams come out in the device's order: completions by flow
 slot, then the fault entry, then activations by flow slot, all at the
@@ -71,12 +77,13 @@ class HostMaestro:
     """Drive a DeviceCollective one advance per dispatch, host-side."""
 
     def __init__(self, dc: DeviceCollective, tape=None, device=None,
-                 eps: float = 1e-5, done_eps: float = 1e-4):
+                 eps: float = 1e-5, done_eps: float = 1e-4,
+                 dtype="auto"):
         self.dc = dc
         self.n_v = dc.n_v
         self.n_c = dc.n_c
         self.sim = DrainSim(dc.e_var, dc.e_cnst, dc.e_w, dc.c_bound,
-                            dc.sizes, dtype=np.float64, device=device,
+                            dc.sizes, dtype=dtype, device=device,
                             eps=eps, done_eps=done_eps,
                             penalty=dc.penalty0,
                             repack_min=1 << 62)
@@ -133,7 +140,9 @@ class HostMaestro:
         now = self.t
         next_t = min(next_ft, next_at)
         fire = np.isfinite(next_t) and next_t <= now + dt_plan
-        dt = max(next_t - now, 0.0) if fire else dt_plan
+        # dt is the solve dtype's, as the remains it decrements
+        dt = float(s.dtype.type(max(next_t - now, 0.0))) if fire \
+            else dt_plan
         if not np.isfinite(dt):
             raise RuntimeError(
                 f"collective schedule deadlocked: "
@@ -142,7 +151,7 @@ class HostMaestro:
 
         s._pen, s._rem, out = _drain_forced_advance(
             s._pen, s._rem, s._thresh, rates_dev,
-            jnp.asarray(dt, np.float64), _ZERO_BITS)
+            jnp.asarray(dt, s.dtype), _ZERO_BITS)
         self.dispatches += 1
         opstats.bump("dispatches")
         out = opstats.timed_fetch(out)

@@ -4,7 +4,11 @@ ScenarioSpec/ScenarioPlan.
 A spec names (op, algo, ranks, topology flavor, payload) — everything
 needed to regenerate the schedule and compile the tape — in the same
 content-addressed style as ScenarioSpec: canonical dict form, stable
-sha256 ``key()``, JSON round trip.  ``build()`` materializes the
+sha256 ``key()``, JSON round trip.  ``topo`` is a synthetic flavor's
+name or a :class:`~.topology.RoutedTopology` (the ``routed`` flavor:
+a loaded platform and the ranks' hosts, which no JSON can carry, so
+such a spec is addressed by the topology's ``key()`` and
+``from_dict`` refuses it).  ``build()`` materializes the
 DeviceCollective (schedule generation + topology lowering); plan
 construction caches it, so fleets sweeping rank counts × algorithms ×
 topologies pay one compile per distinct spec.
@@ -16,9 +20,10 @@ import hashlib
 import json
 from typing import Dict, Optional
 
+from ..ops import opstats
 from .schedule import GENERATORS, generate
 from .tape import DeviceCollective
-from .topology import FLAVORS, Topology
+from .topology import FLAVORS, RoutedTopology, Topology
 
 
 class CollectiveSpec:
@@ -34,14 +39,19 @@ class CollectiveSpec:
         if (op, algo) not in GENERATORS:
             raise ValueError(f"unknown collective {op}/{algo}; known: "
                              f"{sorted(GENERATORS)}")
-        if topo not in FLAVORS:
+        if isinstance(topo, RoutedTopology):
+            if topo.ranks != int(ranks):
+                raise ValueError(f"the routed topology places "
+                                 f"{topo.ranks} ranks, the collective "
+                                 f"has {ranks}")
+        elif topo not in FLAVORS:
             raise ValueError(f"unknown topology flavor {topo!r}")
         if ranks < 2:
             raise ValueError("a collective needs at least 2 ranks")
         self.op = str(op)
         self.algo = str(algo)
         self.ranks = int(ranks)
-        self.topo = str(topo)
+        self.topo = topo
         #: payload bytes (elements for lr — see schedule.GENERATORS)
         self.payload = float(payload)
         self.bw = float(bw)
@@ -51,8 +61,10 @@ class CollectiveSpec:
     # -- stable serialization / content addressing -------------------------
 
     def to_dict(self) -> Dict:
+        topo = (self.topo if isinstance(self.topo, str)
+                else list(self.topo.key()))
         return {"op": self.op, "algo": self.algo, "ranks": self.ranks,
-                "topo": self.topo, "payload": self.payload,
+                "topo": topo, "payload": self.payload,
                 "bw": self.bw, "loop_bw": self.loop_bw,
                 "core_bw": self.core_bw}
 
@@ -62,6 +74,9 @@ class CollectiveSpec:
 
     @classmethod
     def from_dict(cls, d: Dict) -> "CollectiveSpec":
+        if not isinstance(d.get("topo", "nic"), str):
+            raise ValueError("a routed collective is rebuilt from its "
+                             "platform and rank hosts, not from JSON")
         return cls(op=d.get("op", "allreduce"),
                    algo=d.get("algo", "rdb"),
                    ranks=d.get("ranks", 8),
@@ -81,16 +96,20 @@ class CollectiveSpec:
         return hashlib.sha256(self.to_json().encode("utf-8")).hexdigest()
 
     def label(self) -> str:
-        return (f"{self.op}/{self.algo} r{self.ranks} {self.topo} "
+        topo = getattr(self.topo, "flavor", self.topo)
+        return (f"{self.op}/{self.algo} r{self.ranks} {topo} "
                 f"{self.payload:g}B")
 
     # -- materialization ---------------------------------------------------
 
     def topology(self) -> Topology:
+        if not isinstance(self.topo, str):
+            return self.topo
         return Topology(self.ranks, self.topo, bw=self.bw,
                         loop_bw=self.loop_bw, core_bw=self.core_bw)
 
     def build(self, exec_cost=None) -> DeviceCollective:
-        sched = generate(self.op, self.algo, self.ranks, self.payload)
-        return DeviceCollective(sched, self.topology(),
-                                exec_cost=exec_cost)
+        with opstats.span("coll.lower", id="tape"):
+            sched = generate(self.op, self.algo, self.ranks, self.payload)
+            return DeviceCollective(sched, self.topology(),
+                                    exec_cost=exec_cost)

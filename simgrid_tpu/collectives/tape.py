@@ -19,6 +19,11 @@ Activation protocol (mirrored exactly by maestro.HostMaestro):
 * root records WITH exec cost start dormant with ready = exec_cost —
   the compute leg of a compute/comm phase runs before the wire.
 
+``exec_cost`` defaults to the topology's own delays: nothing on the
+synthetic flavors, the route's latency (under the network model's
+latency factor) on a routed one, so a block is on the wire only after
+both its ranks finished the step before AND its latency has passed.
+
 Zero-byte payloads (a barrier's b"" token) are clamped to one byte:
 a zero-size flow can never cross the relative retirement threshold,
 and both the tape and the host maestro apply the same clamp, so
@@ -57,8 +62,12 @@ class DeviceCollective:
             raise ValueError("schedule has no communications")
         self.n_v = n_v
         self.n_c = topology.n_c
+        src = np.fromiter((r.src for r in recs), np.int64, count=n_v)
+        dst = np.fromiter((r.dst for r in recs), np.int64, count=n_v)
         if exec_cost is None:
-            ex = np.zeros(n_v)
+            # a routed topology delays every record by its route's
+            # latency; the synthetic flavors by nothing
+            ex = np.asarray(topology.delays(src, dst), np.float64)
         else:
             ex = np.asarray(exec_cost, np.float64)
             if ex.shape != (n_v,):
@@ -66,29 +75,32 @@ class DeviceCollective:
                                  f"record ({n_v}), got {ex.shape}")
         self.exec_cost = ex
 
-        ev, ec = [], []
-        for rec in recs:
-            for c in topology.route(rec.src, rec.dst):
-                ev.append(rec.rid)
-                ec.append(c)
+        # records are in rid order (rid = index), so a transfer's index
+        # is its flow slot
+        ev, ec, ew = topology.lower(src, dst)
         self.e_var = np.asarray(ev, np.int32)
         self.e_cnst = np.asarray(ec, np.int32)
-        self.e_w = np.ones(len(ev))
+        self.e_w = np.asarray(ew, np.float64)
         self.c_bound = np.asarray(topology.c_bound, np.float64)
         self.sizes = np.maximum(
-            np.asarray([r.size for r in recs], np.float64), 1.0)
+            np.fromiter((r.size for r in recs), np.float64, count=n_v),
+            1.0)
 
-        self.pred0 = np.asarray([len(r.preds) for r in recs], np.int32)
+        self.pred0 = np.fromiter((len(r.preds) for r in recs), np.int32,
+                                 count=n_v)
         roots = self.pred0 == 0
         timed_root = roots & (ex > 0)
         self.penalty0 = np.where(roots & ~timed_root, 1.0, 0.0)
         self.ready0 = np.where(timed_root, ex, np.inf)
-        es, ed = [], []
-        for rec in recs:
-            for p in sorted(r.rid for r in rec.preds):
-                es.append(p)
-                ed.append(rec.rid)
-        if not es:
+        if self.pred0.any():
+            # successor edges, grouped by successor, predecessors
+            # ascending within a group
+            es = np.fromiter((p.rid for r in recs for p in r.preds),
+                             np.int64, count=int(self.pred0.sum()))
+            ed = np.repeat(np.arange(n_v), self.pred0)
+            order = np.lexsort((es, ed))
+            es, ed = es[order], ed[order]
+        else:
             # keep the edge arrays non-empty: a single dropped-slot
             # row (dst = n_v scatters into the drop lane)
             es, ed = [0], [n_v]
@@ -106,10 +118,14 @@ class DeviceCollective:
 
     def make_sim(self, superstep: int = 16, pipeline: int = 0,
                  tape=None, device=None, **kw):
-        """A ready-to-run tape-driven DrainSim over this collective."""
+        """A ready-to-run tape-driven DrainSim over this collective, in
+        the dtype ``lmm/dtype:auto`` resolves on ``device`` (float64
+        where it is IEEE, float32 on the TPU) unless ``dtype=`` says
+        otherwise."""
         from ..ops.lmm_drain import DrainSim
+        kw.setdefault("dtype", "auto")
         return DrainSim(self.e_var, self.e_cnst, self.e_w,
-                        self.c_bound, self.sizes, dtype=np.float64,
+                        self.c_bound, self.sizes,
                         superstep=superstep, pipeline=pipeline,
                         penalty=self.penalty0, tape=tape,
                         device=device, collective=self.drain_args(),

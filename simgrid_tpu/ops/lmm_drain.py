@@ -253,9 +253,20 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     runs must be bit-identical at EVERY dispatch grouping (the
     host-maestro oracle replays the same recurrence one advance per
     dispatch), the Kahan clock pair is carried ACROSS dispatches via
-    ``coll_clk = (t, comp)`` and ring times are ABSOLUTE f64 dates;
-    the dtype must be float64.  The ring grows by another n_v
-    activation slots.
+    ``coll_clk = (t, comp)`` and ring times are ABSOLUTE dates.  The
+    pair, the activation dates and ``exec_cost`` are float64 WHATEVER
+    the solve dtype (the f64 spine the fault tape's dates already are:
+    IEEE on the CPU, an f32 pair on the TPU, finer than float32 on
+    both); only ``dt`` is rounded to the solve dtype, where the
+    remains it decrements live.  In float64 that is the recurrence it
+    always was, bit for bit; in float32 a date is off by the rounding
+    of the dts behind it, never by the clock's magnitude.  The ring's
+    own dates are in the solve dtype, so the host replays the pair
+    from the per-advance dt table instead (``DrainSim._demux``).  The
+    ring grows by another n_v activation slots, the state by the live
+    flows entering each advance (an exact [high, low] pair, as
+    ``fixpoint``'s element counts) and the activations fired; those
+    three ride the END of the packed vector.
     """
     # trace-time only: a steady-state superstep loop re-enters the jit
     # cache, so this stays flat; a nonzero delta on a repeat run means
@@ -284,7 +295,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         if has_coll:
             # a dormant flow with a pending activation keeps the loop
             # walking even when nothing currently holds bandwidth
-            alive = alive | jnp.any(jnp.isfinite(st[-1]))
+            alive = alive | jnp.any(jnp.isfinite(st[-3]))
         return ((flag == _FLAG_OK) & (adv < k) & (rounds < round_budget)
                 & alive)
 
@@ -298,7 +309,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         else:
             cb_c = c_bound
         if has_coll:
-            pred_c, ready_c = st[idx], st[idx + 1]
+            pred_c, ready_c, live_sum, fires = st[idx:idx + 4]
         with jax.named_scope("sg.drain.solve"):
             out = fixpoint(e_var, e_cnst, e_w, cb_c, fat, pen_c, v_bound,
                            eps_c, n_c, n_v, parallel_rounds=True,
@@ -352,9 +363,11 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
 
             # Kahan clock: per-advance dts combine compensated so the f32
             # in-dispatch clock error is O(k ulp), not O(advances) drift
-            y = dt - t_comp
+            # (a collective's pair is float64 whatever the solve dtype)
+            y = (dt.astype(jnp.float64) if has_coll else dt) - t_comp
             t_new = t_sum + y
             t_comp2 = (t_new - t_sum) - y
+            t_ring = t_new.astype(dtype) if has_coll else t_new
 
         with jax.named_scope("sg.drain.ring"):
             # completion ring: positions by stable slot order (cumsum), the
@@ -365,7 +378,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
             pos = jnp.where(done, n_ev + dcount - 1, ring_n)
             pos2 = pos.reshape(-1, group)
             ring_t2 = ring_t.at[pos2].set(
-                jnp.broadcast_to(t_new, pos2.shape), mode="drop")
+                jnp.broadcast_to(t_ring, pos2.shape), mode="drop")
             ring_id2 = ring_id.at[pos2].set(ids.reshape(-1, group),
                                             mode="drop")
             n_done = dcount[-1]
@@ -377,7 +390,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                 # cursor bump — all dropped when not firing
                 slot = tape_slot[ti]
                 fpos = jnp.where(f_fire, n_ev + n_done, ring_n)
-                ring_t2 = ring_t2.at[fpos].set(t_new, mode="drop")
+                ring_t2 = ring_t2.at[fpos].set(t_ring, mode="drop")
                 ring_id2 = ring_id2.at[fpos].set(-(1 + slot), mode="drop")
                 n_new = n_ev + n_done + f_fire.astype(jnp.int32)
                 cb2 = cb_c.at[jnp.where(f_fire, slot, n_c)].set(
@@ -387,31 +400,38 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                 n_new = n_ev + n_done
 
             if has_coll:
-                # activations fire AFTER completions and any fault entry:
-                # every pending flow whose ready date is <= the event date
-                # wakes up (penalty scatter), its ready slot is consumed,
-                # and a tagged entry id = -(1 + n_c + flow_id) logs the
-                # fired successor at the (absolute) advance clock
-                a_any = fire & (next_at <= next_ft)
-                act = a_any & (ready_c <= next_t)
-                acount = jnp.cumsum(act.astype(jnp.int32))
-                apos = jnp.where(act, n_new + acount - 1, ring_n)
-                ring_t2 = ring_t2.at[apos].set(
-                    jnp.broadcast_to(t_new, apos.shape), mode="drop")
-                ring_id2 = ring_id2.at[apos].set(-(1 + n_c + ids),
-                                                 mode="drop")
-                n_new = n_new + acount[-1]
-                pen2 = jnp.where(act, jnp.asarray(1.0, dtype), pen2)
-                ready2 = jnp.where(act, jnp.inf, ready_c)
-                # DAG walk: completions decrement their successors'
-                # outstanding-predecessor counts; flows reaching zero get
-                # a ready date = completion clock + exec cost (activation
-                # happens on a LATER advance, never the completing one)
-                pred2 = pred_c.at[edge_dst].add(
-                    -jnp.take(done.astype(jnp.int32), edge_src), mode="drop")
-                newly = (pred2 <= 0) & (pred_c > 0)
-                ready2 = jnp.where(
-                    newly, t_new.astype(jnp.float64) + exec_cost, ready2)
+                with jax.named_scope("sg.drain.coll"):
+                    # activations fire AFTER completions and any fault entry:
+                    # every pending flow whose ready date is <= the event date
+                    # wakes up (penalty scatter), its ready slot is consumed,
+                    # and a tagged entry id = -(1 + n_c + flow_id) logs the
+                    # fired successor at the (absolute) advance clock
+                    a_any = fire & (next_at <= next_ft)
+                    act = a_any & (ready_c <= next_t)
+                    acount = jnp.cumsum(act.astype(jnp.int32))
+                    apos = jnp.where(act, n_new + acount - 1, ring_n)
+                    ring_t2 = ring_t2.at[apos].set(
+                        jnp.broadcast_to(t_ring, apos.shape), mode="drop")
+                    ring_id2 = ring_id2.at[apos].set(-(1 + n_c + ids),
+                                                     mode="drop")
+                    n_new = n_new + acount[-1]
+                    pen2 = jnp.where(act, jnp.asarray(1.0, dtype), pen2)
+                    ready2 = jnp.where(act, jnp.inf, ready_c)
+                    # DAG walk: completions decrement their successors'
+                    # outstanding-predecessor counts; flows reaching zero get
+                    # a ready date = completion clock + exec cost (activation
+                    # happens on a LATER advance, never the completing one)
+                    pred2 = pred_c.at[edge_dst].add(
+                        -jnp.take(done.astype(jnp.int32), edge_src),
+                        mode="drop")
+                    newly = (pred2 <= 0) & (pred_c > 0)
+                    ready2 = jnp.where(
+                        newly, t_new.astype(jnp.float64) + exec_cost, ready2)
+                    # what the tape did, for the packed tail: the flows live
+                    # as this advance entered, and the activations it fired
+                    live_sum2 = _pair_add(live_sum, jnp.count_nonzero(
+                        live).astype(jnp.int32))
+                    fires2 = fires + acount[-1]
 
             adv_dt2 = adv_dt.at[adv].set(dt.astype(dtype))
             adv_nev2 = adv_nev.at[adv].set(n_new)
@@ -435,7 +455,9 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                                    jnp.where(ok, tpos2, tpos))
             if has_coll:
                 out_st = out_st + (jnp.where(ok, pred2, pred_c),
-                                   jnp.where(ok, ready2, ready_c))
+                                   jnp.where(ok, ready2, ready_c),
+                                   jnp.where(ok, live_sum2, live_sum),
+                                   sel(fires2, fires))
         return out_st
 
     zero = jnp.asarray(0, jnp.int32)
@@ -443,7 +465,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         # the Kahan clock pair is carried across dispatches so the
         # recurrence — and therefore every event date — is invariant
         # to how advances are grouped into dispatches
-        clk0 = (coll_clk[0].astype(dtype), coll_clk[1].astype(dtype))
+        clk0 = (coll_clk[0], coll_clk[1])
     else:
         clk0 = (jnp.asarray(0.0, dtype), jnp.asarray(0.0, dtype))
     st0 = (pen, rem) + clk0 + (
@@ -453,7 +475,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     if has_tape:
         st0 = st0 + (c_bound, jnp.asarray(tape_pos, jnp.int32))
     if has_coll:
-        st0 = st0 + (coll_pred, coll_ready)
+        st0 = st0 + (coll_pred, coll_ready, jnp.zeros(2, jnp.int32), zero)
     st = lax.while_loop(cond, body, st0)
     (pen_o, rem_o, t_sum, t_comp_o, ring_t, ring_id, adv_dt, adv_nev,
      n_ev, adv, rounds, flag, worked) = st[:13]
@@ -465,9 +487,9 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         cb_o = c_bound
         tpos_o = jnp.asarray(tape_pos, jnp.int32)
     if has_coll:
-        pred_o, ready_o = st[idx], st[idx + 1]
-        clk_o = jnp.stack([t_sum.astype(jnp.float64),
-                           t_comp_o.astype(jnp.float64)])
+        pred_o, ready_o, live_sum, fires = st[idx:idx + 4]
+        clk_o = jnp.stack([t_sum, t_comp_o])
+        t_sum = t_sum.astype(dtype)
     else:
         pred_o, ready_o, clk_o = coll_pred, coll_ready, coll_clk
     with jax.named_scope("sg.drain.pack"):
@@ -479,8 +501,11 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                            n_live.astype(dtype), flag.astype(dtype),
                            live_elems.astype(dtype),
                            *worked.astype(dtype)])
-        packed = jnp.concatenate([stats, adv_dt, adv_nev.astype(dtype),
-                                  ring_t, ring_id.astype(dtype)])
+        parts = [stats, adv_dt, adv_nev.astype(dtype),
+                 ring_t, ring_id.astype(dtype)]
+        if has_coll:
+            parts.append(jnp.stack([*live_sum, fires]).astype(dtype))
+        packed = jnp.concatenate(parts)
     return pen_o, rem_o, cb_o, tpos_o, pred_o, ready_o, clk_o, packed
 
 
@@ -812,10 +837,6 @@ class DrainSim:
                 if len(ces) != len(ced):
                     raise ValueError("collective edge arrays must have "
                                      "equal length")
-                if self.dtype != np.float64:
-                    raise ValueError("collective= needs dtype=float64 (the "
-                                     "carried Kahan clock must match the "
-                                     "host-maestro oracle bit-for-bit)")
                 self.has_coll = True
                 # a repack would scramble the DAG's static slot indexing
                 self.repack_min = 1 << 62
@@ -826,6 +847,9 @@ class DrainSim:
                 self._coll_clk = jax.device_put(
                     np.zeros(2, np.float64), device)
                 self._coll_total = int(self.n_v)
+                #: the carried Kahan pair as the host replays it from a
+                #: dispatch's dt table (see _demux)
+                self._coll_clk_host = (0.0, 0.0)
                 opstats.bump("collective_tape_slots", self.n_v)
                 opstats.bump("uploaded_bytes_delta",
                              cp.nbytes + cr.nbytes + ces.nbytes
@@ -1114,6 +1138,11 @@ class DrainSim:
             opstats.bump("fixpoint_worked_elem_rounds",
                          _live_elem_rounds(p[7:9]))
             self.advances += adv
+            if self.has_coll:
+                # the tape's own counts ride the tail of the same fetch
+                opstats.bump("collective_live_flow_advances",
+                             _live_elem_rounds(p[-3:-1]))
+                opstats.bump("collective_tape_fires", int(p[-1]))
             with opstats.span("drain.demux"):
                 batches, fired = self._demux(p, adv, tok.k_max, t_sum)
 
@@ -1163,11 +1192,10 @@ class DrainSim:
         ring_id = p[o + ring_n:o + 2 * ring_n].astype(np.int64)
         batches: List[Tuple[float, List[int]]] = []
         start = 0
-        # collective rings carry ABSOLUTE dates (the Kahan clock pair is
-        # carried across dispatches), so the base folds to zero
+        # collective dates are ABSOLUTE (the Kahan clock pair is carried
+        # across dispatches), so the base folds to zero
         t_base = 0.0 if self.has_coll else self.t
         fired = 0
-        coll_fired = 0
         if self.has_tape or self.has_coll:
             # demux the ring: negative ids are tagged entries — fault
             # fires (idx < n_c, into the fault stream) or collective
@@ -1176,15 +1204,24 @@ class DrainSim:
             for i in range(adv):
                 end = int(adv_nev[i])
                 batch_ids: List[int] = []
+                if self.has_coll:
+                    # the ring's dates are in the solve dtype; the
+                    # advance's own is the device's float64 pair, one
+                    # step of the same recurrence on its exact dt (the
+                    # step HostMaestro takes)
+                    t_c, comp = self._coll_clk_host
+                    y = float(adv_dt[i]) - comp
+                    t_adv = t_c + y
+                    self._coll_clk_host = (t_adv, (t_adv - t_c) - y)
                 for j in range(start, end):
                     fid = int(ring_id[j])
-                    tj = t_base + float(ring_t[j])
+                    tj = (t_adv if self.has_coll
+                          else t_base + float(ring_t[j]))
                     if fid < 0:
                         idx = -fid - 1
                         if idx >= self.n_c:
                             self.collective_events.append(
                                 (tj, idx - self.n_c))
-                            coll_fired += 1
                         else:
                             self.fault_events.append((tj, idx))
                             fired += 1
@@ -1197,8 +1234,6 @@ class DrainSim:
             self._last_fired = fired > 0
             if fired:
                 opstats.bump("fault_tape_events", fired)
-            if coll_fired:
-                opstats.bump("collective_tape_fires", coll_fired)
         else:
             for i in range(adv):
                 end = int(adv_nev[i])
@@ -1209,9 +1244,10 @@ class DrainSim:
                                         int(ring_id[j])))
                 start = end
         # f64 master clock: one Kahan-compensated dtype total per
-        # superstep, accumulated on host in f64 (collective runs carry
-        # the absolute clock on device; t_base is 0 there)
-        self.t = t_base + t_sum
+        # superstep, accumulated on host in f64 (a collective's is the
+        # absolute clock of the pair replayed above)
+        self.t = (self._coll_clk_host[0] if self.has_coll
+                  else t_base + t_sum)
         return batches, fired
 
     def superstep_batch(self, k: Optional[int] = None,
@@ -1224,6 +1260,10 @@ class DrainSim:
         (dt, [original flow ids]) per executed advance; with
         fetch=False nothing is transferred (replay) and (None, None) is
         returned.  Events/clock/counters are committed on fetch."""
+        if self.has_coll and not fetch:
+            raise ValueError("superstep_batch(fetch=False): a collective "
+                             "tape's host clock is replayed from the "
+                             "fetched dt table")
         tok = self._superstep_issue(k, stop_live=stop_live,
                                     round_budget=round_budget)
         if not fetch:
@@ -1231,9 +1271,6 @@ class DrainSim:
             if self.has_tape:
                 self._cb = tok.cb_out
                 self._tpos = tok.tpos_out
-            if self.has_coll:
-                self._coll = (tok.pred_out, tok.ready_out)
-                self._coll_clk = tok.clk_out
             return None, None
         n_live, batches, _clean = self._superstep_collect(tok)
         return n_live, batches

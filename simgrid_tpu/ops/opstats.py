@@ -197,8 +197,18 @@ context object through the solver entry points:
                               device schedule tapes at sim
                               construction (collectives.tape)
 * ``collective_tape_fires`` — collective tape events that FIRED
-                              mid-drain (ring entries the host demuxed
-                              into ``collective_events``)
+                              mid-drain: counted by the superstep and
+                              read from the tail of its packed vector
+                              (a fleet counts the ring entries it
+                              demuxes into ``collective_events``)
+* ``collective_live_flow_advances`` — flows live as an advance of a
+                              collective tape entered, summed over the
+                              advances: an exact [high, low] pair in
+                              the superstep's state (as
+                              ``fixpoint_live_elem_rounds``), read from
+                              the same tail; over advances x flow
+                              slots it is the share of the tape a
+                              full-width solve works for
 * ``collective_replays``    — speculative in-flight supersteps
                               discarded because the superstep they
                               chained from fired a collective tape
@@ -276,6 +286,13 @@ this table, like the counters (the ``opstats-discipline`` lint checks
                         the zones, hosts, links and routes it builds
 * ``lmm.flatten``     — ``lmm_jax.flatten``: the live host system
                         walked into padded COO arrays
+* ``coll.lower``      — a collective lowered for the device: once in
+                        ``collectives.RoutedTopology`` (``id``
+                        ``routes``: every rank pair's route looked up
+                        and put in slots) and once in
+                        ``CollectiveSpec.build`` (``id`` ``tape``: the
+                        schedule generated, its records and DAG
+                        compiled into ``DeviceCollective``'s arrays)
 * ``drain.init``      — ``DrainSim.__init__``: the host arrays shaped
                         and handed to the device (``device_put``)
 * ``drain.issue``     — ``DrainSim._superstep_issue``: one superstep

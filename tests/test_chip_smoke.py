@@ -37,7 +37,7 @@ def test_tiny_smoke_runs_every_leg(tmp_path):
     assert by_leg["drain"]["dispatches"] >= 2
     assert by_leg["engine"]["opstats"]["fastpath_advances"] > 0
     assert by_leg["serve"]["warm_cache"]["plan_cache_disk_hits"] > 0
-    assert by_leg["compile"]["programs"] == 11
+    assert by_leg["compile"]["programs"] == 12
 
     # the driver reads the last line: exactly these keys, no other
     verdict = rows[-1]

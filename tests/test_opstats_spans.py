@@ -246,13 +246,17 @@ def test_solve_chunk_program_holds_every_pass_name(parallel_rounds,
 
 @pytest.mark.parametrize("name", ["drain/superstep", "drain/superstep_f32",
                                   "drain/superstep_tape",
-                                  "drain/superstep_coll"])
+                                  "drain/superstep_coll",
+                                  "drain/superstep_coll_f32"])
 def test_superstep_program_holds_every_pass_name(name):
     spec = {s.name: s for s in registry.iter_programs()}[name]
     args, statics = spec.make(1)
     text = lowered_text(spec.jitted, args, statics)
     for scope in DRAIN_SCOPES + LMM_SCOPES:
         assert scope + "/" in text, scope
+    # the tape's activation scatter and DAG walk have a name of their
+    # own, inside the ring's, and only where a tape is armed
+    assert ("sg.drain.ring/sg.drain.coll/" in text) == statics["has_coll"]
     # the round's passes nest under the superstep's solve
     assert "sg.drain.solve/while/body/sg.lmm.update/" in text
 

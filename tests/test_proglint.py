@@ -294,7 +294,7 @@ class TestShapeDiscipline:
 class TestRegistry:
     def test_every_registered_program_stages_and_passes(self):
         specs = iter_programs()
-        assert len(specs) == 11
+        assert len(specs) == 12
         findings = lint_programs(specs)
         assert findings == [], "\n".join(
             f"{f.path}: [{f.rule}] {f.message}" for f in findings)

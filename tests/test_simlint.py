@@ -349,7 +349,7 @@ class TestOpstatsSpanDiscipline:
             declared_spans
         from simgrid_tpu.ops import opstats
         names = set(declared_spans(opstats.__doc__))
-        assert {"platform.load", "lmm.flatten", "drain.init",
+        assert {"platform.load", "lmm.flatten", "coll.lower", "drain.init",
                 "drain.issue", "drain.collect", "drain.demux",
                 "solve.chunk", "fetch", "xla.compile"} == names
 
