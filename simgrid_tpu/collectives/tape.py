@@ -45,7 +45,8 @@ class DeviceCollective:
 
     __slots__ = ("schedule", "topology", "n_v", "n_c", "e_var",
                  "e_cnst", "e_w", "c_bound", "sizes", "penalty0",
-                 "pred0", "ready0", "edge_src", "edge_dst", "exec_cost")
+                 "pred0", "ready0", "edge_src", "edge_dst", "exec_cost",
+                 "v_ptr", "ve_idx")
 
     def __init__(self, schedule: CollectiveSchedule,
                  topology: Topology,
@@ -81,6 +82,11 @@ class DeviceCollective:
         self.e_var = np.asarray(ev, np.int32)
         self.e_cnst = np.asarray(ec, np.int32)
         self.e_w = np.asarray(ew, np.float64)
+        # which elements belong to which flow: with it a solve reaches
+        # the few live flows' elements without a pass over the list
+        # (ops.lmm_jax.var_index; one argsort, here and not per sim)
+        from ..ops.lmm_jax import var_index
+        self.v_ptr, self.ve_idx = var_index(self.e_var, self.e_w, n_v)
         self.c_bound = np.asarray(topology.c_bound, np.float64)
         self.sizes = np.maximum(
             np.fromiter((r.size for r in recs), np.float64, count=n_v),
@@ -128,8 +134,8 @@ class DeviceCollective:
                         self.c_bound, self.sizes,
                         superstep=superstep, pipeline=pipeline,
                         penalty=self.penalty0, tape=tape,
-                        device=device, collective=self.drain_args(),
-                        **kw)
+                        device=device, collective=self.drain_args()
+                        + (self.v_ptr, self.ve_idx), **kw)
 
     def key(self) -> tuple:
         return ("dcoll", self.n_v, self.n_c, self.topology.key(),

@@ -88,7 +88,7 @@ from . import opstats
 from .device import default_platform, solve_dtype
 from .lmm_jax import (_MAX_ROUNDS, SolveError, _bucket, _live_elem_rounds,
                       _pair_add, _pos_group, _stable_livefirst_perm,
-                      fixpoint)
+                      fixpoint, var_index)
 
 
 def _to2d(a: np.ndarray, group: int = 8) -> np.ndarray:
@@ -197,6 +197,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                        tape_t, tape_slot, tape_val, tape_pos,
                        coll_pred, coll_ready, coll_clk,
                        edge_src, edge_dst, exec_cost, t0,
+                       v_ptr=None, ve_idx=None, *,
                        eps: float, n_c: int, n_v: int, k_max: int,
                        group: int, has_bounds: bool = False,
                        has_tape: bool = False, has_coll: bool = False):
@@ -266,7 +267,13 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     ring grows by another n_v activation slots, the state by the live
     flows entering each advance (an exact [high, low] pair, as
     ``fixpoint``'s element counts) and the activations fired; those
-    three ride the END of the packed vector.
+    three ride the END of the packed vector.  ``(v_ptr, ve_idx)`` is
+    the element list's variable-major index (``lmm_jax.var_index``;
+    the tape's list is never repacked, so it stays true): with most
+    flows dormant, ``fixpoint`` then enters from the live flows' own
+    elements instead of the whole list, and the advances that did are
+    counted into a fourth scalar at the end.  A sim without a
+    collective, and the fleet, pass none.
     """
     # trace-time only: a steady-state superstep loop re-enters the jit
     # cache, so this stays flat; a nonzero delta on a repeat run means
@@ -286,6 +293,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     if has_tape:
         T = tape_t.shape[0]
         t0 = jnp.asarray(t0, jnp.float64)
+    index = (v_ptr, ve_idx) if has_coll and v_ptr is not None else None
 
     def cond(st):
         pen_c = st[0]
@@ -295,7 +303,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         if has_coll:
             # a dormant flow with a pending activation keeps the loop
             # walking even when nothing currently holds bandwidth
-            alive = alive | jnp.any(jnp.isfinite(st[-3]))
+            alive = alive | jnp.any(jnp.isfinite(st[-4]))
         return ((flag == _FLAG_OK) & (adv < k) & (rounds < round_budget)
                 & alive)
 
@@ -309,13 +317,13 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         else:
             cb_c = c_bound
         if has_coll:
-            pred_c, ready_c, live_sum, fires = st[idx:idx + 4]
+            pred_c, ready_c, live_sum, fires, var_entries = st[idx:idx + 5]
         with jax.named_scope("sg.drain.solve"):
             out = fixpoint(e_var, e_cnst, e_w, cb_c, fat, pen_c, v_bound,
                            eps_c, n_c, n_v, parallel_rounds=True,
                            carry=None, max_rounds=round_budget - rounds,
                            return_carry=True, has_bounds=has_bounds,
-                           has_fatpipe=False)
+                           has_fatpipe=False, var_index=index)
             carry2 = out[4]
             r = out[3].astype(jnp.int32)
             converged = jnp.count_nonzero(carry2[4]) == 0
@@ -457,7 +465,8 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                 out_st = out_st + (jnp.where(ok, pred2, pred_c),
                                    jnp.where(ok, ready2, ready_c),
                                    jnp.where(ok, live_sum2, live_sum),
-                                   sel(fires2, fires))
+                                   sel(fires2, fires),
+                                   sel(var_entries + out[9], var_entries))
         return out_st
 
     zero = jnp.asarray(0, jnp.int32)
@@ -475,7 +484,8 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     if has_tape:
         st0 = st0 + (c_bound, jnp.asarray(tape_pos, jnp.int32))
     if has_coll:
-        st0 = st0 + (coll_pred, coll_ready, jnp.zeros(2, jnp.int32), zero)
+        st0 = st0 + (coll_pred, coll_ready, jnp.zeros(2, jnp.int32), zero,
+                     zero)
     st = lax.while_loop(cond, body, st0)
     (pen_o, rem_o, t_sum, t_comp_o, ring_t, ring_id, adv_dt, adv_nev,
      n_ev, adv, rounds, flag, worked) = st[:13]
@@ -487,15 +497,20 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         cb_o = c_bound
         tpos_o = jnp.asarray(tape_pos, jnp.int32)
     if has_coll:
-        pred_o, ready_o, live_sum, fires = st[idx:idx + 4]
+        pred_o, ready_o, live_sum, fires, var_entries = st[idx:idx + 5]
         clk_o = jnp.stack([t_sum, t_comp_o])
         t_sum = t_sum.astype(dtype)
     else:
         pred_o, ready_o, clk_o = coll_pred, coll_ready, coll_clk
     with jax.named_scope("sg.drain.pack"):
         n_live = jnp.count_nonzero(pen_o > 0)
-        live_elems = jnp.count_nonzero(
-            (e_w > 0) & jnp.take(pen_o > 0, e_var, fill_value=False))
+        if index is None:
+            live_elems = jnp.count_nonzero(
+                (e_w > 0) & jnp.take(pen_o > 0, e_var, fill_value=False))
+        else:
+            # the same count from the index: no pass over the list
+            live_elems = jnp.sum(jnp.where(pen_o > 0,
+                                           v_ptr[1:] - v_ptr[:-1], 0))
         stats = jnp.stack([rounds.astype(dtype), adv.astype(dtype),
                            n_ev.astype(dtype), t_sum,
                            n_live.astype(dtype), flag.astype(dtype),
@@ -504,7 +519,8 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         parts = [stats, adv_dt, adv_nev.astype(dtype),
                  ring_t, ring_id.astype(dtype)]
         if has_coll:
-            parts.append(jnp.stack([*live_sum, fires]).astype(dtype))
+            parts.append(jnp.stack([*live_sum, fires,
+                                    var_entries]).astype(dtype))
         packed = jnp.concatenate(parts)
     return pen_o, rem_o, cb_o, tpos_o, pred_o, ready_o, clk_o, packed
 
@@ -817,7 +833,9 @@ class DrainSim:
 
             # collective schedule tape: `collective` is (pred, ready,
             # edge_src, edge_dst, exec_cost) — the compiled comm DAG
-            # (collectives.tape.DeviceCollective.drain_args()).  Dormant
+            # (collectives.tape.DeviceCollective.drain_args()), with
+            # the element list's (v_ptr, ve_idx) behind them where the
+            # caller has it already (``make_sim``).  Dormant
             # flows (penalty 0) activate on device when their outstanding
             # predecessor count hits zero; the superstep loop walks the
             # whole schedule without host involvement (see
@@ -825,7 +843,7 @@ class DrainSim:
             self.has_coll = False
             self.collective_events: list = []   # (time, flow id) activations
             if collective is not None:
-                cp, cr, ces, ced, cec = collective
+                cp, cr, ces, ced, cec, *index = collective
                 cp = np.asarray(cp, np.int32)
                 cr = np.asarray(cr, np.float64)
                 ces = np.asarray(ces, np.int32)
@@ -847,13 +865,22 @@ class DrainSim:
                 self._coll_clk = jax.device_put(
                     np.zeros(2, np.float64), device)
                 self._coll_total = int(self.n_v)
+                # the element list's variable-major index, with which a
+                # solve finds the live flows' elements without a pass
+                # over the list; DeviceCollective brings its own, built
+                # once per lowered collective
+                index = [np.asarray(a, np.int32) for a in
+                         index or var_index(elems[0], elems[2], self.n_v)]
+                self._var_index = tuple(jax.device_put(a, device)
+                                        for a in index)
                 #: the carried Kahan pair as the host replays it from a
                 #: dispatch's dt table (see _demux)
                 self._coll_clk_host = (0.0, 0.0)
                 opstats.bump("collective_tape_slots", self.n_v)
                 opstats.bump("uploaded_bytes_delta",
                              cp.nbytes + cr.nbytes + ces.nbytes
-                             + ced.nbytes + cec.nbytes)
+                             + ced.nbytes + cec.nbytes
+                             + sum(a.nbytes for a in index))
             else:
                 self._coll = (
                     jax.device_put(np.zeros(1, np.int32), device),
@@ -865,6 +892,7 @@ class DrainSim:
                 self._coll_clk = jax.device_put(np.zeros(2, np.float64),
                                                 device)
                 self._coll_total = 0
+                self._var_index = (None, None)
 
             opstats.bump("uploaded_bytes_full",
                          pen0.nbytes + rem0.nbytes + thresh.nbytes
@@ -984,6 +1012,9 @@ class DrainSim:
         if vb_pair is not None and len(vb_pair[0]) \
                 and np.any(np.asarray(vb_pair[1]) > 0):
             self.has_bounds = True
+        if any(ti < 3 for ti, _, _ in layout):
+            # the element list changes under its variable-major index
+            self._var_index = (None, None)
         payload = jax.device_put(np.concatenate(chunks), self.device)
         out = _apply_transition_payload(
             payload, *self._dev, self._cb, self._pen, self._rem,
@@ -1074,7 +1105,7 @@ class DrainSim:
                 np.int32(k), np.int32(budget), np.int32(want_stop),
                 _ZERO_BITS, *self._tape, tpos_in,
                 pred_in, ready_in, clk_in, *self._coll_edges, t0_in,
-                eps=self.eps, n_c=self.n_c, n_v=self.n_v,
+                *self._var_index, eps=self.eps, n_c=self.n_c, n_v=self.n_v,
                 k_max=k_max, group=group, has_bounds=self.has_bounds,
                 has_tape=self.has_tape, has_coll=self.has_coll)
         self.supersteps += 1
@@ -1141,8 +1172,9 @@ class DrainSim:
             if self.has_coll:
                 # the tape's own counts ride the tail of the same fetch
                 opstats.bump("collective_live_flow_advances",
-                             _live_elem_rounds(p[-3:-1]))
-                opstats.bump("collective_tape_fires", int(p[-1]))
+                             _live_elem_rounds(p[-4:-2]))
+                opstats.bump("collective_tape_fires", int(p[-2]))
+                opstats.bump("fixpoint_var_entries", int(p[-1]))
             with opstats.span("drain.demux"):
                 batches, fired = self._demux(p, adv, tok.k_max, t_sum)
 

@@ -401,7 +401,7 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
              parallel_rounds: bool = False, carry=None,
              max_rounds: Optional[int] = None, return_carry: bool = False,
              unroll: bool = False, has_bounds: bool = True,
-             has_fatpipe: bool = True):
+             has_fatpipe: bool = True, var_index=None):
     """The saturate-bottleneck fixpoint over padded COO arrays.
 
     The single implementation behind every solve path: single-device
@@ -432,12 +432,14 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     host and raise, not spin inside one dispatch).  With
     ``return_carry`` the result is ``(values, remaining, usage, rounds,
     carry, bound_rounds, live_elem_rounds, worked_elem_rounds,
-    partitions)``: the 6-tuple carry to hand back in, the number of this
-    call's rounds that took the bound-first rule (the local round's
+    partitions, var_entry)``: the 6-tuple carry to hand back in, the
+    number of this call's rounds that took the bound-first rule (the
+    local round's
     bound block, the global round's min-bound branch), the live
     elements its rounds entered with and the elements they indexed
     (the rung's size), each summed over them (:func:`_live_elem_rounds`
-    reads either pair), and the partitions the ladder ran.
+    reads either pair), the partitions the ladder ran, and 1 when the
+    call entered from the variable side (below).
 
     THE LADDER.  An element-wide gather or scatter costs by the index,
     live or dead (PERF.md §5), so the round loop is a ladder of round
@@ -461,7 +463,24 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     list of up to ``2 * _LADDER_MIN_ELEMS`` has one rung: the program it
     lowered to before the ladder.  ``unroll=True`` keeps the single
     loop.
+
+    THE VARIABLE SIDE.  A call that enters with few live variables (a
+    collective tape's advance: 0.15 % of the flows) would pay entry and
+    one descent at full width to find them.  ``var_index`` is
+    :func:`var_index`'s ``(v_ptr, ve_idx)`` of this element list; with
+    it, and more than one rung, the live variables' element counts are
+    added up first (n_v wide), and when they fit the BOTTOM rung one
+    ``lax.cond`` builds that rung's lists from the live variables' own
+    elements (:func:`_rung_from_vars`) and enters there: the live
+    elements in the list's order, which is what the stable partition
+    leaves, so every result and counter is the full-width entry's bit
+    for bit.  Otherwise the other branch is the entry and the descent
+    above, unchanged.  ``None`` is the program without the index, to
+    its lowered text.
     """
+    if var_index is not None and axis:
+        raise ValueError("fixpoint: a variable index is of the whole "
+                         "element list, not of a shard's")
     dtype = e_w.dtype
     inf = jnp.array(jnp.inf, dtype)
     big = jnp.array(jnp.finfo(dtype).max, dtype)
@@ -484,17 +503,27 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
     def allmin(x):
         return lax.pmin(x, axis) if axis else x
 
-    with jax.named_scope("sg.lmm.init"):
-        # Element liveness and the live count per constraint ride the
-        # loop state, so no round gathers v_fixed again.  Both are
-        # rebuilt HERE (from the carry's v_fixed when a chunked caller
-        # hands a mid-solve carry back) and dropped at exit: the public
-        # carry stays the 6-tuple.
-        _, e_upen, e_live0, n_live_c0, usage0 = _entry(
-            e_var, e_cnst, e_w, c_fatpipe, v_penalty,
-            None if carry is None else carry[1], n_c, has_fatpipe, allsum,
-            allmax)
-        if carry is None:
+    sizes = [e_var.size] if unroll else _ladder_sizes(e_var.shape)
+    var_side = var_index is not None and len(sizes) > 1
+
+    def enter(e_var, e_cnst, e_w):
+        """Entry over element lists of any width: ``(elems, carry,
+        e_live, n_live_c)``, of which ``first_state`` makes the loop
+        state (apart, so that a call without an index emits its ops in
+        the order it always did)."""
+        with jax.named_scope("sg.lmm.init"):
+            # Element liveness and the live count per constraint ride
+            # the loop state, so no round gathers v_fixed again.  Both
+            # are rebuilt HERE (from the carry's v_fixed when a chunked
+            # caller hands a mid-solve carry back) and dropped at exit:
+            # the public carry stays the 6-tuple.
+            _, e_upen, e_live0, n_live_c0, usage0 = _entry(
+                e_var, e_cnst, e_w, c_fatpipe, v_penalty,
+                None if carry is None else carry[1], n_c, has_fatpipe,
+                allsum, allmax)
+            if carry is not None:
+                return (e_var, e_cnst, e_w, e_upen), carry, e_live0, \
+                    n_live_c0
             remaining0 = c_bound
             # Initial light set: usage strictly positive (exact,
             # maxmin.cpp:545) and remaining above the relative epsilon
@@ -507,10 +536,14 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
             # penalty=inf and inf*0.0 is NaN, so sanitize.
             v_value0 = jnp.where(jnp.isfinite(v_penalty), v_penalty,
                                  0.0) * 0.0
-            carry = (v_value0, v_penalty < 0, remaining0, usage0, light0,
-                     jnp.array(0, jnp.int32))
+            return ((e_var, e_cnst, e_w, e_upen),
+                    (v_value0, v_penalty < 0, remaining0, usage0, light0,
+                     jnp.array(0, jnp.int32)), e_live0, n_live_c0)
+
+    if not var_side:
+        elems, *entered = enter(e_var, e_cnst, e_w)
     v_enabled = v_penalty > 0
-    start_it = carry[5]
+    start_it = jnp.array(0, jnp.int32) if carry is None else carry[5]
     if max_rounds is None:
         max_rounds = _MAX_ROUNDS
 
@@ -766,37 +799,71 @@ def fixpoint(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty, v_bound,
         return level2_v, fix_bound, any_low
 
     body = body_local if parallel_rounds else body_global
-    elems = (e_var, e_cnst, e_w, e_upen)
-    state = (*carry, e_live0, n_live_c0, live_elems(e_live0, n_live_c0),
-             jnp.array(0, jnp.int32), jnp.zeros(2, jnp.int32),
-             jnp.zeros(2, jnp.int32))
-    partitions = jnp.array(0, jnp.int32)
-    sizes = [e_var.size] if unroll else _ladder_sizes(e_var.shape)
-    for size, below in zip(sizes, sizes[1:]):
-        entered = state[5]
-        state = lax.while_loop(
-            lambda st: cond(st) & (st[8] > below),
-            functools.partial(body, elems), state)
-        # Rounds are left to run (else the next list is never read): put
-        # this one live-first and keep its head.  One that came out of a
-        # partition and saw no round since is live-first already, and
-        # entry's padding is dead from the start, so rungs skipped in
-        # one step (random pairs fall from 100 % live to 4 % in a round)
-        # cost one partition and a slice each.
-        part = cond(state) & ((state[5] > entered) | (size == sizes[0]))
-        with jax.named_scope("sg.lmm.partition"):
-            *elems, e_live = lax.cond(
-                part, functools.partial(_livefirst_head, n_keep=below),
-                lambda *lists: tuple(_head(a, below) for a in lists),
-                *elems, state[6])
-        state = (*state[:6], e_live, *state[7:])
-        partitions = partitions + part.astype(jnp.int32)
+
+    def first_state(carry, e_live0, n_live_c0):
+        return (*carry, e_live0, n_live_c0,
+                live_elems(e_live0, n_live_c0), jnp.array(0, jnp.int32),
+                jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32))
+
+    def descend(elems, state):
+        """The rungs above the bottom one: ``(the bottom rung's lists,
+        the state there, the partitions it took)``."""
+        partitions = jnp.array(0, jnp.int32)
+        for size, below in zip(sizes, sizes[1:]):
+            entered = state[5]
+            state = lax.while_loop(
+                lambda st: cond(st) & (st[8] > below),
+                functools.partial(body, elems), state)
+            # Rounds are left to run (else the next list is never read):
+            # put this one live-first and keep its head.  One that came
+            # out of a partition and saw no round since is live-first
+            # already, and entry's padding is dead from the start, so
+            # rungs skipped in one step (random pairs fall from 100 %
+            # live to 4 % in a round) cost one partition and a slice
+            # each.
+            part = cond(state) & ((state[5] > entered)
+                                  | (size == sizes[0]))
+            with jax.named_scope("sg.lmm.partition"):
+                *elems, e_live = lax.cond(
+                    part, functools.partial(_livefirst_head, n_keep=below),
+                    lambda *lists: tuple(_head(a, below) for a in lists),
+                    *elems, state[6])
+            state = (*state[:6], e_live, *state[7:])
+            partitions = partitions + part.astype(jnp.int32)
+        return tuple(elems), state, partitions
+
+    if not var_side:
+        elems, state, partitions = descend(elems, first_state(*entered))
+        var_entry = jnp.array(0, jnp.int32)
+    else:
+        v_ptr, ve_idx = var_index
+        # The ladder's own rung test, read from the variables before any
+        # element is touched: a live variable's elements are live.
+        live_v = v_enabled if carry is None else v_enabled & ~carry[1]
+        deg = jnp.where(live_v, v_ptr[1:] - v_ptr[:-1], 0)
+
+        def from_lists(e_var, e_cnst, e_w):
+            elems, *st = enter(e_var, e_cnst, e_w)
+            return (*descend(elems, first_state(*st)),
+                    jnp.array(0, jnp.int32))
+
+        def from_vars(e_var, e_cnst, e_w):
+            with jax.named_scope("sg.lmm.init"):
+                lists = _rung_from_vars(v_ptr, ve_idx, deg, e_var, e_cnst,
+                                        e_w, sizes[-1])
+            elems, *st = enter(*lists)
+            return (elems, first_state(*st), jnp.array(0, jnp.int32),
+                    jnp.array(1, jnp.int32))
+
+        elems, state, partitions, var_entry = lax.cond(
+            jnp.sum(deg) <= sizes[-1], from_vars, from_lists,
+            e_var, e_cnst, e_w)
     state = _run_rounds(cond, functools.partial(body, tuple(elems)), state,
                         max_rounds, unroll)
     v_value, v_fixed, remaining, usage, light, rounds = state[:6]
     if return_carry:
         return (v_value, remaining, usage, rounds, state[:6], state[9],
-                state[10], state[11], partitions)
+                state[10], state[11], partitions, var_entry)
     return v_value, remaining, usage, rounds
 
 
@@ -912,6 +979,74 @@ def _livefirst_head(*lists_and_live, n_keep: int):
     head_live = (lax.iota(jnp.int32, n_keep).reshape(keep.shape)
                  < jnp.count_nonzero(live))
     return (*heads, head_live)
+
+
+def var_index(e_var, e_w, n_v: int):
+    """The variable-major index of an element list, on the host:
+    ``(v_ptr[n_v + 1], ve_idx[E])``, int32.  ``ve_idx`` holds the
+    positions (in the flattened list) of the elements that count — a
+    positive weight on a variable of ``[0, n_v)`` — grouped by variable,
+    ascending within one; variable ``v``'s are ``ve_idx[v_ptr[v]:
+    v_ptr[v + 1]]``.  Static for a list that is never renumbered or
+    reweighted; one stable argsort of the list, so build it once per
+    list and not per sim."""
+    e_var = np.asarray(e_var, np.int64).reshape(-1)
+    counts = (np.asarray(e_w, np.float64).reshape(-1) > 0) \
+        & (e_var >= 0) & (e_var < n_v)
+    key = np.where(counts, e_var, n_v)
+    ve_idx = np.argsort(key, kind="stable").astype(np.int32)
+    v_ptr = np.zeros(n_v + 1, np.int32)
+    np.cumsum(np.bincount(key, minlength=n_v + 1)[:n_v], out=v_ptr[1:])
+    return v_ptr, ve_idx
+
+
+def _rung_from_vars(v_ptr, ve_idx, deg, e_var, e_cnst, e_w, n_keep: int):
+    """The ``n_keep``-element rung of the ladder as the stable live-first
+    partition of the whole list would leave it — the live elements in
+    the list's order, then dead padding (weight 0, as a list's own) —
+    built from the live variables' own elements: ``(e_var, e_cnst,
+    e_w)`` in the list's shape convention.  ``deg`` is the element count
+    of each live variable (0 for the others) and must add up to at most
+    ``n_keep``; ``(v_ptr, ve_idx)`` is :func:`var_index`'s.
+
+    Nothing here is as wide as the list: one n_v-wide scatter marks
+    where each live variable's elements start in the rung, a running
+    max hands every position its variable, and ``n_keep``-wide gathers
+    fetch the element's position (two: the variable's shift, then
+    ``ve_idx``), its constraint and its weight (its variable is the one
+    that owns it).  Positions come out ascending
+    when the list is variable-major (as the collective tape lowers it);
+    another list pays one ``n_keep``-wide sort."""
+    n_v = deg.shape[0]
+    shape = _head(e_var, n_keep).shape
+    end = jnp.cumsum(deg)
+    first = end - deg
+    group = _pos_group(n_v)
+    marks = jnp.zeros(n_keep, jnp.int32).at[
+        jnp.where(deg > 0, first, n_keep).reshape(-1, group)].set(
+        lax.iota(jnp.int32, n_v).reshape(-1, group) + 1, mode="drop")
+    owner = jnp.maximum(lax.cummax(marks) - 1, 0).reshape(shape)
+    at = lax.iota(jnp.int32, n_keep).reshape(shape)
+    inside = at < end[-1]
+    # element j of variable v sits at ve_idx[v_ptr[v] + j], and in the
+    # rung at first[v] + j
+    pos = jnp.where(
+        inside,
+        jnp.take(ve_idx, at + jnp.take(v_ptr[:-1] - first, owner)),
+        e_var.size).reshape(-1)
+    pos, owner = lax.cond(
+        jnp.all(pos[1:] >= pos[:-1]), lambda *both: both,
+        lambda *both: tuple(lax.sort(both, num_keys=1)),
+        pos, owner.reshape(-1))
+    pos = jnp.where(inside, pos.reshape(shape), 0)
+
+    def fetch(a):
+        got = a[pos // a.shape[1], pos % a.shape[1]] if a.ndim == 2 \
+            else jnp.take(a, pos)
+        return jnp.where(inside, got, 0)
+
+    return (jnp.where(inside, owner.reshape(shape), 0).astype(e_var.dtype),
+            fetch(e_cnst), fetch(e_w))
 
 
 #: ``fixpoint`` sums the live and the indexed elements of its rounds in
@@ -1339,7 +1474,7 @@ def _solve_kernel_chunk(e_var, e_cnst, e_w, c_bound, c_fatpipe, v_penalty,
                     axis=None, parallel_rounds=parallel_rounds,
                     carry=carry, max_rounds=chunk, return_carry=True,
                     unroll=unroll, has_bounds=has_bounds,
-                    has_fatpipe=has_fatpipe)
+                    has_fatpipe=has_fatpipe)[:9]
 
 
 def _solve_chunk_batched_lane(e_var, e_cnst, ew, cb, fat, pen, vb, carry,

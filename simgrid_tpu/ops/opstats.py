@@ -44,6 +44,16 @@ context object through the solver entry points:
                               rungs (a descent over several rungs is
                               one partition and a slice a rung); 0
                               under the floor
+* ``fixpoint_var_entries``  — committed advances of a collective
+                              tape's superstep whose solve entered from
+                              the variable side: the live flows'
+                              elements fit the ladder's bottom rung, so
+                              ``fixpoint`` built it from the element
+                              list's variable-major index and ran no
+                              op as wide as the list; one more scalar
+                              at the tail of the packed vector.  Over
+                              the advances: the share that paid a
+                              live-width entry, not a full-width one
 * ``uploaded_bytes_full``   — host->device bytes shipped as whole
                               arrays (fresh ``device_put``)
 * ``uploaded_bytes_delta``  — host->device bytes shipped as indexed

@@ -224,6 +224,77 @@ def test_every_rung_holds_the_rounds_budget_and_a_partition_its_own(
     assert count_sorts(jaxpr) == 0
 
 
+def widest(jaxpr):
+    """The most elements any equation of ``jaxpr`` (sub-jaxprs
+    included) puts out."""
+    most = 0
+    for eqn in jaxpr.eqns:
+        most = max([most] + [int(np.prod(v.aval.shape))
+                             for v in eqn.outvars if hasattr(v, "aval")]
+                   + [widest(sub) for sub in sub_jaxprs(eqn)])
+    return most
+
+
+@CALLS
+@pytest.mark.parametrize("two_d", [False, True], ids=["1d", "2d"])
+@pytest.mark.parametrize("parallel_rounds,has_bounds,has_fatpipe",
+                         sorted(BUDGETS))
+def test_the_variable_side_runs_nothing_as_wide_as_the_list(
+        parallel_rounds, has_bounds, has_fatpipe, carried, two_d,
+        monkeypatch):
+    """With the list's variable-major index (ISSUE 34) entry is one cond:
+    one branch is the entry and the descent above, within their budgets
+    at full width; the other builds the bottom rung from the live
+    variables' elements and puts out NOTHING as wide as the list (no
+    gather, scatter, sort, scan, copy or elementwise pass: the widest
+    thing it makes is n_v wide), with entry's two indexed ops, a
+    handful of gathers and one scatter of start marks, at the rung's
+    width; the only sort stands behind the cond that asks whether the
+    rung's positions came out ascending."""
+    monkeypatch.setattr(lmm_jax, "_LADDER_MIN_ELEMS", 32)
+    _, _, entry = BUDGETS[parallel_rounds, has_bounds, has_fatpipe]
+    if carried:
+        entry = CARRIED_ENTRY
+    a = system(bounds="bind" if has_bounds else None, fatpipe=has_fatpipe)
+    n_c, n_v, n_elem = len(a.c_bound), len(a.v_penalty), len(a.e_var)
+    lists = [x.reshape(-1, 8) if two_d else x
+             for x in (a.e_var, a.e_cnst, a.e_w)]
+    sizes = lmm_jax._ladder_sizes(lists[0].shape)
+    assert n_elem > 2 * n_v > 2 * sizes[-1] and len(sizes) >= 4
+
+    def run(carry, index, *args):
+        return lmm_jax.fixpoint(*args, jnp.asarray(1e-9, a.e_w.dtype), n_c,
+                                n_v, parallel_rounds=parallel_rounds,
+                                carry=carry, return_carry=True,
+                                has_bounds=has_bounds,
+                                has_fatpipe=has_fatpipe, var_index=index)
+
+    carry = (np.zeros(n_v), np.zeros(n_v, bool), a.c_bound, np.ones(n_c),
+             np.ones(n_c, bool), np.int32(0)) if carried else None
+    jaxpr = jax.make_jaxpr(run)(
+        carry, lmm_jax.var_index(a.e_var, a.e_w, n_v), *lists, a.c_bound,
+        a.c_fatpipe, a.v_penalty, a.v_bound).jaxpr
+    # outside the cond: the bottom rung's round loop and n_v-wide math
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1 and len(round_loops(jaxpr)) == 1
+    assert count_indexed(jaxpr, n_elem)[0] == 0
+    # (index 0 is the false branch: the lists' side)
+    from_lists, from_vars = (br.jaxpr for br in conds[0].params["branches"])
+    assert widest(from_lists) >= n_elem
+    assert len(round_loops(from_lists)) == len(sizes) - 1
+    in_loops = sum(count_indexed(loop.params["body_jaxpr"].jaxpr, size)[0]
+                   for loop, size in zip(round_loops(from_lists), sizes))
+    assert count_indexed(from_lists, sizes[-1])[0] - in_loops <= entry
+    assert widest(from_vars) <= n_v < n_elem
+    got, inner = count_indexed(from_vars, sizes[-1])
+    # marks, shift by owner, ve_idx, e_cnst, e_w, and entry's own
+    assert got <= 5 + entry and inner == [[0, 0]]
+    assert count_sorts(from_vars) == 1 == sum(
+        count_sorts(br.jaxpr) for e in from_vars.eqns
+        if e.primitive.name == "cond" for br in e.params["branches"])
+    assert not round_loops(from_vars)
+
+
 # ---------------------------------------------------------------------------
 # exactness
 # ---------------------------------------------------------------------------
