@@ -40,6 +40,8 @@ from __future__ import annotations
 from collections import deque
 from typing import Dict, List, Tuple
 
+from ..utils.gc_pause import collector_paused
+
 # Mirrors smpi/coll.py (reference smpi/include/private.hpp COLL_TAG_*);
 # kept literal so importing the schedule compiler never drags in the
 # SMPI runtime.  tests/test_collectives.py asserts they stay in sync.
@@ -407,8 +409,11 @@ def generate(op: str, algo: str, ranks: int,
     except KeyError:
         raise ValueError(f"no schedule generator for {op}/{algo}; "
                          f"known: {sorted(GENERATORS)}") from None
-    if mode is None:
-        return fn(ranks)
-    if mode == "elems":
-        return fn(ranks, int(payload))
-    return fn(ranks, float(payload))
+    # millions of records and sets that all stay: nothing for the
+    # cyclic collector to find while they are built
+    with collector_paused():
+        if mode is None:
+            return fn(ranks)
+        if mode == "elems":
+            return fn(ranks, int(payload))
+        return fn(ranks, float(payload))

@@ -109,7 +109,7 @@ class CollectiveSpec:
                         loop_bw=self.loop_bw, core_bw=self.core_bw)
 
     def build(self, exec_cost=None) -> DeviceCollective:
-        with opstats.span("coll.lower", id="tape"):
+        with opstats.span("coll.lower", id="schedule"):
             sched = generate(self.op, self.algo, self.ranks, self.payload)
-            return DeviceCollective(sched, self.topology(),
-                                    exec_cost=exec_cost)
+        return DeviceCollective(sched, self.topology(),
+                                exec_cost=exec_cost)

@@ -36,6 +36,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..ops import opstats
 from .schedule import CollectiveSchedule
 from .topology import Topology
 
@@ -62,56 +63,60 @@ class DeviceCollective:
         if n_v == 0:
             raise ValueError("schedule has no communications")
         self.n_v = n_v
-        self.n_c = topology.n_c
-        src = np.fromiter((r.src for r in recs), np.int64, count=n_v)
-        dst = np.fromiter((r.dst for r in recs), np.int64, count=n_v)
-        if exec_cost is None:
-            # a routed topology delays every record by its route's
-            # latency; the synthetic flavors by nothing
-            ex = np.asarray(topology.delays(src, dst), np.float64)
-        else:
-            ex = np.asarray(exec_cost, np.float64)
-            if ex.shape != (n_v,):
-                raise ValueError(f"exec_cost must have one entry per "
-                                 f"record ({n_v}), got {ex.shape}")
-        self.exec_cost = ex
+        with opstats.span("coll.lower", id="tape"):
+            src = np.fromiter((r.src for r in recs), np.int64, count=n_v)
+            dst = np.fromiter((r.dst for r in recs), np.int64, count=n_v)
+            self.sizes = np.maximum(
+                np.fromiter((r.size for r in recs), np.float64,
+                            count=n_v), 1.0)
+            self.pred0 = np.fromiter((len(r.preds) for r in recs),
+                                     np.int32, count=n_v)
+            if self.pred0.any():
+                # successor edges, grouped by successor, predecessors
+                # ascending within a group
+                es = np.fromiter((p.rid for r in recs for p in r.preds),
+                                 np.int64, count=int(self.pred0.sum()))
+                ed = np.repeat(np.arange(n_v), self.pred0)
+                order = np.lexsort((es, ed))
+                es, ed = es[order], ed[order]
+            else:
+                # keep the edge arrays non-empty: a single dropped-slot
+                # row (dst = n_v scatters into the drop lane)
+                es, ed = [0], [n_v]
+            self.edge_src = np.asarray(es, np.int32)
+            self.edge_dst = np.asarray(ed, np.int32)
 
         # records are in rid order (rid = index), so a transfer's index
-        # is its flow slot
+        # is its flow slot.  A routed topology looks its routes up HERE
+        # (its own ``routes`` span, between the tape's two), and knows
+        # its constraints only afterwards
         ev, ec, ew = topology.lower(src, dst)
-        self.e_var = np.asarray(ev, np.int32)
-        self.e_cnst = np.asarray(ec, np.int32)
-        self.e_w = np.asarray(ew, np.float64)
-        # which elements belong to which flow: with it a solve reaches
-        # the few live flows' elements without a pass over the list
-        # (ops.lmm_jax.var_index; one argsort, here and not per sim)
-        from ..ops.lmm_jax import var_index
-        self.v_ptr, self.ve_idx = var_index(self.e_var, self.e_w, n_v)
-        self.c_bound = np.asarray(topology.c_bound, np.float64)
-        self.sizes = np.maximum(
-            np.fromiter((r.size for r in recs), np.float64, count=n_v),
-            1.0)
-
-        self.pred0 = np.fromiter((len(r.preds) for r in recs), np.int32,
-                                 count=n_v)
-        roots = self.pred0 == 0
-        timed_root = roots & (ex > 0)
-        self.penalty0 = np.where(roots & ~timed_root, 1.0, 0.0)
-        self.ready0 = np.where(timed_root, ex, np.inf)
-        if self.pred0.any():
-            # successor edges, grouped by successor, predecessors
-            # ascending within a group
-            es = np.fromiter((p.rid for r in recs for p in r.preds),
-                             np.int64, count=int(self.pred0.sum()))
-            ed = np.repeat(np.arange(n_v), self.pred0)
-            order = np.lexsort((es, ed))
-            es, ed = es[order], ed[order]
-        else:
-            # keep the edge arrays non-empty: a single dropped-slot
-            # row (dst = n_v scatters into the drop lane)
-            es, ed = [0], [n_v]
-        self.edge_src = np.asarray(es, np.int32)
-        self.edge_dst = np.asarray(ed, np.int32)
+        with opstats.span("coll.lower", id="tape"):
+            self.n_c = topology.n_c
+            self.c_bound = np.asarray(topology.c_bound, np.float64)
+            if exec_cost is None:
+                # a routed topology delays every record by its route's
+                # latency; the synthetic flavors by nothing
+                ex = np.asarray(topology.delays(src, dst), np.float64)
+            else:
+                ex = np.asarray(exec_cost, np.float64)
+                if ex.shape != (n_v,):
+                    raise ValueError(f"exec_cost must have one entry "
+                                     f"per record ({n_v}), got {ex.shape}")
+            self.exec_cost = ex
+            self.e_var = np.asarray(ev, np.int32)
+            self.e_cnst = np.asarray(ec, np.int32)
+            self.e_w = np.asarray(ew, np.float64)
+            # which elements belong to which flow: with it a solve
+            # reaches the few live flows' elements without a pass over
+            # the list (ops.lmm_jax.var_index; one argsort, here and not
+            # per sim)
+            from ..ops.lmm_jax import var_index
+            self.v_ptr, self.ve_idx = var_index(self.e_var, self.e_w, n_v)
+            roots = self.pred0 == 0
+            timed_root = roots & (ex > 0)
+            self.penalty0 = np.where(roots & ~timed_root, 1.0, 0.0)
+            self.ready0 = np.where(timed_root, ex, np.inf)
 
     @property
     def n_edges(self) -> int:

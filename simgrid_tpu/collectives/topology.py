@@ -31,7 +31,8 @@ reference platform's loopback link, not the fabric.
 :meth:`Topology.lower` and :meth:`Topology.delays` are what the tape
 compiler calls; the three synthetic flavors answer them from
 ``route()``, the routed one from its route table without a Python
-loop per element.
+loop per element.  A flavor's ``n_c`` and ``c_bound`` are read AFTER
+``lower``: the routed one knows its constraints only then.
 """
 
 from __future__ import annotations
@@ -41,6 +42,7 @@ from typing import List, Tuple
 import numpy as np
 
 from ..ops import opstats
+from ..utils.gc_pause import collector_paused
 
 FLAVORS = ("nic", "star", "ring")
 
@@ -128,19 +130,29 @@ class RoutedTopology(Topology):
     """The ``routed`` flavor: rank ``r`` sits on ``hosts[r]`` of the
     platform ``engine`` has loaded.
 
-    Constraint slots are the links the R(R-1) routes cross, numbered by
-    first crossing (pairs in rank order); a slot's capacity is the
-    link's bandwidth under the network model's bandwidth factor, which
-    is what the link's own LMM constraint holds.  ``route(src, dst)``
-    is ``routing/``'s route in slots; a transfer's elements are that
-    route at weight 1 and, when the model runs with cross-traffic, the
-    route back at weight 0.05; its delay is the route's latency under
-    the model's latency factor (LV08: 13.01) — the three things
+    A pair's route is looked up when a schedule first asks for it
+    (:meth:`lower`, :meth:`delays`, :meth:`route`), once, together with
+    its way back where the model runs with cross-traffic: a collective
+    over R ranks uses a few of the R(R-1) ordered pairs (recursive
+    doubling: R log2 R), and all of them are 4.3 x 10^9 at the 65,536
+    hosts of a machine.  Constraint slots are the links those routes
+    cross, numbered by first crossing, each call's new pairs in rank
+    order (so a schedule that uses every pair numbers them as a walk
+    over all pairs would); a slot's capacity is the link's bandwidth
+    under the network model's bandwidth factor, which is what the
+    link's own LMM constraint holds.  ``n_c``, ``c_bound`` and
+    ``links`` therefore stand once the collective is lowered, and
+    ``DeviceCollective`` reads them then.  ``route(src, dst)`` is
+    ``routing/``'s route in slots; a transfer's elements are that
+    route at weight 1 and, with cross-traffic, the route back at weight
+    0.05; its delay is the route's latency under the model's latency
+    factor (LV08: 13.01) — the three things
     ``NetworkCm02Model.communicate`` gives the same pair of hosts.
     """
 
-    __slots__ = ("links", "_off", "_slot", "_w", "_n_fwd", "_delay",
-                 "_hosts")
+    __slots__ = ("links", "_hosts", "_host_objs", "_lat_factor",
+                 "_bw_factor", "_crosstraffic", "_slot_of", "_pair",
+                 "_at", "_off", "_slot", "_delay")
 
     #: weight of a flow on the links of its way back
     #: (network_cm02.cpp, as ``communicate`` expands it)
@@ -149,44 +161,34 @@ class RoutedTopology(Topology):
     def __init__(self, engine, hosts):
         from ..utils.config import config
 
-        R = len(hosts)
-        if R < 2:
+        if len(hosts) < 2:
             raise ValueError("a routed topology needs at least 2 ranks")
         model = engine.pimpl.network_model
         self.flavor = "routed"
-        self.ranks = R
+        self.ranks = len(hosts)
+        self._host_objs = list(hosts)
         self._hosts = tuple(h.name for h in hosts)
-        lat_factor = model.get_latency_factor(0.0)
-        with opstats.span("coll.lower", id="routes"):
-            slot_of: dict = {}
-            fwd: List[List[int]] = []
-            delay = np.zeros(R * R)
-            for a in range(R):
-                for b in range(R):
-                    links: list = []
-                    if a != b:
-                        delay[a * R + b] = lat_factor * hosts[a].route_to(
-                            hosts[b], links)
-                    fwd.append([slot_of.setdefault(link, len(slot_of))
-                                for link in links])
-            self.links = list(slot_of)
-            # pair (a, b): its route, then (cross-traffic) b's route to a
-            back = ([fwd[b * R + a] for a in range(R) for b in range(R)]
-                    if config["network/crosstraffic"] else [[]] * (R * R))
-            self._n_fwd = np.array([len(r) for r in fwd])
-            n = self._n_fwd + np.array([len(r) for r in back])
-            self._off = np.concatenate([[0], np.cumsum(n)])
-            self._slot = np.fromiter(
-                (c for f, k in zip(fwd, back) for c in f + k), np.int64,
-                count=self._off[-1])
-            within = np.arange(self._off[-1]) - np.repeat(self._off[:-1], n)
-            self._w = np.where(within < np.repeat(self._n_fwd, n), 1.0,
-                               self.CROSSTRAFFIC_WEIGHT)
-            self._delay = delay
-        self.n_c = len(self.links)
-        self.c_bound = np.array(
-            [model.get_bandwidth_factor(0.0) * link.get_bandwidth()
-             for link in self.links])
+        self._lat_factor = model.get_latency_factor(0.0)
+        self._bw_factor = model.get_bandwidth_factor(0.0)
+        self._crosstraffic = bool(config["network/crosstraffic"])
+        self.links: list = []
+        self._slot_of: dict = {}
+        # the routed pairs (src * ranks + dst): sorted, with where each
+        # one's route sits in the route table (rows in the order routed)
+        self._pair = np.zeros(0, np.int64)
+        self._at = np.zeros(0, np.int64)
+        self._off = np.zeros(1, np.int64)
+        self._slot = np.zeros(0, np.int64)
+        self._delay = np.zeros(0)
+
+    @property
+    def n_c(self) -> int:
+        return len(self.links)
+
+    @property
+    def c_bound(self) -> np.ndarray:
+        return np.array([self._bw_factor * link.get_bandwidth()
+                         for link in self.links])
 
     def _pairs(self, src, dst) -> np.ndarray:
         src, dst = np.asarray(src, np.int64), np.asarray(dst, np.int64)
@@ -195,23 +197,72 @@ class RoutedTopology(Topology):
                              "(no loopback is lowered)")
         return src * self.ranks + dst
 
+    def _back(self, p: np.ndarray) -> np.ndarray:
+        """Pairs ``p`` the other way round."""
+        return p % self.ranks * self.ranks + p // self.ranks
+
+    def _rows(self, p: np.ndarray) -> np.ndarray:
+        """The route-table rows of pairs ``p``, each routed here first
+        if it never was (and with it, under cross-traffic, the pair
+        the other way round)."""
+        R = self.ranks
+        want = np.unique(np.concatenate([p, self._back(p)])
+                         if self._crosstraffic else p)
+        new = np.setdiff1d(want, self._pair, assume_unique=True)
+        if len(new):
+            with opstats.span("coll.lower", id="routes"), \
+                    collector_paused():
+                hosts, slot_of = self._host_objs, self._slot_of
+                slots: List[int] = []
+                n = np.zeros(len(new), np.int64)
+                delay = np.zeros(len(new))
+                for i, (a, b) in enumerate(zip((new // R).tolist(),
+                                               (new % R).tolist())):
+                    links: list = []
+                    delay[i] = hosts[a].route_to(hosts[b], links)
+                    slots += [slot_of.setdefault(link, len(slot_of))
+                              for link in links]
+                    n[i] = len(links)
+                opstats.bump("collective_routes", len(new))
+                self.links = list(slot_of)
+                pair = np.concatenate([self._pair, new])
+                at = np.concatenate([self._at, len(self._pair)
+                                     + np.arange(len(new))])
+                order = np.argsort(pair, kind="stable")
+                self._pair, self._at = pair[order], at[order]
+                self._off = np.concatenate(
+                    [self._off, self._off[-1] + np.cumsum(n)])
+                self._slot = np.concatenate(
+                    [self._slot, np.asarray(slots, np.int64)])
+                self._delay = np.concatenate(
+                    [self._delay, self._lat_factor * delay])
+        return self._at[np.searchsorted(self._pair, p)]
+
     def route(self, src: int, dst: int) -> List[int]:
-        p = int(self._pairs([src], [dst])[0])
-        o = self._off[p]
-        return self._slot[o:o + self._n_fwd[p]].tolist()
+        row = int(self._rows(self._pairs([src], [dst]))[0])
+        return self._slot[self._off[row]:self._off[row + 1]].tolist()
 
     def lower(self, src, dst):
         p = self._pairs(src, dst)
-        n = self._off[p + 1] - self._off[p]
-        rec = np.repeat(np.arange(len(p)), n)
-        # element j of transfer i sits at off[p[i]] + j
+        # transfer i: its route and, with cross-traffic, its peer's
+        # route back; one table row and one weight each
+        rows, w = [self._rows(p)], [1.0]
+        if self._crosstraffic:
+            rows.append(self._rows(self._back(p)))
+            w.append(self.CROSSTRAFFIC_WEIGHT)
+        rows = np.stack(rows, axis=1)
+        n = self._off[rows + 1] - self._off[rows]
+        rec = np.repeat(np.arange(len(p)), n.sum(axis=1))
+        rows, n = rows.ravel(), n.ravel()
+        # element j of table row r sits at off[r] + j
         at = np.arange(n.sum()) - np.repeat(np.cumsum(n) - n, n) \
-            + np.repeat(self._off[p], n)
-        return rec, self._slot[at], self._w[at]
+            + np.repeat(self._off[rows], n)
+        return rec, self._slot[at], np.repeat(np.tile(w, len(p)), n)
 
     def delays(self, src, dst) -> np.ndarray:
-        return self._delay[self._pairs(src, dst)]
+        rows = self._rows(self._pairs(src, dst))
+        return self._delay[rows]
 
     def key(self) -> tuple:
-        return ("topo", self.flavor, self.ranks, self._hosts, self.n_c,
-                float(self.c_bound.sum()), float(self._delay.sum()))
+        return ("topo", self.flavor, self.ranks, self._hosts,
+                self._lat_factor, self._bw_factor, self._crosstraffic)

@@ -925,6 +925,17 @@ _LADDER_MIN_ELEMS = 1 << 15
 #: rounds x n_elem in the alltoall where 2 reads 54 %) in twice the
 #: rungs.
 _LADDER_RATIO = 2
+#: No rung but the list itself holds more than this many elements: a
+#: rung is one more copy of the round for XLA to compile, and above
+#: 2^21 elements a copy costs ~25 s on the chip's host where one at
+#: config #4's width costs ~10 (PERF.md §6, PR 35: the nine rungs from
+#: 9,234,864 down compiled in ~230 s, the seven without 4,617,432 and
+#: 2,308,720 in 161-195 s).  A longer list steps from its own size to the
+#: first power of the ratio at or under this, so a solve with more
+#: live elements than that runs its rounds on the whole list.  Lists
+#: of up to 2^22 elements (config #4's are 2^21 at most) keep the
+#: plain ladder.
+_LADDER_TOP_ELEMS = 1 << 21
 
 
 def _ladder_sizes(shape) -> List[int]:
@@ -936,7 +947,10 @@ def _ladder_sizes(shape) -> List[int]:
     group = shape[-1] if len(shape) == 2 else _pos_group(size)
     sizes = [size]
     while True:
-        below = -(-sizes[-1] // (_LADDER_RATIO * group)) * group
+        step = _LADDER_RATIO
+        while (below := -(-sizes[-1] // (step * group)) * group) \
+                > _LADDER_TOP_ELEMS:
+            step *= _LADDER_RATIO
         if not _LADDER_MIN_ELEMS < below < sizes[-1]:
             return sizes
         sizes.append(below)

@@ -219,6 +219,13 @@ context object through the solver entry points:
                               the same tail; over advances x flow
                               slots it is the share of the tape a
                               full-width solve works for
+* ``collective_routes``     — (src, dst) rank pairs a
+                              ``collectives.RoutedTopology`` looked up
+                              through ``routing/`` (``route_to``), each
+                              once, when a schedule first used it or
+                              its way back: bumped inside the
+                              ``coll.lower`` span of ``id`` ``routes``,
+                              so span / count is the price of a route
 * ``collective_replays``    — speculative in-flight supersteps
                               discarded because the superstep they
                               chained from fired a collective tape
@@ -296,13 +303,17 @@ this table, like the counters (the ``opstats-discipline`` lint checks
                         the zones, hosts, links and routes it builds
 * ``lmm.flatten``     — ``lmm_jax.flatten``: the live host system
                         walked into padded COO arrays
-* ``coll.lower``      — a collective lowered for the device: once in
-                        ``collectives.RoutedTopology`` (``id``
-                        ``routes``: every rank pair's route looked up
-                        and put in slots) and once in
-                        ``CollectiveSpec.build`` (``id`` ``tape``: the
-                        schedule generated, its records and DAG
-                        compiled into ``DeviceCollective``'s arrays)
+* ``coll.lower``      — a collective lowered for the device, three
+                        ``id``s that never nest: ``schedule`` in
+                        ``CollectiveSpec.build`` (the generator: the
+                        per-rank programs matched into records with
+                        their predecessor sets), ``routes`` in
+                        ``collectives.RoutedTopology`` (the routes of
+                        the pairs a schedule uses looked up and put in
+                        slots, when ``lower`` first asks for them) and
+                        ``tape`` in ``DeviceCollective`` (the records
+                        and the DAG compiled into its arrays: two
+                        spans, before and after the routes)
 * ``drain.init``      — ``DrainSim.__init__``: the host arrays shaped
                         and handed to the device (``device_put``)
 * ``drain.issue``     — ``DrainSim._superstep_issue``: one superstep

@@ -259,3 +259,210 @@ def test_the_collective_program_names_its_scope():
         text = spec.jitted.lower(*args, **statics).as_text(
             debug_info=True)
         assert "sg.drain.coll" in text
+
+
+# ---------------------------------------------------------------------------
+# routes on demand (ISSUE 35): a schedule's own pairs, each looked up once
+# ---------------------------------------------------------------------------
+
+def all_ranks(e):
+    return list(e.get_all_hosts())
+
+
+@pytest.fixture
+def route_calls(monkeypatch):
+    """Every ``route_to`` call, as (source host, destination host)."""
+    from simgrid_tpu.models.host import Host
+    calls, real = [], Host.route_to
+
+    def counted(self, dst, links):
+        calls.append((self.name, dst.name))
+        return real(self, dst, links)
+
+    monkeypatch.setattr(Host, "route_to", counted)
+    return calls
+
+
+def test_a_route_is_looked_up_once_and_only_if_a_record_uses_it(
+        engine, route_calls):
+    hosts = all_ranks(engine)
+    topo = RoutedTopology(engine, hosts)
+    assert route_calls == [] and topo.n_c == 0        # nothing routed yet
+    before = opstats.snapshot()
+    dc = CollectiveSpec("allreduce", "rdb", 128, topo, 8192.0).build()
+    used = {(hosts[r.src].name, hosts[r.dst].name)
+            for r in dc.schedule.records}
+    # recursive doubling: every pair's way back is a pair of its own
+    assert len(used) == dc.n_v == 128 * 7
+    assert len(route_calls) == len(set(route_calls)) == len(used)
+    assert set(route_calls) == used
+    assert opstats.diff(before)["collective_routes"] == len(used)
+    names = [s.id for s in opstats.spans() if s.name == "coll.lower"][-4:]
+    assert names == ["schedule", "tape", "routes", "tape"]
+    assert dc.n_c == topo.n_c == len(topo.links) == len(dc.c_bound)
+    # asking again routes nothing; a new pair routes itself and its way
+    # back, and the slots handed out so far stay
+    slots = topo.route(0, 1)
+    topo.delays([0, 5], [1, 4])
+    assert len(route_calls) == len(used)
+    assert (hosts[0].name, hosts[3].name) not in used
+    topo.route(0, 3)
+    assert route_calls[len(used):] == [(hosts[0].name, hosts[3].name),
+                                       (hosts[3].name, hosts[0].name)]
+    assert topo.route(0, 1) == slots and topo.n_c >= dc.n_c
+
+
+def test_a_binomial_bcast_routes_the_ways_back_too(engine, route_calls):
+    """A pair whose way back no record uses is still crossed by the
+    cross-traffic of the way there: both are looked up, once."""
+    hosts = rank_hosts(engine)
+    dc = CollectiveSpec("bcast", "binomial_tree", RANKS,
+                        RoutedTopology(engine, hosts), 1e6).build()
+    assert dc.n_v == RANKS - 1
+    assert len(route_calls) == len(set(route_calls)) == 2 * dc.n_v
+    fwd = dc.e_w == 1.0
+    assert fwd.any() and (~fwd).any()
+    assert np.all(dc.e_w[~fwd] == RoutedTopology.CROSSTRAFFIC_WEIGHT)
+
+
+def lowering_digest(dc):
+    h = hashlib.sha256()
+    for a in (dc.e_var, dc.e_cnst, dc.e_w, dc.c_bound, dc.exec_cost):
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()[:16]
+
+
+#: ``lowering_digest`` of the pairwise alltoall at the parent commit
+#: (8f4321b), whose constructor routed all R x R pairs in rank order:
+#: 16 ranks at stride 8, rotated by 5, on the 128-host dragonfly, and
+#: ``dfly65k-pairwise.drain``'s 320 ranks at stride 204 on config #4's
+PARENT_LOWERING = {16: "09737e93014b9d39", 320: "c40c35b5b82543f7"}
+
+XML_65K = XML.replace("0-127", "0-65535").replace("4,3;2,2;4,2;4",
+                                                  "16,3;4,2;16,2;64")
+
+
+def test_the_16_rank_pairwise_lowers_to_the_parents_arrays(engine):
+    dc = CollectiveSpec("alltoall", "pairwise", RANKS,
+                        RoutedTopology(engine, rank_hosts(engine, 5)),
+                        1e6).build()
+    assert lowering_digest(dc) == PARENT_LOWERING[16]
+
+
+def test_the_320_rank_pairwise_lowers_to_the_parents_arrays(tmp_path):
+    """The benchmark cell's program input: slots numbered by first
+    crossing, pairs in rank order, as the all-pairs walk numbered
+    them."""
+    path = tmp_path / "dfly65k.xml"
+    path.write_text(XML_65K)
+    s4u.Engine._reset()
+    try:
+        e = s4u.Engine(["routed65k",
+                        "--cfg=network/maxmin-selective-update:no",
+                        "--cfg=network/optim:Full"])
+        e.load_platform(str(path))
+        hosts = e.get_all_hosts()
+        before = opstats.snapshot()
+        dc = CollectiveSpec(
+            "alltoall", "pairwise", 320,
+            RoutedTopology(e, [hosts[r * 204] for r in range(320)]),
+            1e6).build()
+        assert opstats.diff(before)["collective_routes"] == 320 * 319
+        assert (dc.n_c, dc.n_v, len(dc.e_var), dc.n_edges) \
+            == (8724, 102080, 1275102, 407040)
+        assert lowering_digest(dc) == PARENT_LOWERING[320]
+    finally:
+        s4u.Engine._reset()
+
+
+# ---------------------------------------------------------------------------
+# recursive doubling on the routed flavor: bursts of R flows
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def rdb128(engine):
+    return CollectiveSpec("allreduce", "rdb", 128,
+                          RoutedTopology(engine, all_ranks(engine)),
+                          8192.0).build()
+
+
+def ran(dc, **kw):
+    before = opstats.snapshot()
+    sim = dc.make_sim(superstep=16, **kw)
+    sim.run()
+    return sim, opstats.diff(before)
+
+
+def test_the_rdb_tape_is_the_host_maestro_in_float64_and_close_in_float32(
+        rdb128):
+    dc = rdb128
+    assert (dc.n_v, dc.n_edges) == (896, 4 * 128 * 6)
+    sim, took = ran(dc)
+    assert sim.dtype == np.float64
+    assert len(sim.events) == len(sim.collective_events) == dc.n_v
+    assert took["collective_tape_fires"] == dc.n_v
+    ma = HostMaestro(dc)
+    ma.run()
+    assert ma.events == sim.events
+    assert ma.collective_events == sim.collective_events
+    assert sim.t == ma.clock[0] == sim.events[-1][0]
+    # a step's 128 flows are on the wire together
+    assert took["collective_live_flow_advances"] > 128
+    low, _ = ran(dc, dtype=np.float32)
+    for got, want in ((low.events, sim.events),
+                      (low.collective_events, sim.collective_events)):
+        t_ref = dict((f, t) for t, f in want)
+        assert len(got) == len(want)
+        assert max(abs(t - t_ref[f]) / t_ref[f] for t, f in got) < DATE_GAP
+        # the same order, but for dates float64 itself tells apart by
+        # an ulp (two ways of adding up to one activation date): float32
+        # may take those either way round
+        high = 0.0
+        for t, f in sorted(got, key=lambda e: (e[0], t_ref[e[1]])):
+            assert t_ref[f] >= high * (1 - 1e-12)
+            high = max(high, t_ref[f])
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32],
+                         ids=["f64", "f32"])
+def test_a_burst_over_the_bottom_rung_falls_back_with_the_index_present(
+        rdb128, dtype, monkeypatch):
+    """The other branch of ``fixpoint``'s ``lax.cond``: an advance that
+    enters with more live elements than the ladder's bottom rung holds
+    takes the full-width entry and the descent, index or no index.
+    With a bottom rung of 120 elements the flows of a step that start
+    on one date are over it in a quarter of the advances and the
+    stragglers under; the events, the activations, the clock, the
+    rounds and the tape's counters are those of the same tape on a
+    ladder whose bottom rung (1,848) fits every burst, where every
+    advance enters from the variable side."""
+    import jax
+    from simgrid_tpu.ops import lmm_jax
+    dc = rdb128
+
+    def under(floor):
+        monkeypatch.setattr(lmm_jax, "_LADDER_MIN_ELEMS", floor)
+        jax.clear_caches()
+        sizes = lmm_jax._ladder_sizes((-(-len(dc.e_var) // 8), 8))
+        sim, took = ran(dc, dtype=dtype)
+        return sizes, sim, took
+
+    try:
+        sizes, fits, took_fits = under(1500)
+        assert sizes == [7392, 3696, 1848]
+        assert took_fits["fixpoint_var_entries"] == fits.advances
+        sizes, burst, took = under(64)
+        assert sizes[-1] == 120 and len(sizes) == 7
+        assert 0 < took["fixpoint_var_entries"] < burst.advances
+    finally:
+        jax.clear_caches()
+    assert burst.events == fits.events
+    assert burst.collective_events == fits.collective_events
+    assert (burst.t, burst.rounds, burst.advances) \
+        == (fits.t, fits.rounds, fits.advances)
+    for k in ("collective_live_flow_advances", "collective_tape_fires",
+              "fixpoint_rounds"):
+        assert took[k] == took_fits[k]
+    # what the burst's rounds indexed is wider than the bottom rung
+    assert took["fixpoint_worked_elem_rounds"] \
+        > sizes[-1] * took["fixpoint_rounds"]

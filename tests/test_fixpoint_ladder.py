@@ -138,6 +138,24 @@ def test_rung_sizes():
             assert above > below >= above / 2 and below % group == 0
 
 
+@pytest.mark.parametrize("shape,want", [
+    # the pairwise alltoall's list and the largest plain ladder
+    ((159388, 8), [1275104, 637552, 318776, 159392, 79696, 39848]),
+    ((1 << 22,), [1 << k for k in range(22, 15, -1)]),
+    # longer: no rung but the list holds more than 2^21 (a copy of the
+    # round that size is four times as dear to compile); the full-machine
+    # allreduce's 9,234,862 elements keep seven of nine rungs
+    ((1154358, 8), [9234864, 1154360, 577184, 288592, 144296, 72152,
+                    36080]),
+    (((1 << 22) + 8,), [4194312, 1048584, 524296, 262152, 131080, 65544,
+                        32776]),
+])
+def test_rung_sizes_of_a_long_list(shape, want):
+    got = lmm_jax._ladder_sizes(shape)
+    assert got == want
+    assert all(size <= lmm_jax._LADDER_TOP_ELEMS for size in got[1:])
+
+
 # ---------------------------------------------------------------------------
 # the laddered solve is the single loop
 # ---------------------------------------------------------------------------
