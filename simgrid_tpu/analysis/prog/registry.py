@@ -147,9 +147,10 @@ def _solo_superstep(scale: int, dtype, tape=False, coll=False):
     if tape:
         kw["tape"] = _tape(n_c)
     if coll:
-        # the sim adds the two inputs only a collective dispatch has:
-        # (v_ptr, ve_idx), its element list's variable-major index
-        # (every other sim passes None for both: no argument at all)
+        # the sim adds the four inputs only a collective dispatch has:
+        # (v_ptr, ve_idx), its element list's variable-major index, and
+        # (s_ptr, s_dst), its DAG's source-major one (every other sim
+        # passes None for them: no argument at all)
         kw["collective"] = _collective(n_v)
         # dormant successors: only the DAG root starts live
         pen = np.zeros(n_v)

@@ -6,6 +6,9 @@ LMM flow slot (variable = record id), its route the element rows, and
 the dependency sets become the (pred-count, successor-edge, exec-cost)
 arrays the superstep while_loop walks autonomously — the full tape
 row of the ISSUE: (pred, src, dst, route-slots, size, exec-cost).
+Beside the edge list (grouped by successor) lies its source-major
+index, from which an advance that finished few flows walks their
+successor edges alone.
 
 Activation protocol (mirrored exactly by maestro.HostMaestro):
 
@@ -47,7 +50,7 @@ class DeviceCollective:
     __slots__ = ("schedule", "topology", "n_v", "n_c", "e_var",
                  "e_cnst", "e_w", "c_bound", "sizes", "penalty0",
                  "pred0", "ready0", "edge_src", "edge_dst", "exec_cost",
-                 "v_ptr", "ve_idx")
+                 "v_ptr", "ve_idx", "_succ")
 
     def __init__(self, schedule: CollectiveSchedule,
                  topology: Topology,
@@ -85,6 +88,8 @@ class DeviceCollective:
                 es, ed = [0], [n_v]
             self.edge_src = np.asarray(es, np.int32)
             self.edge_dst = np.asarray(ed, np.int32)
+            self._succ = None
+            self.succ_index()
 
         # records are in rid order (rid = index), so a transfer's index
         # is its flow slot.  A routed topology looks its routes up HERE
@@ -122,6 +127,21 @@ class DeviceCollective:
     def n_edges(self) -> int:
         return int(np.count_nonzero(self.edge_dst < self.n_v))
 
+    def succ_index(self):
+        """The edge list's source-major index ``(s_ptr, s_dst)``
+        (ops.lmm_drain.succ_index), with which an advance walks the
+        successor edges of its own completions.  One argsort of the
+        edges, kept with the two arrays it was made from: a sim is made
+        every lap, the index again only when ``edge_src`` or
+        ``edge_dst`` is another array than it was (a test that cuts
+        edges out assigns new ones)."""
+        from ..ops.lmm_drain import succ_index
+        if (self._succ is None or self._succ[0] is not self.edge_src
+                or self._succ[1] is not self.edge_dst):
+            self._succ = (self.edge_src, self.edge_dst, succ_index(
+                self.edge_src, self.edge_dst, self.n_v))
+        return self._succ[2]
+
     def drain_args(self):
         """The ``collective=`` 5-tuple for DrainSim/BatchDrainSim."""
         return (self.pred0, self.ready0, self.edge_src, self.edge_dst,
@@ -140,7 +160,8 @@ class DeviceCollective:
                         superstep=superstep, pipeline=pipeline,
                         penalty=self.penalty0, tape=tape,
                         device=device, collective=self.drain_args()
-                        + (self.v_ptr, self.ve_idx), **kw)
+                        + (self.v_ptr, self.ve_idx) + self.succ_index(),
+                        **kw)
 
     def key(self) -> tuple:
         return ("dcoll", self.n_v, self.n_c, self.topology.key(),

@@ -87,8 +87,8 @@ from jax import lax
 from . import opstats
 from .device import default_platform, solve_dtype
 from .lmm_jax import (_MAX_ROUNDS, SolveError, _bucket, _live_elem_rounds,
-                      _pair_add, _pos_group, _stable_livefirst_perm,
-                      fixpoint, var_index)
+                      _owners, _pair_add, _pos_group,
+                      _stable_livefirst_perm, fixpoint, var_index)
 
 
 def _to2d(a: np.ndarray, group: int = 8) -> np.ndarray:
@@ -192,12 +192,74 @@ _FLAG_STALLED = 1     # no flow holds bandwidth (dt not finite)
 _FLAG_BUDGET = 2      # solve hit the round budget mid-superstep
 
 
+#: An advance of a collective tape whose completions own at most this
+#: many successor edges (and number at most this many) decrements the
+#: predecessor counts from those edges alone (:func:`_succ_walk`); one
+#: that owns more walks the whole edge list, as every advance did.  Six
+#: indexed ops this wide sit at the floor of what an op costs on the
+#: chip (PERF.md §5), and 4,096 holds every advance of the pairwise
+#: alltoall (784 at most) and all but a burst's of the allreduce (2,248;
+#: a step's burst owns 157,872-209,400).
+_SRC_WALK_EDGES = 1 << 12
+
+
+def succ_index(edge_src, edge_dst, n_v: int):
+    """The SOURCE-major index of a collective DAG's edge list, on the
+    host: ``(s_ptr[n_v + 1], s_dst[E])``, int32.  Flow ``f``'s
+    successors are ``s_dst[s_ptr[f]:s_ptr[f + 1]]``, in the list's own
+    order; the rows that count for nothing (the pad row, whose successor
+    is the dropped slot ``n_v``) lie behind the last flow's.  One stable
+    argsort of the list: build it once per list, not per sim
+    (``DeviceCollective.succ_index`` keeps it)."""
+    edge_src = np.asarray(edge_src, np.int64)
+    edge_dst = np.asarray(edge_dst, np.int64)
+    counts = ((edge_src >= 0) & (edge_src < n_v)
+              & (edge_dst >= 0) & (edge_dst < n_v))
+    # the edges grouped by source as var_index groups elements by
+    # variable, an edge that counts being an element of weight 1
+    s_ptr, order = var_index(edge_src, counts, n_v)
+    return s_ptr, np.where(counts, edge_dst, n_v)[order].astype(np.int32)
+
+
+def _succ_walk(pred, ring_id, n_ev, n_done, s_ptr, s_dst, width: int):
+    """``pred`` less one for every successor edge of the ``n_done``
+    flows whose slots the completion ring holds at ``[n_ev, n_ev +
+    n_done)``: the tape's DAG walk from the completions' own edges.
+    They own at most ``width`` edges together and are at most ``width``
+    (the caller's test), and nothing here is wider: the edges are
+    expanded from the flows as ``lmm_jax._rung_from_vars`` expands
+    elements from variables (out-degrees, then ``lmm_jax._owners``).
+    The counts are integers and the adds commute, so the edge-wide walk
+    gives the same to the bit."""
+    n_v = pred.shape[0]
+    group = _pos_group(width)
+    # 2D index shapes: the ops/ gather and scatter convention
+    rows = lambda a: a.reshape(width // group, group)
+    at = lax.iota(jnp.int32, width)
+    # a gather, not a dynamic_slice: that clamps its start near the
+    # ring's end and would misalign
+    flow = jnp.where(at < n_done,
+                     jnp.take(ring_id, rows(n_ev + at), mode="clip")
+                     .reshape(-1), n_v)
+    span = jnp.take(s_ptr, flow[:, None] + lax.iota(jnp.int32, 2),
+                    mode="clip")
+    start = span[:, 0]
+    deg = jnp.where(at < n_done, span[:, 1] - start, 0)
+    owner, first, _ = _owners(deg, width)
+    # edge j of completion i sits at s_dst[start[i] + j], and here at
+    # first[i] + j
+    edge = at + jnp.take(start - first, rows(owner)).reshape(-1)
+    dst = jnp.where(rows(at < jnp.sum(deg)),
+                    jnp.take(s_dst, rows(edge), mode="clip"), n_v)
+    return pred.at[dst].add(-1, mode="drop")
+
+
 def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                        thresh, ids, k, round_budget, stop_live, zero_bits,
                        tape_t, tape_slot, tape_val, tape_pos,
                        coll_pred, coll_ready, coll_clk,
                        edge_src, edge_dst, exec_cost, t0,
-                       v_ptr=None, ve_idx=None, *,
+                       v_ptr=None, ve_idx=None, s_ptr=None, s_dst=None, *,
                        eps: float, n_c: int, n_v: int, k_max: int,
                        group: int, has_bounds: bool = False,
                        has_tape: bool = False, has_coll: bool = False):
@@ -272,8 +334,16 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     the tape's list is never repacked, so it stays true): with most
     flows dormant, ``fixpoint`` then enters from the live flows' own
     elements instead of the whole list, and the advances that did are
-    counted into a fourth scalar at the end.  A sim without a
-    collective, and the fleet, pass none.
+    counted into a fourth scalar at the end.  ``(s_ptr, s_dst)`` is
+    the DAG's source-major edge index (:func:`succ_index`): an advance
+    whose completions own at most ``_SRC_WALK_EDGES`` successor edges
+    then decrements ``coll_pred`` from those edges alone
+    (:func:`_succ_walk`) and one that owns more walks the whole edge
+    list, a ``lax.cond`` an advance on a count of its own completions;
+    both give the same counts to the bit, and the advances that took
+    the first side are counted into a fifth scalar.  A sim without a
+    collective, and the fleet (whose ``vmap`` would run both sides),
+    pass neither index.
     """
     # trace-time only: a steady-state superstep loop re-enters the jit
     # cache, so this stays flat; a nonzero delta on a repeat run means
@@ -294,6 +364,11 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         T = tape_t.shape[0]
         t0 = jnp.asarray(t0, jnp.float64)
     index = (v_ptr, ve_idx) if has_coll and v_ptr is not None else None
+    src_index = has_coll and s_ptr is not None
+    if src_index:
+        out_deg = s_ptr[1:] - s_ptr[:-1]
+    # where the loop state holds the pending-activation dates
+    ready_at = 13 + 2 * has_tape + 1
 
     def cond(st):
         pen_c = st[0]
@@ -303,7 +378,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         if has_coll:
             # a dormant flow with a pending activation keeps the loop
             # walking even when nothing currently holds bandwidth
-            alive = alive | jnp.any(jnp.isfinite(st[-4]))
+            alive = alive | jnp.any(jnp.isfinite(st[ready_at]))
         return ((flag == _FLAG_OK) & (adv < k) & (rounds < round_budget)
                 & alive)
 
@@ -318,6 +393,8 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
             cb_c = c_bound
         if has_coll:
             pred_c, ready_c, live_sum, fires, var_entries = st[idx:idx + 5]
+            if src_index:
+                src_walks = st[idx + 5]
         with jax.named_scope("sg.drain.solve"):
             out = fixpoint(e_var, e_cnst, e_w, cb_c, fat, pen_c, v_bound,
                            eps_c, n_c, n_v, parallel_rounds=True,
@@ -429,9 +506,26 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                     # outstanding-predecessor counts; flows reaching zero get
                     # a ready date = completion clock + exec cost (activation
                     # happens on a LATER advance, never the completing one)
-                    pred2 = pred_c.at[edge_dst].add(
-                        -jnp.take(done.astype(jnp.int32), edge_src),
-                        mode="drop")
+                    def edge_walk(pred):
+                        return pred.at[edge_dst].add(
+                            -jnp.take(done.astype(jnp.int32), edge_src),
+                            mode="drop")
+
+                    if src_index:
+                        # the same counts from the completions' own
+                        # edges, when they are few: the test reads no
+                        # index
+                        own = jnp.sum(jnp.where(done, out_deg, 0))
+                        few = ((own <= _SRC_WALK_EDGES)
+                               & (n_done <= _SRC_WALK_EDGES))
+                        pred2 = lax.cond(
+                            few,
+                            lambda pred: _succ_walk(
+                                pred, ring_id2, n_ev, n_done, s_ptr, s_dst,
+                                _SRC_WALK_EDGES),
+                            edge_walk, pred_c)
+                    else:
+                        pred2 = edge_walk(pred_c)
                     newly = (pred2 <= 0) & (pred_c > 0)
                     ready2 = jnp.where(
                         newly, t_new.astype(jnp.float64) + exec_cost, ready2)
@@ -467,6 +561,9 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                                    jnp.where(ok, live_sum2, live_sum),
                                    sel(fires2, fires),
                                    sel(var_entries + out[9], var_entries))
+                if src_index:
+                    out_st = out_st + (
+                        sel(src_walks + few.astype(jnp.int32), src_walks),)
         return out_st
 
     zero = jnp.asarray(0, jnp.int32)
@@ -486,6 +583,8 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     if has_coll:
         st0 = st0 + (coll_pred, coll_ready, jnp.zeros(2, jnp.int32), zero,
                      zero)
+        if src_index:
+            st0 = st0 + (zero,)
     st = lax.while_loop(cond, body, st0)
     (pen_o, rem_o, t_sum, t_comp_o, ring_t, ring_id, adv_dt, adv_nev,
      n_ev, adv, rounds, flag, worked) = st[:13]
@@ -519,8 +618,10 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         parts = [stats, adv_dt, adv_nev.astype(dtype),
                  ring_t, ring_id.astype(dtype)]
         if has_coll:
-            parts.append(jnp.stack([*live_sum, fires,
-                                    var_entries]).astype(dtype))
+            tail = [*live_sum, fires, var_entries]
+            if src_index:
+                tail.append(st[idx + 5])
+            parts.append(jnp.stack(tail).astype(dtype))
         packed = jnp.concatenate(parts)
     return pen_o, rem_o, cb_o, tpos_o, pred_o, ready_o, clk_o, packed
 
@@ -834,8 +935,9 @@ class DrainSim:
             # collective schedule tape: `collective` is (pred, ready,
             # edge_src, edge_dst, exec_cost) — the compiled comm DAG
             # (collectives.tape.DeviceCollective.drain_args()), with
-            # the element list's (v_ptr, ve_idx) behind them where the
-            # caller has it already (``make_sim``).  Dormant
+            # the element list's (v_ptr, ve_idx) and the DAG's (s_ptr,
+            # s_dst) behind them where the caller has them already
+            # (``make_sim``).  Dormant
             # flows (penalty 0) activate on device when their outstanding
             # predecessor count hits zero; the superstep loop walks the
             # whole schedule without host involvement (see
@@ -869,10 +971,15 @@ class DrainSim:
                 # solve finds the live flows' elements without a pass
                 # over the list; DeviceCollective brings its own, built
                 # once per lowered collective
-                index = [np.asarray(a, np.int32) for a in
-                         index or var_index(elems[0], elems[2], self.n_v)]
+                # and the DAG's source-major one, with which an advance
+                # finds its completions' successor edges
+                index = [np.asarray(a, np.int32) for a in (
+                    *(index[:2] or var_index(elems[0], elems[2], self.n_v)),
+                    *(index[2:] or succ_index(ces, ced, self.n_v)))]
                 self._var_index = tuple(jax.device_put(a, device)
-                                        for a in index)
+                                        for a in index[:2])
+                self._succ_index = tuple(jax.device_put(a, device)
+                                         for a in index[2:])
                 #: the carried Kahan pair as the host replays it from a
                 #: dispatch's dt table (see _demux)
                 self._coll_clk_host = (0.0, 0.0)
@@ -892,7 +999,7 @@ class DrainSim:
                 self._coll_clk = jax.device_put(np.zeros(2, np.float64),
                                                 device)
                 self._coll_total = 0
-                self._var_index = (None, None)
+                self._var_index = self._succ_index = (None, None)
 
             opstats.bump("uploaded_bytes_full",
                          pen0.nbytes + rem0.nbytes + thresh.nbytes
@@ -1105,9 +1212,10 @@ class DrainSim:
                 np.int32(k), np.int32(budget), np.int32(want_stop),
                 _ZERO_BITS, *self._tape, tpos_in,
                 pred_in, ready_in, clk_in, *self._coll_edges, t0_in,
-                *self._var_index, eps=self.eps, n_c=self.n_c, n_v=self.n_v,
-                k_max=k_max, group=group, has_bounds=self.has_bounds,
-                has_tape=self.has_tape, has_coll=self.has_coll)
+                *self._var_index, *self._succ_index, eps=self.eps,
+                n_c=self.n_c, n_v=self.n_v, k_max=k_max, group=group,
+                has_bounds=self.has_bounds, has_tape=self.has_tape,
+                has_coll=self.has_coll)
         self.supersteps += 1
         opstats.bump("dispatches")
         if speculative:
@@ -1172,9 +1280,10 @@ class DrainSim:
             if self.has_coll:
                 # the tape's own counts ride the tail of the same fetch
                 opstats.bump("collective_live_flow_advances",
-                             _live_elem_rounds(p[-4:-2]))
-                opstats.bump("collective_tape_fires", int(p[-2]))
-                opstats.bump("fixpoint_var_entries", int(p[-1]))
+                             _live_elem_rounds(p[-5:-3]))
+                opstats.bump("collective_tape_fires", int(p[-3]))
+                opstats.bump("fixpoint_var_entries", int(p[-2]))
+                opstats.bump("collective_src_walks", int(p[-1]))
             with opstats.span("drain.demux"):
                 batches, fired = self._demux(p, adv, tok.k_max, t_sum)
 

@@ -1014,6 +1014,23 @@ def var_index(e_var, e_w, n_v: int):
     return v_ptr, ve_idx
 
 
+def _owners(deg, n_keep: int):
+    """``n_keep`` positions handed out to owners in order, ``deg[i]`` of
+    them to owner ``i`` (at most ``n_keep`` in all): ``(owner[n_keep],
+    first, end)``, owner ``i`` holding positions ``[first[i], end[i])``.
+    One scatter as wide as ``deg`` marks where each owner's run starts
+    and a running max hands every position its owner; the positions
+    past the last run get the last owner's (mask them by ``end[-1]``)."""
+    n = deg.shape[0]
+    end = jnp.cumsum(deg)
+    first = end - deg
+    group = _pos_group(n)
+    marks = jnp.zeros(n_keep, jnp.int32).at[
+        jnp.where(deg > 0, first, n_keep).reshape(n // group, group)].set(
+        lax.iota(jnp.int32, n).reshape(n // group, group) + 1, mode="drop")
+    return jnp.maximum(lax.cummax(marks) - 1, 0), first, end
+
+
 def _rung_from_vars(v_ptr, ve_idx, deg, e_var, e_cnst, e_w, n_keep: int):
     """The ``n_keep``-element rung of the ladder as the stable live-first
     partition of the whole list would leave it — the live elements in
@@ -1031,15 +1048,9 @@ def _rung_from_vars(v_ptr, ve_idx, deg, e_var, e_cnst, e_w, n_keep: int):
     that owns it).  Positions come out ascending
     when the list is variable-major (as the collective tape lowers it);
     another list pays one ``n_keep``-wide sort."""
-    n_v = deg.shape[0]
     shape = _head(e_var, n_keep).shape
-    end = jnp.cumsum(deg)
-    first = end - deg
-    group = _pos_group(n_v)
-    marks = jnp.zeros(n_keep, jnp.int32).at[
-        jnp.where(deg > 0, first, n_keep).reshape(-1, group)].set(
-        lax.iota(jnp.int32, n_v).reshape(-1, group) + 1, mode="drop")
-    owner = jnp.maximum(lax.cummax(marks) - 1, 0).reshape(shape)
+    owner, first, end = _owners(deg, n_keep)
+    owner = owner.reshape(shape)
     at = lax.iota(jnp.int32, n_keep).reshape(shape)
     inside = at < end[-1]
     # element j of variable v sits at ve_idx[v_ptr[v] + j], and in the

@@ -54,6 +54,17 @@ context object through the solver entry points:
                               at the tail of the packed vector.  Over
                               the advances: the share that paid a
                               live-width entry, not a full-width one
+* ``collective_src_walks``  — committed advances of a collective
+                              tape's superstep that walked the DAG from
+                              the source side: their completions owned
+                              at most ``lmm_drain._SRC_WALK_EDGES``
+                              successor edges, so the predecessor
+                              counts were decremented from those edges
+                              alone (the DAG's source-major index) and
+                              no op ran as wide as the edge list; a
+                              fifth scalar at the tail of the packed
+                              vector.  Over the advances: the share
+                              that did not pay the edge-wide walk
 * ``uploaded_bytes_full``   — host->device bytes shipped as whole
                               arrays (fresh ``device_put``)
 * ``uploaded_bytes_delta``  — host->device bytes shipped as indexed
