@@ -71,6 +71,9 @@ class Run:
         #: the program's counters (``opstats``) over the window
         self.counters: Dict[str, float] = {}
         self.trace = None
+        #: device self time by the program's ``sg.*`` names
+        #: (``scopes.DeviceScopes``), once a traced window is reduced
+        self.scopes = None
         self.setup_s = float("nan")
         self._window_note = None
 
@@ -124,8 +127,18 @@ def measure(run: Run, state) -> Dict[str, Any]:
 
 
 def reduce_trace(run: Run) -> None:
-    from . import trace
-    run.trace = trace.summarize(run.trace_dir, chips=len(run.devices))
+    """``run.trace``, and ``run.scopes`` from what only the raw file
+    holds (the op-name paths), before the raw file goes."""
+    from . import scopes, trace, xmeta
+    path = trace.find_xplane(run.trace_dir)
+    t1 = time.perf_counter()
+    run.trace = trace.TraceSummary(trace.read_xplane(path),
+                                   len(run.devices))
+    t2 = time.perf_counter()
+    run.scopes = scopes.device_scopes(xmeta.read(path), run.trace)
+    note(run, f"trace of {os.path.getsize(path) / 1e6:.1f} MB parsed: "
+              f"events {t2 - t1:.2f} s, op-name paths "
+              f"{time.perf_counter() - t2:.2f} s")
     shutil.rmtree(run.trace_dir, ignore_errors=True)
 
 
@@ -182,8 +195,9 @@ def execute(workload: str, seed: int, seconds: float, trace: bool,
     if trace:
         result["device"]["busy_s"] = run.trace.busy_s
         result["device"]["window_s"] = run.trace.window_s
-        result["breakdown"] = {"device_ops": run.trace.top_ops(10),
-                               "idle_gaps": run.trace.top_gaps(10)}
+        from . import scopes
+        result["breakdown"] = {"device_ops": scopes.top_ops(run, 10),
+                               "idle_gaps": scopes.top_gaps(run.trace, 10)}
     result["compared"] = compared.as_dict()
     for line in compared.lines():
         print(line, file=sys.stderr, flush=True)

@@ -9,10 +9,13 @@ the profiler's host timeline).  Two reductions of one traced window:
   its body, as ``trace.self_times``) per compiled program and innermost
   ``sg.*`` scope of each op's op-name path, ``unscoped`` for ops whose
   path has none (loop plumbing, the copies XLA inserts).  Needs the
-  metadata ``lib/xmeta.py`` decodes from the raw ``.xplane.pb``.
-* :func:`idle_by_span` - the device's idle time per innermost ``sg:``
-  host span covering it, ``unnamed`` for the rest.  Needs only what
-  ``lib/trace.py`` already read.
+  metadata ``lib/xmeta.py`` decodes from the raw ``.xplane.pb``; the
+  harness keeps it as ``run.scopes`` before the raw file goes, and
+  :func:`pass_ms` reads a pass's milliseconds a unit of work from it.
+* :func:`idle_by_span` - the device's idle time per innermost host
+  span covering it, the program's ``sg:`` spans and the benchmark's
+  ``bench:`` ones under one nesting rule, ``unannotated`` for the
+  rest.  Needs only what ``lib/trace.py`` already read.
 
 Both work on the window and the chips of a ``trace.TraceSummary``
 (``lo``, ``hi``, ``devices``, ``planes``).  :func:`program_spans` reads
@@ -26,13 +29,22 @@ from bisect import bisect_right
 from typing import Dict, Iterable, List, Optional, Tuple
 
 from . import trace, xmeta
+from .spans import PREFIX as BENCH_PREFIX
 
 #: what an op-name path component / a host annotation of the program
 #: starts with
 DEVICE_PREFIX = "sg."
 HOST_PREFIX = "sg:"
 UNSCOPED = "unscoped"
-UNNAMED = "unnamed"
+UNNAMED = "unannotated"
+
+#: the compiled programs the cells drive, as XLA names them, and the
+#: key of a driver's record that counts the unit of work each pays for
+SUPERSTEP = ("jit__superstep_program", "advances")
+SOLVE_CHUNK = ("jit__solve_kernel_chunk", "solves")
+#: the four passes of one saturation round
+ROUND = tuple("sg.lmm." + p for p in ("neighmin", "level", "update",
+                                      "prune"))
 
 Segment = Tuple[int, int, str]        # start_ns, end_ns, name
 
@@ -135,26 +147,29 @@ def innermost_segments(spans: Iterable[trace.Event]) -> List[Segment]:
     return out
 
 
-def host_spans(summary: trace.TraceSummary) -> List[trace.Event]:
-    """The program's ``sg:`` annotations on the host planes."""
+def host_spans(summary: trace.TraceSummary,
+               prefixes: Tuple[str, ...] = (HOST_PREFIX,)
+               ) -> List[trace.Event]:
+    """The annotations on the host planes named like ``prefixes`` (the
+    program's ``sg:`` spans alone, unless told otherwise), less the
+    benchmark's mark of the window itself."""
     return [ev for plane, lines in summary.planes.items()
             if not trace.is_device_plane(plane)
             for events in lines.values() for ev in events
-            if ev[0].startswith(HOST_PREFIX)]
+            if ev[0].startswith(prefixes) and ev[0] != trace.WINDOW]
 
 
-def idle_by_span(summary: trace.TraceSummary) -> Dict[str, int]:
+def idle_by_span(summary: trace.TraceSummary,
+                 prefixes: Tuple[str, ...] = (HOST_PREFIX,)
+                 ) -> Dict[str, int]:
     """Idle nanoseconds of the first chip inside the window by the
-    innermost ``sg:`` span open at the time (the prefix dropped), and
-    ``unnamed`` where none was: all of it, for a program that opens no
-    span."""
-    spans = host_spans(summary)
-    device = summary.planes[summary.devices[0]]
-    busy = trace.clip(trace.union((a, b) for _n, a, b
-                                  in device[trace.OPS_LINE]),
+    innermost span (of those named like ``prefixes``) open at the time,
+    under its annotation's whole name, and ``unannotated`` where none
+    was: all of it, for a program that opens no span.  The values add
+    up to the chip's idle time, to the nanosecond."""
+    idle = trace.gaps(summary.busy[summary.devices[0]],
                       summary.lo, summary.hi)
-    idle = trace.gaps(busy, summary.lo, summary.hi)
-    segments = innermost_segments(spans)
+    segments = innermost_segments(host_spans(summary, prefixes))
     out: Dict[str, int] = {}
     named = i = 0
     for a, b in idle:
@@ -165,12 +180,54 @@ def idle_by_span(summary: trace.TraceSummary) -> Dict[str, int]:
             sa, sb, name = segments[j]
             cover = min(b, sb) - max(a, sa)
             if cover > 0:
-                key = name[len(HOST_PREFIX):]
-                out[key] = out.get(key, 0) + cover
+                out[name] = out.get(name, 0) + cover
                 named += cover
             j += 1
     out[UNNAMED] = trace.total(idle) - named
     return out
+
+
+def top_gaps(summary: trace.TraceSummary, n: Optional[int] = 10
+             ) -> List[List]:
+    """``breakdown.idle_gaps``: [name, idle seconds], the largest
+    first, by the innermost of the program's AND the benchmark's spans;
+    a ``bench:`` name bare (``lap.events``), a program's with its
+    prefix (``sg:drain.demux``)."""
+    by = idle_by_span(summary, (BENCH_PREFIX, HOST_PREFIX))
+    top = sorted(((name, ns) for name, ns in by.items() if ns),
+                 key=lambda kv: -kv[1])[:n]
+    return [[name[len(BENCH_PREFIX):] if name.startswith(BENCH_PREFIX)
+             else name, ns / 1e9] for name, ns in top]
+
+
+def top_ops(run, n: int = 10) -> List[List]:
+    """``breakdown.device_ops``: [name, self seconds], the largest
+    first, each op behind its innermost scope (``sg.drain.ring
+    %fusion.560 f32[204160] fusion``); as XLA names it alone where the
+    trace carries no ``sg.`` name or was not decoded."""
+    got = run.scopes
+    if got is None or all(scope == UNSCOPED for _program, scope in got.by):
+        return run.trace.top_ops(n)
+    return [[f"{scope} {op}"[:80], s]
+            for _program, scope, op, s in got.top_ops(n)]
+
+
+def pass_ms(run, program: Tuple[str, str], *names: str
+            ) -> Optional[float]:
+    """Device self milliseconds of ``program`` (its name's needle and
+    the record key of its unit of work) under the scopes ``names``,
+    over the window's units: PERF.md section 5's columns.  A pass that
+    never ran reads 0; nothing to read where the raw trace was not
+    decoded, the program carries no ``sg.`` name at all or the window
+    finished no unit."""
+    needle, unit = program
+    units = run.record.get(unit)
+    if run.scopes is None or not units:
+        return None
+    by = run.scopes.scopes(needle)
+    if not any(scope.startswith(DEVICE_PREFIX) for scope in by):
+        return None
+    return 1e3 * sum(by.get(name, 0.0) for name in names) / units
 
 
 def program_spans(run, name: str, in_window: bool

@@ -1,6 +1,7 @@
 """From the profiler's ``.xplane.pb`` to numbers: device busy time, the
-device time of each compiled program, the operations that took most
-time, and the idle gaps named by the benchmark's own annotations.
+device time of each compiled program and the operations that took most
+time (``lib/scopes.py`` names both, and the idle gaps, as the program
+does).
 
 Read with nothing but JAX (``jax.profiler.ProfileData``).  Everything
 below works on plain ``(name, start_ns, end_ns)`` tuples, so the
@@ -118,16 +119,6 @@ def short_op(name: str) -> str:
                                 kind.group(1) if kind else "") if x)[:80]
 
 
-def name_gap(gap: Tuple[int, int], notes: Sequence[Event]) -> str:
-    """The innermost (shortest) annotation that covers most of a gap."""
-    best, key = "unannotated", (0, 0)
-    for name, a, b in notes:
-        cover = min(b, gap[1]) - max(a, gap[0])
-        if cover > 0 and (cover, -(b - a)) > key:
-            best, key = name, (cover, -(b - a))
-    return best
-
-
 class TraceSummary:
     """One traced window, reduced."""
 
@@ -152,10 +143,12 @@ class TraceSummary:
             self.lo = min(e[1] for e in ops)
             self.hi = max(e[2] for e in ops)
         self.window_s = (self.hi - self.lo) / 1e9
-        self._busy = {d: clip(union((a, b) for _n, a, b
-                                    in planes[d][OPS_LINE]),
-                              self.lo, self.hi) for d in self.devices}
-        self.busy_s = sum(total(b) for b in self._busy.values()) \
+        #: chip -> the merged intervals, cut to the window, in which an
+        #: operation ran on it
+        self.busy = {d: clip(union((a, b) for _n, a, b
+                                   in planes[d][OPS_LINE]),
+                             self.lo, self.hi) for d in self.devices}
+        self.busy_s = sum(total(b) for b in self.busy.values()) \
             / 1e9 / len(self.devices)
 
     def module_seconds(self, needle: str) -> Tuple[float, int]:
@@ -180,18 +173,3 @@ class TraceSummary:
         top = sorted(own.items(), key=lambda kv: -kv[1])[:n]
         return [[short_op(name), ns / 1e9 / len(self.devices)]
                 for name, ns in top]
-
-    def top_gaps(self, n: int = 10) -> List[List]:
-        """Idle seconds of the first chip by what the host was doing."""
-        notes = [ev for ev in self.notes if ev[0] != WINDOW]
-        by: Dict[str, int] = {}
-        for gap in gaps(self._busy[self.devices[0]], self.lo, self.hi):
-            name = name_gap(gap, notes)
-            by[name] = by.get(name, 0) + gap[1] - gap[0]
-        top = sorted(by.items(), key=lambda kv: -kv[1])[:n]
-        return [[name[len(PREFIX):] if name.startswith(PREFIX) else name,
-                 ns / 1e9] for name, ns in top]
-
-
-def summarize(trace_dir: str, chips: int = 1) -> TraceSummary:
-    return TraceSummary(read_xplane(find_xplane(trace_dir)), chips)

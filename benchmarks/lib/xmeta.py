@@ -145,16 +145,58 @@ class Plane:
             out: List[Op] = []
             for timestamp_ns, events in self._raw.get(line, ()):
                 for buf_e in events:
-                    e = dict_of(buf_e)
-                    start = int(timestamp_ns
-                                + signed(e.get(2, 0)) / 1000.0)
-                    out.append(Op(e.get(1, 0), start, start + int(
-                        signed(e.get(3, 0)) / 1000.0)))
+                    ident, offset_ps, duration_ps = event(buf_e)
+                    start = int(timestamp_ns + signed(offset_ps) / 1000.0)
+                    out.append(Op(ident, start, start + int(
+                        signed(duration_ps) / 1000.0)))
             self._ops[line] = out
         return self._ops[line]
 
     def stat_of(self, metadata_id: int, name: str, default=None):
         return self.stats.get(metadata_id, {}).get(name, default)
+
+
+def event(buf: memoryview) -> Tuple[int, int, int]:
+    """(metadata_id, offset_ps, duration_ps) of one XEvent, as
+    ``dict_of`` would give fields 1 to 3 (the last value of each, 0
+    for one left out).  A window's trace holds millions of events and
+    this is all the reduction reads of them, so it is decoded in one
+    loop over the bytes, not through ``fields``: a third of the time
+    (the pairwise cell's 220 MB trace took 31.5 s the slow way)."""
+    raw = bytes(buf)
+    got = [0, 0, 0, 0]
+    at, end = 0, len(raw)
+    while at < end:
+        key = raw[at]
+        at += 1
+        if key >= 0x80:                 # a field number over 15
+            return tuple(dict_of(buf).get(n, 0) for n in (1, 2, 3))
+        wire = key & 7
+        if wire == VARINT or wire == BYTES:
+            value = raw[at]
+            at += 1
+            if value >= 0x80:
+                value &= 0x7F
+                shift = 7
+                while True:
+                    byte = raw[at]
+                    at += 1
+                    value |= (byte & 0x7F) << shift
+                    if byte < 0x80:
+                        break
+                    shift += 7
+            if wire == BYTES:
+                at += value
+            elif key >> 3 <= 3:
+                got[key >> 3] = value
+        elif wire == FIXED64:
+            at += 8
+        elif wire == FIXED32:
+            at += 4
+        else:
+            raise ValueError(f"wire type {wire} in an XEvent: not an "
+                             f"xplane.pb, or a newer encoding")
+    return got[1], got[2], got[3]
 
 
 def map_value(entry: memoryview) -> memoryview:

@@ -4,12 +4,11 @@ geometry, on the CPU: 128 ranks, one a host, on the 128-host dragonfly,
 draws, the reference's own graph against the program's, the harness end
 to end against the reference, each control of its ``correct`` (the
 reference in bfloat16, and the faults a collective tape can have), the
-refusal of a program that routes all pairs, and the four readers the
-cell brings."""
+refusal of a program that routes all pairs, and the readers the cell
+brings (its two shares of a burst are ``drain.var_entry_pct``'s and
+``drain.worked_elem_pct``'s: ``test_var_entry.py``,
+``test_ladder_metrics.py``)."""
 
-import os
-import runpy
-import sys
 import time
 import types
 
@@ -280,31 +279,6 @@ def handmade(counters, advances=64, rounds=96,
         spans=types.SimpleNamespace(window_from=float("inf")))
 
 
-def test_wide_entry_pct_is_the_advances_that_did_not_enter_by_variable():
-    from simgrid_tpu.ops import opstats
-    read = reader("coll.wide_entry_pct")
-    opstats.reset()
-    # a program without the counter: left out, never 0 or 100
-    assert read(handmade({})) is None
-    opstats.bump("fixpoint_var_entries", 0)
-    assert read(handmade({})) == 100.0       # counted, and none entered so
-    assert read(handmade({"fixpoint_var_entries": 46})) \
-        == pytest.approx(100 * 18 / 64)
-    assert read(handmade({"fixpoint_var_entries": 1}, advances=0)) is None
-    opstats.reset()
-
-
-def test_worked_elem_pct_is_over_the_unpadded_list():
-    read = reader("coll.worked_elem_pct")
-    run = handmade({"fixpoint_worked_elem_rounds": 96 * 36080})
-    assert read(run) == pytest.approx(100 * 36080 / 9234862)
-    assert read(handmade({})) is None
-    assert read(handmade({"fixpoint_worked_elem_rounds": 5},
-                         rounds=0)) is None
-    run.shape = None
-    assert read(run) is None
-
-
 def test_the_two_set_up_readers_tell_the_spans_apart_by_id():
     from simgrid_tpu.ops import opstats
     sched, route = reader("coll.schedule_s"), reader("coll.route_us_per_pair")
@@ -331,9 +305,7 @@ def test_the_tiny_cell_reads_them_through_the_harness(monkeypatch):
     """A step's bursts are small beside the tiny ladder's one rung, so
     nothing enters from the variable side here; the readers still
     read.  BENCHMARK.json lists the cell wherever the pairwise cell is
-    listed, but for the two lists a test pins; the four readers above
-    wait beside it (``tools/passes_allreduce.py`` prints them):
-    ``test_var_entry.py`` holds the manifest's LAST per-layer entry."""
+    listed, and for the two parts of its ``coll.lower`` besides."""
     manifest = mf.load_manifest()
     tiny.patch(monkeypatch)
     from lib import harness
@@ -344,8 +316,8 @@ def test_the_tiny_cell_reads_them_through_the_harness(monkeypatch):
     tiny.execute(CELL)
     run = seen["run"]
     assert run.shape == (391, 896, 7392)
-    assert 0.0 <= reader("coll.wide_entry_pct")(run) <= 100.0
-    assert reader("coll.worked_elem_pct")(run) >= 100.0
+    assert reader("drain.var_entry_pct")(run) == 0.0
+    assert reader("drain.worked_elem_pct")(run) >= 100.0
     assert reader("coll.schedule_s")(run) > 0
     assert 0 < reader("coll.route_us_per_pair")(run) < 1e4
     assert reader("coll.lower_s")(run) > reader("coll.schedule_s")(run)
@@ -356,35 +328,21 @@ def test_the_tiny_cell_reads_them_through_the_harness(monkeypatch):
         manifest, "dfly65k-allreduce.drain").per_layer()}
     pairwise = {m["name"] for m in mf.Cell(
         manifest, "dfly65k-pairwise.drain").per_layer()}
-    assert pairwise - ours == {"drain.var_entry_pct"} and ours <= pairwise
-    assert manifest["per_layer"][-1]["name"] == "drain.var_entry_pct"
-    sys.path.insert(0, os.path.join(mf.BENCH, "tools"))
-    import passes_allreduce
-    assert all(os.path.isfile(os.path.join(mf.BENCH, "metrics", n + ".py"))
-               for n in passes_allreduce.READERS)
-    assert not set(passes_allreduce.READERS) & {
-        m["name"] for m in manifest["per_layer"]}
+    assert ours - pairwise == {"coll.schedule_s", "coll.route_us_per_pair"}
+    assert pairwise <= ours
 
 
-def test_the_passes_tool_knows_the_driver():
-    sys.path.insert(0, os.path.join(mf.BENCH, "tools"))
-    import passes
-    assert "coll_allreduce" not in passes.PROGRAMS
-    monkey = pytest.MonkeyPatch()
-    monkey.setattr(passes, "main", lambda: 0)
-    monkey.setattr(passes, "breakdown", passes.breakdown)   # it wraps it
-    try:
-        with pytest.raises(SystemExit):
-            runpy.run_path(os.path.join(mf.BENCH, "tools",
-                                        "passes_allreduce.py"),
-                           run_name="__main__")
-        assert passes.PROGRAMS["coll_allreduce"] == passes.PROGRAMS["drain"]
-        out = passes.breakdown(types.SimpleNamespace(
-            cell=types.SimpleNamespace(traffic={"driver": "coll_allreduce"}),
-            counters={}, record={}), types.SimpleNamespace(
-                scopes=lambda needle: {}))
-        assert out is None
-        assert passes.breakdown.__name__ == "breakdown_with_readers"
-    finally:
-        monkey.undo()
-        passes.PROGRAMS.pop("coll_allreduce", None)
+def test_a_traced_run_reports_every_metric_the_manifest_lists(monkeypatch):
+    """``coll_allreduce`` drives ``drain``'s compiled program too: on
+    the recorded drain's trace the six columns of an advance read (the
+    tape's 0: it never ran there), with the window's own counters."""
+    tiny.patch(monkeypatch)
+    tiny.traced(monkeypatch)
+    result = tiny.execute(CELL, trace=True)
+    assert set(result["metrics"]) == {m["name"] for m in mf.Cell(
+        tiny.tiny_manifest(), CELL).per_layer()}
+    got = {name: m["value"] for name, m in result["metrics"].items()}
+    assert got["drain.coll_ms"] == got["drain.partition_ms"] == 0.0
+    assert got["drain.solve_init_ms"] > 0 and got["drain.retire_ms"] > 0
+    assert got["drain.init_ms"] > 0 and got["coll.src_walk_pct"] == 100.0
+    assert "drain.advance_ms" not in got and "drain.upload_ms" not in got

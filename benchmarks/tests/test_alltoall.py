@@ -214,13 +214,28 @@ def test_the_tiny_solve_reports_the_three(monkeypatch):
     assert reader("solve.bound_rounds")(run) == 0.0
 
 
-def test_the_passes_tool_reads_the_driver_as_its_entry_point():
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(mf.BENCH, "tools"))
-    import passes
-    import passes_by_entry
-    assert "solve_alltoall" not in passes.PROGRAMS
-    passes_by_entry.register()
-    assert passes.PROGRAMS["solve_alltoall"] == passes.PROGRAMS["solve"]
-    assert set(passes.PROGRAMS) == {"drain", "solve", "solve_alltoall"}
+def test_a_traced_run_reads_the_solves_passes_by_the_programs_name(
+        monkeypatch):
+    """The pass readers go by the compiled program's name and the
+    record's ``solves``, not by the driver's file name: this cell's
+    ``solve_alltoall`` reads what ``solve`` reads.  (The recorded trace
+    is a drain's, so the chunk program's scopes are made up.)"""
+    from lib import harness, scopes
+    tiny.patch(monkeypatch)
+    tiny.traced(monkeypatch)
+    chunk = scopes.SOLVE_CHUNK[0] + "(3)"
+    made_up = scopes.DeviceScopes({
+        (chunk, "sg.lmm.init", "%a"): 0.5, (chunk, "unscoped", "%w"): 0.25,
+        (chunk, "sg.lmm.level", "%b"): 2.0, (chunk, "sg.lmm.prune", "%c"): 1.0,
+        (chunk, "sg.lmm.partition", "%d"): 0.125})
+    real = harness.reduce_trace
+    monkeypatch.setattr(harness, "reduce_trace", lambda run: (
+        real(run), setattr(run, "scopes", made_up))[0])
+    result = tiny.execute(CELL, trace=True)
+    solves = result["attempted"]
+    assert solves >= 2
+    got = {name: m["value"] for name, m in result["metrics"].items()}
+    assert got["solve.init_ms"] == pytest.approx(750.0 / solves)
+    assert got["solve.rounds_ms"] == pytest.approx(3000.0 / solves)
+    assert got["solve.partition_ms"] == pytest.approx(125.0 / solves)
+    assert result["breakdown"]["device_ops"][0] == ["sg.lmm.level %b", 2.0]

@@ -60,15 +60,31 @@ def test_the_manifest_lists_them_for_their_cells():
     assert by["solve.worked_elem_pct"]["workloads"] == solves
     assert by["solve.partitions_per_solve"]["workloads"] == solves
     assert by["drain.worked_elem_pct"]["workloads"] == [
-        "dfly65k-random.drain"]
+        "dfly65k-random.drain", "dfly65k-pairwise.drain",
+        "dfly65k-allreduce.drain"]
     for name in ("solve.worked_elem_pct", "drain.worked_elem_pct"):
         assert by[name]["better"] == "lower" and by[name]["unit"] == "%"
+
+
+def test_a_tapes_rounds_run_on_the_rung_their_advance_stopped_at():
+    """The allreduce cell's sizes: 96 rounds on the bottom rung are a
+    third of a per cent of rounds x the UNPADDED 9.2 M elements."""
+    read = reader("drain.worked_elem_pct")
+    shape = (143993, 1048576, 9234862)
+    run = handmade({"fixpoint_rounds": 96,
+                    "fixpoint_worked_elem_rounds": 96 * 36080}, shape=shape)
+    assert read(run) == pytest.approx(100 * 36080 / 9234862)
+    assert read(handmade({"fixpoint_rounds": 96}, shape=shape)) is None
+    assert read(handmade({"fixpoint_worked_elem_rounds": 5},
+                         shape=shape)) is None          # no round counted
 
 
 @pytest.mark.parametrize("cell,name", [
     ("tiny128-random.solve", "solve.worked_elem_pct"),
     ("tiny128-alltoall.solve", "solve.worked_elem_pct"),
-    ("tiny128-random.drain", "drain.worked_elem_pct")])
+    ("tiny128-random.drain", "drain.worked_elem_pct"),
+    ("tiny128-pairwise.drain", "drain.worked_elem_pct"),
+    ("tiny128-allreduce.drain", "drain.worked_elem_pct")])
 def test_under_the_floor_a_round_indexes_the_whole_list_it_is_given(
         cell, name, monkeypatch):
     """The superstep's list is the system's, padded to rows of 8.  On

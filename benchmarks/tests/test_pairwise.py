@@ -302,22 +302,24 @@ def test_the_tiny_cell_reports_the_four(monkeypatch):
     assert reader("drain.worked_elem_pct")(run) > 0
 
 
-def test_the_passes_tool_knows_the_driver():
-    import os
-    import runpy
-    import sys
-    sys.path.insert(0, os.path.join(mf.BENCH, "tools"))
-    import passes
-    assert "coll_drain" not in passes.PROGRAMS
-    monkey = pytest.MonkeyPatch()
-    monkey.setattr(passes, "main", lambda: 0)
-    monkey.setattr(passes, "breakdown", passes.breakdown)   # it wraps it
-    try:
-        with pytest.raises(SystemExit):
-            runpy.run_path(os.path.join(mf.BENCH, "tools",
-                                        "passes_coll.py"),
-                           run_name="__main__")
-        assert passes.PROGRAMS["coll_drain"] == passes.PROGRAMS["drain"]
-    finally:
-        monkey.undo()
-        passes.PROGRAMS.pop("coll_drain", None)
+def test_a_traced_run_reads_the_supersteps_passes(monkeypatch):
+    """``coll_drain`` drives ``drain``'s compiled program: the pass
+    readers find it by ITS name and the record's ``advances``.  The
+    recorded trace is a drain's without a tape, so the tape's pass
+    reads 0 and the five others add up to the program's time."""
+    from lib import scopes
+    tiny.patch(monkeypatch)
+    tiny.traced(monkeypatch)
+    result = tiny.execute(CELL, trace=True)
+    got = {name: m["value"] for name, m in result["metrics"].items()}
+    columns = ["drain.solve_init_ms", "drain.rounds_ms",
+               "drain.partition_ms", "drain.coll_ms", "drain.ring_ms",
+               "drain.retire_ms"]
+    assert got["drain.coll_ms"] == 0.0 and got["drain.ring_ms"] > 0
+    # the recorded superstep: 41.61 of its 43.03 ms inside the rounds
+    assert got["drain.rounds_ms"] / sum(got[c] for c in columns) \
+        == pytest.approx(0.967, abs=1e-3)
+    assert got["coll.src_walk_pct"] == 100.0
+    assert {n for n, _s in result["breakdown"]["idle_gaps"]} >= {
+        "sg:drain.init", "sg:fetch"}
+    assert scopes.SUPERSTEP == ("jit__superstep_program", "advances")

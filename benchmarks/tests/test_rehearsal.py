@@ -2,7 +2,6 @@
 arithmetic: trace reduction, roofline bytes, traffic, the reference."""
 
 import json
-import lzma
 import os
 import subprocess
 import sys
@@ -13,12 +12,10 @@ import pytest
 
 import tiny
 from configs import dragonfly_lv08 as ref
-from lib import manifest as mf, roofline, trace, traffic
+from lib import manifest as mf, roofline, scopes, trace, traffic
 from lib.compare import events_gap, rate_gap
 
 CELLS = [w["name"] for w in tiny.tiny_manifest()["workloads"]]
-FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
-                       "tiny_drain.xplane.pb.xz")
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device",
                "compared"]
 
@@ -27,10 +24,8 @@ RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device",
 def recorded(tmp_path_factory):
     """The small trace recorded on the v5e (one superstep dispatch of
     the 128-host drain under ``bench:lap`` / ``bench:run``)."""
-    path = tmp_path_factory.mktemp("trace") / "tiny.xplane.pb"
-    with lzma.open(FIXTURE) as f:
-        path.write_bytes(f.read())
-    return trace.TraceSummary(trace.read_xplane(str(path)))
+    return trace.TraceSummary(trace.read_xplane(tiny.recorded(
+        "tiny_drain", tmp_path_factory.mktemp("trace"))))
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -53,15 +48,9 @@ def test_driver_end_to_end_prints_the_contracts_keys(cell, monkeypatch):
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_traced_run_reports_per_layer_metrics(cell, monkeypatch, recorded):
+def test_traced_run_reports_per_layer_metrics(cell, monkeypatch, capfd):
     tiny.patch(monkeypatch)
-    from lib import harness
-    monkeypatch.setattr(harness.jax_profiler(), "start_trace",
-                        lambda *a, **k: None)
-    monkeypatch.setattr(harness.jax_profiler(), "stop_trace", lambda: None)
-    monkeypatch.setattr(harness.shutil, "rmtree", lambda *a, **k: None)
-    monkeypatch.setattr(harness, "reduce_trace",
-                        lambda run: setattr(run, "trace", recorded))
+    tiny.traced(monkeypatch)
     result = tiny.execute(cell, trace=True)
     assert list(result) == RESULT_KEYS[:5] + ["breakdown", "compared"]
     assert result["device"]["busy_s"] > 0
@@ -70,10 +59,23 @@ def test_traced_run_reports_per_layer_metrics(cell, monkeypatch, recorded):
                                             cell).per_layer()}
     assert set(result["metrics"]) <= per_layer
     assert "flatten_s" in result["metrics"]
-    if cell.endswith(".drain"):       # the recorded trace is a drain's
+    if cell.endswith(".drain"):
+        # the recorded trace is a drain's, with the program's names in
+        # it: every metric of the cell reads, a tape cell's too (the
+        # tape's scope never ran in it: 0 ms)
         assert set(result["metrics"]) == per_layer
+        assert result["metrics"]["drain.rounds_ms"]["value"] > 0
+    else:
+        assert not {"solve.init_ms", "solve.rounds_ms",
+                    "solve.partition_ms"} & set(result["metrics"])
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
-    assert 1 <= len(result["breakdown"]["device_ops"]) <= 10
+    for rows in result["breakdown"].values():
+        assert 1 <= len(rows) <= 10
+        for name, seconds in rows:
+            assert isinstance(name, str) and 0 < len(name) <= 80
+            assert isinstance(seconds, float) and seconds > 0
+    assert result["breakdown"]["device_ops"][0][0].startswith("sg.lmm.")
+    assert "op-name paths" in capfd.readouterr().err   # both parses timed
 
 
 def test_same_seed_same_run_and_laps_agree(monkeypatch):
@@ -114,10 +116,6 @@ def test_interval_arithmetic():
     own = trace.self_times([("while", 0, 10), ("body", 1, 4),
                             ("body", 5, 8), ("after", 12, 13)])
     assert own == {"while": 4, "body": 6, "after": 1}
-    notes = [("bench:lap", 0, 100), ("bench:lap.run", 10, 90)]
-    assert trace.name_gap((20, 30), notes) == "bench:lap.run"
-    assert trace.name_gap((0, 8), notes) == "bench:lap"
-    assert trace.name_gap((200, 300), notes) == "unannotated"
     assert trace.short_op("%fusion.163 = f32[141871]{0:T(1024)S(1)} "
                           "fusion(s32[1241664]{0} %x), kind=kCustom") \
         == "%fusion.163 f32[141871] fusion"
@@ -137,8 +135,8 @@ def test_summary_of_made_up_planes():
     s = trace.TraceSummary(planes)
     assert s.window_s == 1000e-9 and s.busy_s == pytest.approx(400e-9)
     assert s.module_seconds("_superstep_program") == (300e-9, 1)
-    assert s.top_gaps() == [["unannotated", 400e-9],
-                            ["lap.upload", 200e-9]]
+    assert scopes.top_gaps(s) == [["unannotated", 400e-9],
+                                  ["lap.upload", 200e-9]]
     assert dict(map(tuple, s.top_ops()))["%while x"] \
         == pytest.approx(200e-9)
     with pytest.raises(ValueError, match="no device operation"):

@@ -1,7 +1,8 @@
 """The reductions that read the program's own names: the wire decoder of
 ``lib/xmeta.py`` against ``ProfileData`` on the recorded traces, device
 self time by ``sg.*`` scope and idle time by ``sg:`` span
-(``lib/scopes.py``), and the new readers on a run with nothing to read.
+(``lib/scopes.py``), the readers that report both, and the readers on a
+run with nothing to read.
 
 Two recorded traces, both one window of ``tiny128-random.drain`` on the
 v5e: ``tiny_drain`` from before the program named anything (PR 26), and
@@ -10,24 +11,26 @@ v5e: ``tiny_drain`` from before the program named anything (PR 26), and
 ``opstats.span`` on the host steps.
 """
 
-import importlib.util
-import lzma
-import os
 import struct
 import types
 
 import pytest
 
+import tiny
 from lib import manifest as mf, scopes, trace, xmeta
 
-FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
-SUPERSTEP = "jit__superstep_program"
+SUPERSTEP = scopes.SUPERSTEP[0]
 LMM = ["sg.lmm." + p for p in ("init", "neighmin", "level", "update",
                                "prune")]
 DRAIN = ["sg.drain." + p for p in ("solve", "advance", "ring", "pack")]
 #: what XLA inserts on its own, with no op-name path to inherit
 XLA_MADE = {"copy-start", "copy-done", "data formatting", "broadcast",
             "while", "custom-call"}
+#: device time by pass: six columns of an advance, three of a solve
+DRAIN_PASSES = ["drain.solve_init_ms", "drain.rounds_ms",
+                "drain.partition_ms", "drain.coll_ms", "drain.ring_ms",
+                "drain.retire_ms"]
+SOLVE_PASSES = ["solve.init_ms", "solve.rounds_ms", "solve.partition_ms"]
 NEW_READERS = [m["name"] for m in mf.load_manifest()["per_layer"]
                if m["name"].split(".")[0] == "setup"
                or m["name"] in ("drain.issue_ms", "drain.demux_ms",
@@ -37,34 +40,30 @@ NEW_READERS = [m["name"] for m in mf.load_manifest()["per_layer"]
                                 "solve.compiles_in_window")]
 
 
-def unpacked(tmp_path_factory, name):
-    path = tmp_path_factory.mktemp(name) / (name + ".xplane.pb")
-    with lzma.open(os.path.join(FIXTURES, name + ".xplane.pb.xz")) as f:
-        path.write_bytes(f.read())
-    return str(path)
-
-
 @pytest.fixture(scope="module", params=["tiny_drain", "tiny_drain_scoped"])
 def either(request, tmp_path_factory):
-    path = unpacked(tmp_path_factory, request.param)
+    path = tiny.recorded(request.param,
+                         tmp_path_factory.mktemp(request.param))
     return xmeta.read(path), trace.read_xplane(path)
 
 
 @pytest.fixture(scope="module", params=["tiny_drain", "tiny_drain_scoped"])
 def either_summary(request, tmp_path_factory):
-    path = unpacked(tmp_path_factory, request.param)
+    path = tiny.recorded(request.param,
+                         tmp_path_factory.mktemp(request.param))
     return xmeta.read(path), trace.TraceSummary(trace.read_xplane(path))
 
 
 @pytest.fixture(scope="module")
 def plain(tmp_path_factory):
-    path = unpacked(tmp_path_factory, "tiny_drain")
+    path = tiny.recorded("tiny_drain", tmp_path_factory.mktemp("plain"))
     return xmeta.read(path), trace.TraceSummary(trace.read_xplane(path))
 
 
 @pytest.fixture(scope="module")
 def scoped(tmp_path_factory):
-    path = unpacked(tmp_path_factory, "tiny_drain_scoped")
+    path = tiny.recorded("tiny_drain_scoped",
+                         tmp_path_factory.mktemp("scoped"))
     return xmeta.read(path), trace.TraceSummary(trace.read_xplane(path))
 
 
@@ -111,6 +110,17 @@ def test_wire_primitives():
     assert xmeta.signed((1 << 64) - 5) == -5 and xmeta.signed(7) == 7
     with pytest.raises(ValueError):
         list(xmeta.fields(memoryview(bytes([0x0B]))))   # a group: wire 3
+    # an event in one loop: id 300, a stat skipped, offset 5, duration
+    # 2^21, occurrences (field 5) ignored; the last value of a repeat
+    ev = bytes([0x08, 0xAC, 0x02, 0x22, 0x02, 0x08, 0x01, 0x10, 0x05,
+                0x18, 0x80, 0x80, 0x80, 0x01, 0x28, 0x07, 0x10, 0x06])
+    assert xmeta.event(memoryview(ev)) == (300, 6, 1 << 21)
+    assert xmeta.event(memoryview(b"")) == (0, 0, 0)
+    # a field numbered over 15 (a two-byte key): the general decoder
+    assert xmeta.event(memoryview(ev[:3] + bytes([0x80, 0x01, 0x09])
+                                  + ev[7:9])) == (300, 5, 0)
+    with pytest.raises(ValueError):
+        xmeta.event(memoryview(bytes([0x0B])))
     # a stat by value and by reference into the stat names
     names = {1: "tf_op", 2: "jit(f)/sg.lmm.init/mul:"}
     assert xmeta.stat(memoryview(bytes([0x08, 1, 0x38, 2])), names) == (
@@ -202,9 +212,31 @@ def test_idle_goes_to_the_innermost_span_open_at_the_time():
         notes=[("sg:drain.collect", 250, 620), ("sg:fetch", 260, 520),
                ("sg:drain.demux", 530, 600), ("bench:lap", 0, 1000)])
     assert scopes.idle_by_span(s) == {
-        "fetch": 200, scopes.UNNAMED: 100 + 200}
+        "sg:fetch": 200, scopes.UNNAMED: 100 + 200}
+    # the benchmark's spans under the same rule: the lap is what was
+    # open where the program had nothing
+    assert scopes.top_gaps(s) == [["lap", 300e-9], ["sg:fetch", 200e-9]]
     s = summary_of(ops=[("%a", 100, 900)], notes=[])
     assert scopes.idle_by_span(s) == {scopes.UNNAMED: 200}
+    assert scopes.top_gaps(s) == [[scopes.UNNAMED, 200e-9]]
+
+
+def test_a_gap_over_three_spans_is_split_three_ways():
+    """One idle stretch, 100..900, under ``bench:lap.events``, then bare
+    ``bench:measure``, then ``bench:lap.upload`` > ``sg:drain.init``:
+    each gets the part it was innermost in, ``measure`` only its own."""
+    s = summary_of(
+        ops=[("%a", 0, 100), ("%b", 900, 1000)],
+        notes=[("bench:measure", 0, 1000), ("bench:lap.events", 50, 300),
+               ("bench:lap.upload", 450, 950), ("sg:drain.init", 500, 950)])
+    assert scopes.top_gaps(s) == [
+        ["sg:drain.init", 400e-9], ["lap.events", 200e-9],
+        ["measure", 150e-9], ["lap.upload", 50e-9]]
+    assert scopes.top_gaps(s, 2) == [["sg:drain.init", 400e-9],
+                                     ["lap.events", 200e-9]]
+    # the program's spans alone: what no sg: span covers, whoever's
+    assert scopes.idle_by_span(s) == {"sg:drain.init": 400,
+                                      scopes.UNNAMED: 400}
 
 
 def test_recorded_idle_time_is_named_by_the_programs_spans(scoped, plain):
@@ -216,16 +248,27 @@ def test_recorded_idle_time_is_named_by_the_programs_spans(scoped, plain):
             in scopes.host_spans(summary)} >= {
         "drain.init", "drain.issue", "drain.collect", "fetch",
         "drain.demux"}
-    assert by["fetch"] > 0 and by[scopes.UNNAMED] < sum(by.values())
+    assert by["sg:fetch"] > 0 and by[scopes.UNNAMED] < sum(by.values())
+    # every gap of the breakdown, before the cut to ten: all of the
+    # idle time, little of it under bare ``measure`` or nothing
+    everything = dict(map(tuple, scopes.top_gaps(summary, None)))
+    assert sum(everything.values()) == pytest.approx(idle_ns / 1e9,
+                                                     abs=2e-9)
+    assert everything["sg:drain.init"] == by["sg:drain.init"] / 1e9
+    assert everything.get("measure", 0.0) \
+        + everything.get(scopes.UNNAMED, 0.0) < 0.1 * idle_ns / 1e9
     _meta, old = plain
     assert set(scopes.idle_by_span(old)) == {scopes.UNNAMED}
+    assert {name for name, _s in scopes.top_gaps(old)} <= {
+        "lap", "run", scopes.UNNAMED}
 
 
 # -- the readers ----------------------------------------------------------
 
 def fake_run(window_from=float("inf"), trace_summary=None):
     return types.SimpleNamespace(
-        trace=trace_summary, counters={}, record={"wall_s": 1.0},
+        trace=trace_summary, scopes=None, counters={},
+        record={"wall_s": 1.0},
         spans=types.SimpleNamespace(window_from=window_from))
 
 
@@ -236,7 +279,6 @@ def test_reader_finds_nothing_in_a_program_without_the_facility(
     monkeypatch.delattr(opstats, "spans")
     monkeypatch.delattr(opstats, "span")
     monkeypatch.setattr(opstats, "_counters", {})
-    assert len(NEW_READERS) == 11
     run = fake_run(trace_summary=plain[1])    # a trace, but no facility
     assert mf.load_module("metrics", name).read(run) is None
 
@@ -278,29 +320,86 @@ def test_readers_cut_the_programs_spans_at_the_window(monkeypatch):
 def test_idle_unnamed_reader(scoped, plain):
     read = mf.load_module("metrics", "drain.idle_unnamed_pct").read
     assert read(fake_run()) is None
-    assert 0 <= read(fake_run(trace_summary=scoped[1])) < 100
+    # idle under no sg: span, the benchmark's own or not, as before the
+    # breakdown's gaps were named by the same function
+    assert read(fake_run(trace_summary=scoped[1])) == pytest.approx(
+        100 * 606750 / 11696359, rel=1e-12)
     # spans opened, none in the trace: a reading (the facility failed)
     assert read(fake_run(trace_summary=plain[1])) == 100.0
 
 
-# -- the tool's arithmetic -------------------------------------------------
+# -- device time by pass ---------------------------------------------------
 
-def test_passes_breakdown_accounts_for_the_module(scoped):
-    spec = importlib.util.spec_from_file_location(
-        "bench_tools_passes", os.path.join(mf.BENCH, "tools", "passes.py"))
-    passes = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(passes)
-    meta, summary = scoped
+def passes_run(meta, summary, **record):
     run = fake_run(trace_summary=summary)
-    run.cell = types.SimpleNamespace(traffic={"driver": "drain"})
-    run.counters = {"fixpoint_rounds": 10}
-    run.record = {"advances": 2}
-    got = passes.breakdown(run, scopes.device_scopes(meta, summary))
-    assert got["scopes_over_module"] == pytest.approx(1.0, abs=0.03)
-    accounted = (sum(got["round_ms"].values()) * 10
-                 + (got["solve_init_ms"] + got["retire_ms"]) * 2) / 1e3
-    assert accounted == pytest.approx(sum(got["scope_s"].values()),
-                                      rel=1e-9)
-    run.counters = {}
-    assert passes.breakdown(run, scopes.device_scopes(meta, summary)) \
-        is None
+    run.scopes = scopes.device_scopes(meta, summary)
+    run.record = record
+    return run
+
+
+def read(name, run):
+    return mf.load_module("metrics", name).read(run)
+
+
+def test_the_six_columns_of_an_advance_add_up_to_the_module(scoped):
+    meta, summary = scoped
+    run = passes_run(meta, summary, advances=8)
+    got = {name: read(name, run) for name in DRAIN_PASSES}
+    module_s, _runs = summary.module_seconds(SUPERSTEP)
+    assert sum(got.values()) == pytest.approx(1e3 * module_s / 8, rel=1e-3)
+    by = run.scopes.scopes(SUPERSTEP)
+    assert got["drain.rounds_ms"] == pytest.approx(
+        1e3 / 8 * sum(by[scope] for scope in scopes.ROUND))
+    assert got["drain.rounds_ms"] > 0.5 * sum(got.values())
+    assert got["drain.solve_init_ms"] == pytest.approx(
+        1e3 / 8 * (by["sg.lmm.init"] + by["sg.drain.solve"]))
+    # recorded before the ladder and without a tape: those passes never
+    # ran, which is a reading
+    assert got["drain.partition_ms"] == got["drain.coll_ms"] == 0.0
+    # the solve's program is not in this trace; no advance, no reading
+    assert [read(n, passes_run(meta, summary, solves=3, advances=8))
+            for n in SOLVE_PASSES] == [None] * 3
+    assert [read(n, passes_run(meta, summary, advances=0))
+            for n in DRAIN_PASSES] == [None] * 6
+
+
+def test_the_three_columns_of_a_solve_on_made_up_scopes():
+    chunk = "jit__solve_kernel_chunk(7)"
+    run = fake_run()
+    run.record = {"solves": 4}
+    run.scopes = scopes.DeviceScopes({
+        (chunk, "sg.lmm.init", "%a"): 0.2, (chunk, "unscoped", "%w"): 0.04,
+        (chunk, "sg.lmm.neighmin", "%b"): 1.0,
+        (chunk, "sg.lmm.update", "%c"): 0.6,
+        (chunk, "sg.lmm.partition", "%d"): 0.1,
+        ("jit_other(1)", "unscoped", "%e"): 9.0})
+    assert [read(n, run) for n in SOLVE_PASSES] == [
+        pytest.approx(60.0), pytest.approx(400.0), pytest.approx(25.0)]
+    assert [read(n, run) for n in DRAIN_PASSES] == [None] * 6
+
+
+@pytest.mark.parametrize("name", DRAIN_PASSES + SOLVE_PASSES)
+def test_a_trace_with_no_sg_path_gives_the_pass_readers_nothing(name, plain):
+    meta, summary = plain
+    run = passes_run(meta, summary, advances=8, solves=8)
+    assert read(name, run) is None           # unscoped time is no pass
+    run.scopes = None                        # the raw trace not decoded
+    assert read(name, run) is None
+    assert read(name, fake_run()) is None    # not traced at all
+
+
+def test_device_ops_carry_the_scope_where_the_trace_has_any(scoped, plain):
+    meta, summary = scoped
+    run = passes_run(meta, summary)
+    ops = scopes.top_ops(run, 10)
+    assert len(ops) == 10 and all(len(name) <= 80 for name, _s in ops)
+    scope, op = ops[0][0].split(" ", 1)
+    assert scope in LMM and op.startswith("%fusion")
+    assert [s for _n, s in ops] == sorted((s for _n, s in ops),
+                                          reverse=True)
+    # no name of the program's in the trace: the names of today
+    meta, old = plain
+    run = passes_run(meta, old)
+    assert scopes.top_ops(run, 10) == old.top_ops(10)
+    run.scopes = None
+    assert scopes.top_ops(run, 10) == old.top_ops(10)

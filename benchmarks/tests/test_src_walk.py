@@ -2,12 +2,8 @@
 whose DAG walk started from their own completions' successor edges: on
 hand-made runs, through the harness on the two tape cells' tiny twins
 (whose every advance owns far fewer edges than the walk is wide), and
-NOT in the manifest: ``test_var_entry.py`` holds BENCHMARK.json's last
-per-layer entry, so the reader waits beside PR 35's four (PERF.md
-section 7); ``tools/passes_coll.py`` and ``passes_allreduce.py`` print
-its counter with the window's others."""
+in the manifest, for the two tape cells."""
 
-import os
 import types
 
 import pytest
@@ -46,11 +42,13 @@ def test_it_is_a_share_of_the_advances_committed(counted):
     assert read(types.SimpleNamespace(counters={}, record={})) is None
 
 
-def test_it_waits_beside_the_manifest():
-    names = {m["name"] for m in mf.load_manifest()["per_layer"]}
-    assert "coll.src_walk_pct" not in names
-    assert os.path.isfile(os.path.join(mf.BENCH, "metrics",
-                                       "coll.src_walk_pct.py"))
+def test_the_manifest_lists_it_for_the_two_tape_cells():
+    by = {m["name"]: m for m in mf.load_manifest()["per_layer"]}
+    assert by["coll.src_walk_pct"] == {
+        "name": "coll.src_walk_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "advance retire ring",
+        "moves": "events_per_s",
+        "workloads": ["dfly65k-pairwise.drain", "dfly65k-allreduce.drain"]}
 
 
 @pytest.mark.parametrize("cell", ["tiny128-pairwise.drain",
