@@ -26,6 +26,8 @@ one ``* ``dotted.name`` — description`` bullet each, held to the
 ``span(<non-literal>)``, is a finding at the call site (a span name
 has no families: a reader finds spans by exact name), and a declared
 span that no linted file opens is a finding at its bullet.
+``note_xla("x", ...)``, which records a span JAX timed, is a site of
+span ``x`` like ``span("x")``.
 """
 
 from __future__ import annotations
@@ -121,10 +123,12 @@ def _is_bump(ctx: FileContext, node: ast.Call) -> bool:
 
 def _is_span(ctx: FileContext, node: ast.Call) -> bool:
     dotted = ctx.imports.resolve(node.func)
-    if ImportMap.matches(dotted, "simgrid_tpu.ops.opstats.span"):
+    if ImportMap.matches(dotted, "simgrid_tpu.ops.opstats.span") \
+            or ImportMap.matches(dotted,
+                                 "simgrid_tpu.ops.opstats.note_xla"):
         return True
     # inside opstats.py: span() by its local name, and the records
-    # note_compile() builds directly
+    # it and note_xla() build directly
     return ctx.path == OPSTATS_PATH and dotted in ("span", "Span")
 
 

@@ -16,6 +16,7 @@ import weakref as _weakref
 from typing import Callable, Dict, List, Optional
 
 from ..exceptions import SimgridException
+from ..ops import opstats
 from ..utils import log as _log
 from ..utils.config import config
 from ..utils.signal import Signal
@@ -54,6 +55,7 @@ class EngineImpl:
     def __init__(self):
         EngineImpl.instance = self
         self.now = 0.0
+        self.advances = 0                 # surf_solve calls so far
         self.models: List = []            # all_existing_models
         self.host_model = None
         self.cpu_model = None
@@ -244,6 +246,15 @@ class EngineImpl:
     # surf_solve: the time-advance (surf_c_bindings.cpp:45-151)
     # ------------------------------------------------------------------
     def surf_solve(self, max_date: float) -> float:
+        """One time advance, under an ``engine.advance`` span (the
+        generic host sweep or the drain fast path, whichever the
+        models take: ``native_advances`` / ``fastpath_advances``)."""
+        with opstats.span("engine.advance", id=self.advances):
+            time_delta = self._advance(max_date)
+        self.advances += 1
+        return time_delta
+
+    def _advance(self, max_date: float) -> float:
         time_delta = -1.0
         # >= 0: a bound AT the current date (run_until(now), timers at
         # t=0) means a zero-length advance, not an unbounded one

@@ -35,10 +35,13 @@ only moves a line, a checkout at another path or another entry script
 compiles once more (43 s for the config-#4 superstep, PERF.md).
 
 Compiles are counted here too: one listener on JAX's monitoring events
-turns every program that leaves the backend-compile step into an
-``xla.compile`` span and the ``xla_compiles`` / ``xla_compile_ms``
-counters of :mod:`.opstats` (that step wraps the persistent-cache
-lookup, so a hit is a short span whose id starts with ``cached:``).
+turns each step of a first call - the function traced into a jaxpr,
+the jaxpr lowered to an MLIR module, the module through the backend
+compiler - into an ``xla.trace`` / ``xla.lower`` / ``xla.compile`` span
+of :mod:`.opstats`, and counts the last in its ``xla_compiles`` /
+``xla_compile_ms`` (the compile step wraps the persistent-cache
+lookup, so a hit is a short span whose id starts with ``cached:``;
+tracing and lowering are Python seconds that no cache saves).
 """
 
 import os
@@ -71,9 +74,13 @@ def compile_cache() -> Tuple[Optional[str], str]:
 from . import opstats  # noqa: E402
 
 #: JAX's monitoring events (jax 0.9.0: jax._src.dispatch
-#: BACKEND_COMPILE_EVENT, jax._src.compiler): the duration event closes
-#: every backend compile, persistent-cache lookups included; the plain
-#: event fires inside it when the lookup hit
+#: JAXPR_TRACE_EVENT, JAXPR_TO_MLIR_MODULE_EVENT, BACKEND_COMPILE_EVENT;
+#: jax._src.compiler): each duration event closes one step of a first
+#: call and carries ``fun_name``; the compile one closes every backend
+#: compile, persistent-cache lookups included, and the plain event
+#: fires inside it when the lookup hit
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _cache_hit = threading.local()
@@ -88,7 +95,15 @@ def _on_duration(event: str, seconds: float, **kw) -> None:
     if event == _COMPILE_EVENT:
         cached = getattr(_cache_hit, "seen", False)
         _cache_hit.seen = False
-        opstats.note_compile(seconds, kw.get("fun_name"), cached)
+        opstats.note_xla("xla.compile", seconds,
+                         ("cached:" if cached else "")
+                         + str(kw.get("fun_name")))
+        opstats.bump("xla_compiles")
+        opstats.bump("xla_compile_ms", seconds * 1e3)
+    elif event == _TRACE_EVENT:
+        opstats.note_xla("xla.trace", seconds, str(kw.get("fun_name")))
+    elif event == _LOWER_EVENT:
+        opstats.note_xla("xla.lower", seconds, str(kw.get("fun_name")))
 
 
 jax.monitoring.register_event_listener(_on_event)

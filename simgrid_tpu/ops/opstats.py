@@ -339,11 +339,26 @@ this table, like the counters (the ``opstats-discipline`` lint checks
 * ``fetch``           — every :func:`timed_fetch`: the host inside one
                         device->host transfer (``blocking_fetches``
                         still says whether the device was ready)
-* ``xla.compile``     — one program through the backend compiler, as
+* ``engine.advance``   — ``EngineImpl.surf_solve``: one time advance
+                        of the engine (the models' next event, which
+                        holds the solve; the profile events; every
+                        model's ``update_actions_state``); ``id`` is
+                        the advance's ordinal in its engine
+* ``xla.trace``       — one jitted function traced into a jaxpr, as
                         JAX's monitoring reports it when it ends:
-                        ``(now - duration, now)``; ``id`` is the jitted
-                        function's name, prefixed ``cached:`` when the
-                        persistent compilation cache served it
+                        ``(now - duration, now)``, ``id`` the
+                        function's name.  Inner jits traced on the way
+                        give spans nested in the outer one's by time
+                        (``parent`` names the enclosing ``span()``, not
+                        the outer trace): a reader takes self or union
+                        seconds, never the plain sum
+* ``xla.lower``       — one jaxpr lowered to an MLIR module, reported
+                        the same way; ``id`` is the module's name
+                        (``jit(<function>)``)
+* ``xla.compile``     — one program through the backend compiler,
+                        reported the same way; ``id`` is the module's
+                        name, prefixed ``cached:`` when the persistent
+                        compilation cache served it
 """
 
 from __future__ import annotations
@@ -451,19 +466,15 @@ def spans() -> List[Span]:
     return sorted(_spans, key=lambda s: s.seq)
 
 
-def note_compile(seconds: float, fun_name: Optional[str],
-                 cached: bool) -> None:
-    """One program left JAX's backend-compile step after ``seconds``
-    (the listener ``ops/__init__.py`` registers calls this): a closed
-    ``xla.compile`` span ending now, and the ``xla_*`` counters."""
+def note_xla(name: str, seconds: float, id) -> None:
+    """One step of JAX's way from a jitted function to an executable
+    ended after ``seconds`` (the listener ``ops/__init__.py``
+    registers calls this with a literal name of the table above): a
+    closed span called ``name`` ending now."""
     end = time.perf_counter()
     stack = getattr(_open, "stack", None)
-    _spans.append(Span("xla.compile", end - seconds, end,
-                       stack[-1]._seq if stack else None,
-                       ("cached:" if cached else "") + str(fun_name),
-                       next(_seq)))
-    bump("xla_compiles")
-    bump("xla_compile_ms", seconds * 1e3)
+    _spans.append(Span(name, end - seconds, end,
+                       stack[-1]._seq if stack else None, id, next(_seq)))
 
 
 def snapshot() -> Dict[str, float]:

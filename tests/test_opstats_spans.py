@@ -1,7 +1,8 @@
 """The program's one tracing facility (ops/opstats.py, ISSUE 27): host
-spans on ``time.perf_counter``, the ``xla.compile`` listener, the fetch
-accounting of ``solve_arrays`` and the ``jax.named_scope`` names of the
-device passes in the lowered programs."""
+spans on ``time.perf_counter``, the listener behind the ``xla.trace`` /
+``xla.lower`` / ``xla.compile`` spans, the engine's ``engine.advance``,
+the fetch accounting of ``solve_arrays`` and the ``jax.named_scope``
+names of the device passes in the lowered programs."""
 
 import importlib.util
 import lzma
@@ -129,7 +130,7 @@ def test_drain_leaves_its_spans_in_order_and_one_id_per_dispatch():
                              repack_min=1 << 62)
     sim.run()
     assert sim.supersteps >= 2 and len(sim.events) == len(sizes)
-    mine = [s for s in opstats.spans() if s.name != "xla.compile"]
+    mine = [s for s in opstats.spans() if not s.name.startswith("xla.")]
     assert mine[0].name == "drain.init" and mine[0].parent is None
     by_seq = {s.seq: s for s in mine}
     per_dispatch = [[s.name for s in mine[1:] if s.id == d]
@@ -198,6 +199,113 @@ def test_a_compile_inside_a_span_names_it_as_parent():
     assert issue.name == "drain.issue"
     assert inner and all(s.parent == issue.seq for s in inner)
     assert not any(str(s.id).startswith("cached:") for s in inner)
+
+
+def xla_spans(needle):
+    return [s for s in opstats.spans()
+            if s.name.startswith("xla.") and needle in str(s.id)]
+
+
+def test_a_first_call_is_traced_lowered_and_compiled_in_that_order():
+    @jax.jit
+    def quadruple(x):
+        return x * 4
+
+    x = jnp.arange(5.0)
+    quadruple(x).block_until_ready()
+    trace, lower, compile_ = xla_spans("quadruple")
+    assert [s.name for s in (trace, lower, compile_)] == [
+        "xla.trace", "xla.lower", "xla.compile"]
+    assert trace.id == "quadruple" and lower.id == compile_.id \
+        == "jit(quadruple)"
+    # each is (now - duration, now) on two clocks: room for their grain
+    assert trace.start < trace.end <= lower.start + 1e-4
+    assert lower.start < lower.end <= compile_.start + 1e-4
+    assert compile_.start < compile_.end
+    counted = opstats.snapshot()
+    assert counted["xla_compile_ms"] >= 1e3 * (compile_.end
+                                               - compile_.start) > 0
+    quadruple(x).block_until_ready()                    # nothing new
+    assert len(xla_spans("quadruple")) == 3
+    assert opstats.diff(counted) == {}
+
+
+def test_inner_traces_nest_in_the_outers_and_their_union_is_under_it():
+    @jax.jit
+    def inner_a(x):
+        return jnp.sin(x) + 1
+
+    @jax.jit
+    def inner_b(x):
+        return jnp.cos(x) * 2
+
+    @jax.jit
+    def outer_fn(x):
+        return inner_a(x) + inner_b(inner_a(x * 3))
+
+    x = jnp.arange(6.0)
+    opstats.reset()                   # whatever making x traced
+    outer_fn(x).block_until_ready()
+    traces = [s for s in opstats.spans() if s.name == "xla.trace"]
+    outer = [s for s in traces if s.id == "outer_fn"]
+    inner = [s for s in traces if str(s.id).startswith("inner_")]
+    assert len(outer) == 1 and {s.id for s in inner} == {"inner_a",
+                                                         "inner_b"}
+    outer = outer[0]
+    for s in inner:                   # recorded when they ended: before
+        assert s.seq < outer.seq      # the outer one, and inside it
+        assert outer.start <= s.start + 1e-4 and s.end <= outer.end
+    union, at = 0.0, outer.start
+    for s in sorted(traces, key=lambda s: s.start):
+        union += max(0.0, s.end - max(at, s.start))
+        at = max(at, s.end)
+    assert union <= (outer.end - outer.start) + 1e-4
+    # the plain sum counts the nested stretches twice
+    assert sum(s.end - s.start for s in traces) > union
+    # only whole programs are lowered and compiled
+    assert {s.id for s in opstats.spans() if s.name == "xla.lower"
+            and ("inner_" in s.id or "outer_" in s.id)} == {"jit(outer_fn)"}
+
+
+# -- the engine's advance -------------------------------------------------
+
+PLATFORM = """<?xml version='1.0'?>
+<platform version="4.1">
+  <zone id="world" routing="Full">
+    <cluster id="c" prefix="n-" radical="0-7" suffix="" speed="1Gf"
+             bw="125MBps" lat="50us"/>
+  </zone>
+</platform>
+"""
+
+
+def test_every_engine_advance_is_a_span_with_its_ordinal(tmp_path):
+    from simgrid_tpu import s4u
+    path = tmp_path / "c8.xml"
+    path.write_text(PLATFORM)
+    s4u.Engine._reset()
+    e = s4u.Engine(["advance", "--cfg=network/optim:Full",
+                    "--cfg=lmm/backend:native"])
+    try:
+        e.load_platform(str(path))
+        hosts = e.get_all_hosts()
+        model = e.pimpl.network_model
+        for k in range(1, 8):
+            model.communicate(hosts[0], hosts[k], 1e6 * k, -1.0)
+        opstats.reset()
+        n = 0
+        while e.pimpl.surf_solve(-1.0) >= 0:
+            n += 1
+        n += 1                                   # the one that ran dry
+        assert n >= 8 and e.pimpl.advances == n
+    finally:
+        s4u.Engine._reset()
+    advances = [s for s in opstats.spans() if s.name == "engine.advance"]
+    assert [s.id for s in advances] == list(range(n))
+    assert all(s.parent is None for s in advances)
+    assert all(a.end <= b.start for a, b in zip(advances, advances[1:]))
+    assert all(s.end > s.start for s in advances)
+    assert opstats.snapshot()["native_advances"] >= 7
 
 
 # -- solve_arrays: every chunk's fetch is a timed fetch ------------------

@@ -297,7 +297,7 @@ SPANS_FIXTURE = OPSTATS_FIXTURE.replace(
         "Prose naming ``not.a.bullet`` declares nothing.\n"
         "\n"
         "* ``drain.issue``  — a declared span\n"
-        "* ``xla.compile``  — recorded by hand inside opstats\n"
+        "* ``xla.compile``  — timed by JAX, recorded through note_xla\n"
         '"""\n'
         "def bump")) + (
     "class Span(tuple):\n"
@@ -307,11 +307,12 @@ SPANS_FIXTURE = OPSTATS_FIXTURE.replace(
     "        self.name = name\n"
     "    def __exit__(self, *exc):\n"
     "        Span(self.name)\n"
-    "def note_compile():\n"
-    "    Span('xla.compile')\n")
+    "def note_xla(name, seconds, id):\n"
+    "    Span(name)\n")
 
 BUMPS = ("opstats.bump('declared')\nopstats.bump('ghost')\n"
-         "opstats.bump('fam_' + kind)\n")
+         "opstats.bump('fam_' + kind)\n"
+         "opstats.note_xla('xla.compile', 0.1, 'f')\n")
 
 
 class TestOpstatsSpanDiscipline:
@@ -326,18 +327,25 @@ class TestOpstatsSpanDiscipline:
     @pytest.mark.parametrize("src,lines", [
         ("with opstats.span('drain.issue', id=3):\n    pass\n", []),
         ("with opstats.span('drain.issue'):\n    pass\n"
-         "with opstats.span('drain.other'):\n    pass\n", [7]),
+         "with opstats.span('drain.other'):\n    pass\n", [8]),
         ("with opstats.span('drain.issue'):\n    pass\n"
-         "with opstats.span('drain.' + kind):\n    pass\n", [7]),
+         "with opstats.span('drain.' + kind):\n    pass\n", [8]),
         ("with opstats.span('drain.issue'):\n    pass\n"
-         "with opstats.span('not.a.bullet'):\n    pass\n", [7]),
-    ], ids=["declared", "undeclared", "non-literal", "prose-token"])
+         "with opstats.span('not.a.bullet'):\n    pass\n", [8]),
+        ("opstats.note_xla('drain.issue', 0.1, 'f')\n", []),
+        ("with opstats.span('drain.issue'):\n    pass\n"
+         "opstats.note_xla('xla.other', 0.1, 'f')\n", [8]),
+        ("with opstats.span('drain.issue'):\n    pass\n"
+         "opstats.note_xla(name, 0.1, 'f')\n", [8]),
+    ], ids=["declared", "undeclared", "non-literal", "prose-token",
+            "note-declared", "note-undeclared", "note-non-literal"])
     def test_span_sites_are_held_to_the_table(self, src, lines):
         got = rules_of(self.lint(src), "opstats-discipline")
         assert sorted(f.line for f in got) == lines
         assert all(f.path.endswith("user.py") for f in got)
 
     def test_declared_but_never_opened_is_flagged_at_registry(self):
+        # the fixture's own note_xla() call keeps xla.compile opened
         got = rules_of(self.lint("x = 1\n"), "opstats-discipline")
         assert len(got) == 1
         assert got[0].path == "simgrid_tpu/ops/opstats.py"
@@ -351,7 +359,36 @@ class TestOpstatsSpanDiscipline:
         names = set(declared_spans(opstats.__doc__))
         assert {"platform.load", "lmm.flatten", "coll.lower", "drain.init",
                 "drain.issue", "drain.collect", "drain.demux",
-                "solve.chunk", "fetch", "xla.compile"} == names
+                "solve.chunk", "fetch", "engine.advance", "xla.trace",
+                "xla.lower", "xla.compile"} == names
+
+    def test_the_real_listener_is_held_to_both_tables(self):
+        """``ops/__init__.py`` names its three spans through
+        ``note_xla`` and bumps the compile's counters itself: with one
+        renamed there, the real rule over the real registry finds the
+        undeclared name and the bullet left without a site."""
+        import os
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        files = {}
+        for rel in ("simgrid_tpu/ops/opstats.py",
+                    "simgrid_tpu/ops/__init__.py"):
+            with open(os.path.join(root, rel)) as f:
+                files[rel] = f.read()
+
+        def findings(src):
+            return [f.message for f in rules_of(
+                lint_sources({**files, "simgrid_tpu/ops/__init__.py": src}),
+                "opstats-discipline")
+                if "xla" in f.message]
+
+        listener = files["simgrid_tpu/ops/__init__.py"]
+        assert findings(listener) == []
+        got = findings(listener.replace('"xla.lower"', '"xla.mlir"'))
+        assert len(got) == 2 and "'xla.mlir'" in got[0] \
+            and "'xla.lower'" in got[1]
+        got = findings(listener.replace('"xla_compile_ms"', '"xla_jit_ms"'))
+        assert len(got) == 2 and "'xla_jit_ms'" in got[0] \
+            and "'xla_compile_ms'" in got[1]
 
 
 # -- engine: suppressions ------------------------------------------------
