@@ -38,8 +38,9 @@ captured from the real coll.py implementation running on threads.
 from __future__ import annotations
 
 from collections import deque
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
+from ..ops import opstats
 from ..utils.gc_pause import collector_paused
 
 # Mirrors smpi/coll.py (reference smpi/include/private.hpp COLL_TAG_*);
@@ -327,30 +328,43 @@ def seq_allreduce_rdb(ranks: int, nbytes: float) -> CollectiveSchedule:
 
 
 def seq_allreduce_lr(ranks: int, count_elems: int,
-                     elem_bytes: float = 8.0) -> CollectiveSchedule:
+                     elem_bytes: float = 8.0,
+                     steps: Optional[int] = None) -> CollectiveSchedule:
     """coll.allreduce_lr: logical-ring reduce-scatter + all-gather on
     an ndarray of ``count_elems`` elements, including the observable
     quirks — the initial sendrecv-to-self copy (rides the loopback
     link) and the ``count_elems % ranks`` remainder folded by a
-    recursive allreduce (which, at len < ranks, is rdb)."""
+    recursive allreduce (which, at len < ranks, is rdb).
+
+    ``steps`` emits the schedule's HEAD: the self-copy and the first
+    ``steps`` of the 2 (ranks - 1) ring ``sendrecv``s of every rank (all
+    of them are the whole schedule, remainder included).  No record of
+    the head waits for one past it, so its DAG is the whole schedule's
+    cut there."""
     progs = [Prog() for _ in range(ranks)]
     if count_elems < ranks:
+        if steps is not None:
+            raise ValueError(
+                f"allreduce/lr of {count_elems} elements among {ranks} "
+                f"ranks falls back to rdb, which has no head (steps=)")
         # the "not support" fallback (allreduce-lr.cpp:41-45)
         _emit_allreduce_rdb(progs, ranks, count_elems * elem_bytes)
         return build_schedule(progs)
+    ring = 2 * (ranks - 1)                  # reduce-scatter + all-gather
+    if steps is not None and not 0 <= steps <= ring:
+        raise ValueError(f"allreduce/lr among {ranks} ranks has {ring} "
+                         f"ring steps: no head of {steps}")
+    held = ring if steps is None else int(steps)
     count = count_elems // ranks
     remainder = count_elems % ranks
     chunk = count * elem_bytes
     for rank in range(ranks):
         p = progs[rank]
         p.sendrecv(rank, rank, chunk, TAG_ALLREDUCE, TAG_ALLREDUCE)
-        for _ in range(ranks - 1):          # reduce-scatter
+        for _ in range(held):
             p.sendrecv((rank + 1) % ranks, (rank - 1 + ranks) % ranks,
                        chunk, TAG_ALLREDUCE, TAG_ALLREDUCE)
-        for _ in range(ranks - 1):          # all-gather
-            p.sendrecv((rank + 1) % ranks, (rank - 1 + ranks) % ranks,
-                       chunk, TAG_ALLREDUCE, TAG_ALLREDUCE)
-    if remainder:
+    if remainder and held == ring:
         _emit_allreduce_rdb(progs, ranks, remainder * elem_bytes)
     return build_schedule(progs)
 
@@ -399,21 +413,33 @@ GENERATORS = {
     ("reduce", "default"): (seq_reduce_flat, "bytes"),
 }
 
+#: generators that emit a schedule's head (``steps=``): the first steps
+#: of a schedule too long to hold whole
+HEADED = frozenset({("allreduce", "lr")})
 
-def generate(op: str, algo: str, ranks: int,
-             payload: float) -> CollectiveSchedule:
+
+def generate(op: str, algo: str, ranks: int, payload: float,
+             steps: Optional[int] = None) -> CollectiveSchedule:
     """Build the schedule for (op, algo) at ``ranks`` with ``payload``
-    (bytes, or elements for lr; ignored by bruck)."""
+    (bytes, or elements for lr; ignored by bruck); with ``steps``, its
+    head (see ``HEADED``)."""
     try:
         fn, mode = GENERATORS[(op, algo)]
     except KeyError:
         raise ValueError(f"no schedule generator for {op}/{algo}; "
                          f"known: {sorted(GENERATORS)}") from None
+    if steps is not None and (op, algo) not in HEADED:
+        raise ValueError(f"{op}/{algo} has no schedule head (steps=); "
+                         f"heads: {sorted(HEADED)}")
+    head = {} if steps is None else {"steps": int(steps)}
     # millions of records and sets that all stay: nothing for the
     # cyclic collector to find while they are built
     with collector_paused():
         if mode is None:
-            return fn(ranks)
-        if mode == "elems":
-            return fn(ranks, int(payload))
-        return fn(ranks, float(payload))
+            sched = fn(ranks)
+        elif mode == "elems":
+            sched = fn(ranks, int(payload), **head)
+        else:
+            sched = fn(ranks, float(payload))
+    opstats.bump("collective_schedule_records", sched.n_comms)
+    return sched

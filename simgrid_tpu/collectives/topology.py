@@ -25,8 +25,10 @@ reference platform's loopback link, not the fabric.
   that the ranks' routes cross, a transfer rides ``routing/``'s route
   (and, under ``network/crosstraffic``, the way back at weight 0.05,
   as ``NetworkCm02Model.communicate`` expands it), and it starts only
-  after the route's latency.  No loopback: a rank sending to itself
-  is refused.
+  after the route's latency.  A rank sending to itself rides what
+  ``communicate`` gives a host sending to itself: its route to itself
+  (on a dragonfly, its router link up and back down), else the
+  model's loopback, with the same links as its way back.
 
 :meth:`Topology.lower` and :meth:`Topology.delays` are what the tape
 compiler calls; the three synthetic flavors answer them from
@@ -150,9 +152,9 @@ class RoutedTopology(Topology):
     ``NetworkCm02Model.communicate`` gives the same pair of hosts.
     """
 
-    __slots__ = ("links", "_hosts", "_host_objs", "_lat_factor",
-                 "_bw_factor", "_crosstraffic", "_slot_of", "_pair",
-                 "_at", "_off", "_slot", "_delay")
+    __slots__ = ("links", "_hosts", "_host_objs", "_loopback",
+                 "_lat_factor", "_bw_factor", "_crosstraffic", "_slot_of",
+                 "_pair", "_at", "_off", "_slot", "_delay")
 
     #: weight of a flow on the links of its way back
     #: (network_cm02.cpp, as ``communicate`` expands it)
@@ -167,6 +169,7 @@ class RoutedTopology(Topology):
         self.flavor = "routed"
         self.ranks = len(hosts)
         self._host_objs = list(hosts)
+        self._loopback = model.loopback
         self._hosts = tuple(h.name for h in hosts)
         self._lat_factor = model.get_latency_factor(0.0)
         self._bw_factor = model.get_bandwidth_factor(0.0)
@@ -192,10 +195,20 @@ class RoutedTopology(Topology):
 
     def _pairs(self, src, dst) -> np.ndarray:
         src, dst = np.asarray(src, np.int64), np.asarray(dst, np.int64)
-        if np.any(src == dst):
-            raise ValueError("routed topology: a rank sends to itself "
-                             "(no loopback is lowered)")
         return src * self.ranks + dst
+
+    def _self_route(self, host, links: list) -> float:
+        """A host's route to itself as ``communicate`` finds it: the
+        platform's, else the model's loopback."""
+        try:
+            latency = host.route_to(host, links)
+        except AssertionError:
+            links.clear()
+            latency = 0.0
+        if not links and latency <= 0:
+            links.append(self._loopback)
+            latency = self._loopback.get_latency()
+        return latency
 
     def _back(self, p: np.ndarray) -> np.ndarray:
         """Pairs ``p`` the other way round."""
@@ -204,7 +217,7 @@ class RoutedTopology(Topology):
     def _rows(self, p: np.ndarray) -> np.ndarray:
         """The route-table rows of pairs ``p``, each routed here first
         if it never was (and with it, under cross-traffic, the pair
-        the other way round)."""
+        the other way round: for a rank sending to itself, itself)."""
         R = self.ranks
         want = np.unique(np.concatenate([p, self._back(p)])
                          if self._crosstraffic else p)
@@ -219,11 +232,15 @@ class RoutedTopology(Topology):
                 for i, (a, b) in enumerate(zip((new // R).tolist(),
                                                (new % R).tolist())):
                     links: list = []
-                    delay[i] = hosts[a].route_to(hosts[b], links)
+                    delay[i] = (hosts[a].route_to(hosts[b], links)
+                                if a != b
+                                else self._self_route(hosts[a], links))
                     slots += [slot_of.setdefault(link, len(slot_of))
                               for link in links]
                     n[i] = len(links)
                 opstats.bump("collective_routes", len(new))
+                opstats.bump("collective_self_routes",
+                             int(np.count_nonzero(new // R == new % R)))
                 self.links = list(slot_of)
                 pair = np.concatenate([self._pair, new])
                 at = np.concatenate([self._at, len(self._pair)

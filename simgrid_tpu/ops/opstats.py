@@ -239,6 +239,15 @@ context object through the solver entry points:
                               its way back: bumped inside the
                               ``coll.lower`` span of ``id`` ``routes``,
                               so span / count is the price of a route
+* ``collective_self_routes`` — the subset of those pairs whose rank
+                              sends to itself (an lr allreduce's
+                              self-copy): routed as ``communicate``
+                              routes a host to itself
+* ``collective_schedule_records`` — comm records a schedule generator
+                              emitted (``collectives.generate``, inside
+                              the ``coll.lower`` span of ``id``
+                              ``schedule``): span / count is the price
+                              of a record
 * ``collective_replays``    — speculative in-flight supersteps
                               discarded because the superstep they
                               chained from fired a collective tape

@@ -53,6 +53,59 @@ def test_capture_matches_generator(op, algo, ranks, gen_pay, nbytes):
     assert cap.sequence() == gen.sequence()
 
 
+@pytest.mark.parametrize("steps", [1, 3])
+def test_the_lr_head_is_the_captured_ring_cut_after_its_steps(steps):
+    """``steps=S`` emits the head of the logical ring: each rank's
+    self-copy and first S ring ``sendrecv``s, exactly the programs the
+    real coll.allreduce_lr posts, cut after that rank's first S + 1
+    ``sendrecv``s (four ops each), with the predecessors the cut
+    programs give.  The remainder's rdb, after the ring, is not in it."""
+    ranks, elems = 16, 16 * 3 + 5
+    progs = record_algorithm("allreduce", "lr", ranks,
+                             default_payload("allreduce", ranks, elems * 8))
+    cut = S.build_schedule([(p.ops if isinstance(p, S.Prog) else p)
+                            [:4 * (steps + 1)] for p in progs])
+    before = opstats.snapshot()
+    gen = generate("allreduce", "lr", ranks, elems, steps=steps)
+    assert opstats.diff(before)["collective_schedule_records"] \
+        == gen.n_comms == ranks * (steps + 1)
+    assert gen.sequence() == cut.sequence()
+    # the whole schedule is the head of every ring step and its remainder
+    whole = generate("allreduce", "lr", ranks, elems)
+    assert generate("allreduce", "lr", ranks, elems,
+                    steps=2 * (ranks - 1)).sequence() == whole.sequence()
+
+
+@pytest.mark.parametrize("op, algo, ranks, payload, steps, match", [
+    ("allreduce", "lr", 16, 160, 31, "allreduce/lr among 16 ranks has 30"),
+    ("allreduce", "lr", 16, 160, -1, "allreduce/lr among 16 ranks"),
+    ("allreduce", "lr", 16, 8, 1, "allreduce/lr of 8 elements"),
+    ("allreduce", "rdb", 16, 8192, 1, "allreduce/rdb has no schedule head"),
+    ("alltoall", "pairwise", 4, 1e6, 1, "alltoall/pairwise has no"),
+])
+def test_a_head_the_schedule_cannot_give_is_refused_by_name(
+        op, algo, ranks, payload, steps, match):
+    with pytest.raises(ValueError, match=match):
+        generate(op, algo, ranks, payload, steps=steps)
+    if algo != "lr":
+        with pytest.raises(ValueError, match=match):
+            CollectiveSpec(op, algo, ranks, "nic", payload, steps=steps)
+
+
+def test_a_head_is_part_of_the_specs_identity():
+    """A whole schedule keeps the key it had before heads existed; a
+    head is another spec, and says so in its label and its JSON."""
+    whole = CollectiveSpec("allreduce", "lr", 8, "nic", 64)
+    head = CollectiveSpec("allreduce", "lr", 8, "nic", 64, steps=3)
+    assert "steps" not in whole.to_dict()
+    assert head.to_dict()["steps"] == 3
+    assert head.key() != whole.key()
+    assert CollectiveSpec.from_json(head.to_json()).key() == head.key()
+    assert CollectiveSpec.from_json(whole.to_json()).steps is None
+    assert head.label() == "allreduce/lr r8 nic 64B steps3"
+    assert head.build().n_v == 8 * 4
+
+
 def test_barrier_is_not_capturable():
     """barrier's linear algorithm receives from MPI_ANY_SOURCE, which
     cannot be compiled into a static tape: the recorder must refuse,

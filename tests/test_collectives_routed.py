@@ -95,12 +95,94 @@ def test_routes_and_capacities_are_communicates(engine):
             cnsts[ci].id.get_bandwidth())
 
 
-def test_a_rank_sending_to_itself_is_refused(engine):
+@pytest.mark.parametrize("crosstraffic", [True, False],
+                         ids=["crosstraffic", "no-crosstraffic"])
+def test_a_rank_sending_to_itself_rides_communicates_self_route(
+        tmp_path, crosstraffic):
+    """A self pair (the lr allreduce's self-copy) is lowered as
+    ``NetworkCm02Model.communicate(h, h)`` expands it into the engine's
+    system: the host's router link up and back down at weight 1 and,
+    under cross-traffic, the same two links again at 0.05, kept apart
+    as ``expand`` keeps them; its delay is the latency the action
+    waits."""
+    path = tmp_path / "dfly128.xml"
+    path.write_text(XML)
+    s4u.Engine._reset()
+    flags = ["--cfg=network/maxmin-selective-update:no",
+             "--cfg=network/optim:Full"]
+    if not crosstraffic:
+        flags.append("--cfg=network/crosstraffic:0")
+    try:
+        e = s4u.Engine(["self"] + flags)
+        e.load_platform(str(path))
+        hosts = rank_hosts(e)
+        topo = RoutedTopology(e, hosts)
+        before = opstats.snapshot()
+        rec, cn, w = topo.lower(np.array([3, 3, 5]), np.array([3, 4, 5]))
+        took = opstats.diff(before)
+        model = e.pimpl.network_model
+        for k, h in ((0, 3), (2, 5)):
+            act = model.communicate(hosts[h], hosts[h], 1e6, -1.0)
+            want = [(el.constraint.id.name, el.consumption_weight)
+                    for el in act.variable.cnsts]
+            ours = [(topo.links[c].name, float(x))
+                    for c, x in zip(cn[rec == k], w[rec == k])]
+            assert ours == want
+            assert len(want) == (4 if crosstraffic else 2)
+            assert [n for n, _ in want[:2]] == [
+                n for n, _ in want[2:]] or not crosstraffic
+            assert topo.delays([h], [h])[0] == act.latency
+            assert act.latency == pytest.approx(13.01 * 2 * 5e-5, rel=1e-12)
+            links = []
+            hosts[h].route_to(hosts[h], links)
+            assert [topo.links[c] for c in topo.route(h, h)] == links
+        # (3, 3), (5, 5), (3, 4) and, with cross-traffic, (4, 3): each
+        # routed once, the two self pairs counted apart
+        assert took["collective_self_routes"] == 2
+        assert took["collective_routes"] == (4 if crosstraffic else 3)
+    finally:
+        s4u.Engine._reset()
+
+
+FLAT_XML = """<?xml version='1.0'?>
+<platform version="4.1">
+  <zone id="world" routing="Full">
+    <host id="a" speed="1Gf"/>
+    <host id="b" speed="1Gf"/>
+    <link id="l" bandwidth="125MBps" latency="50us"/>
+    <route src="a" dst="b"><link_ctn id="l"/></route>
+  </zone>
+</platform>
+"""
+
+
+def test_a_host_with_no_route_to_itself_rides_the_models_loopback(
+        tmp_path):
+    """Where the platform routes no host to itself, ``communicate``
+    puts the flow on the network model's loopback: so does the routed
+    flavor, with its latency."""
+    path = tmp_path / "flat.xml"
+    path.write_text(FLAT_XML)
+    s4u.Engine._reset()
+    try:
+        e = s4u.Engine(["flat"])
+        e.load_platform(str(path))
+        hosts = e.get_all_hosts()
+        topo = RoutedTopology(e, hosts)
+        rec, cn, w = topo.lower(np.array([0]), np.array([0]))
+        act = e.pimpl.network_model.communicate(hosts[0], hosts[0], 1e6,
+                                                -1.0)
+        assert [(topo.links[c].name, float(x)) for c, x in zip(cn, w)] \
+            == [(el.constraint.id.name, el.consumption_weight)
+                for el in act.variable.cnsts]
+        assert topo.links[cn[0]] is e.pimpl.network_model.loopback
+        assert topo.delays([0], [0])[0] == act.latency > 0
+    finally:
+        s4u.Engine._reset()
+
+
+def test_a_topology_for_other_ranks_is_refused(engine):
     topo = RoutedTopology(engine, rank_hosts(engine))
-    with pytest.raises(ValueError, match="to itself"):
-        topo.route(3, 3)
-    with pytest.raises(ValueError, match="to itself"):
-        CollectiveSpec("allreduce", "lr", RANKS, topo, 64).build()
     with pytest.raises(ValueError, match="places 16 ranks"):
         CollectiveSpec("alltoall", "pairwise", 8, topo, 1e6)
 
@@ -475,3 +557,90 @@ def test_a_burst_over_the_rungs_below_the_list_falls_back_with_the_index_present
     # what the bursts' rounds indexed is wider than the bottom rung
     assert took["fixpoint_worked_elem_rounds"] \
         > sizes[-1] * took["fixpoint_rounds"]
+
+
+# ---------------------------------------------------------------------------
+# the logical ring on the routed flavor: a self-copy, then a ring step
+# ---------------------------------------------------------------------------
+
+def ring_reference():
+    """``benchmarks/configs/dragonfly_lv08_ring.py`` (numpy, float64,
+    nothing of the program), loaded with the package beside it under a
+    name of its own."""
+    import importlib
+    import importlib.util
+    import os
+    import sys
+    name = "benchmark_references"
+    if name not in sys.modules:
+        folder = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs")
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(folder, "__init__.py"),
+            submodule_search_locations=[folder])
+        sys.modules[name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(sys.modules[name])
+    return importlib.import_module(name + ".dragonfly_lv08_ring")
+
+
+RING_STEPS = 4
+
+
+@pytest.fixture
+def lr128(engine):
+    before = opstats.snapshot()
+    dc = CollectiveSpec("allreduce", "lr", 128,
+                        RoutedTopology(engine, all_ranks(engine)),
+                        128 * 16384, steps=RING_STEPS).build()
+    took = opstats.diff(before)
+    assert took["collective_schedule_records"] == dc.n_v \
+        == 128 * (RING_STEPS + 1)
+    assert took["collective_self_routes"] == 128
+    return dc
+
+
+def ring_dates(events, dc, dag):
+    """{reference flow: date}, the program's records named as the
+    reference names its flows (both rank-major, the self-copy first)."""
+    src = np.array([r.src for r in dc.schedule.records])
+    dst = np.array([r.dst for r in dc.schedule.records])
+    assert np.array_equal(src, dag.src) and np.array_equal(dst, dag.dst)
+    return {int(f): t for t, f in events}
+
+
+def test_the_lr_head_is_the_ring_reference_in_float64_and_close_in_float32(
+        lr128):
+    """The routed ``lr`` tape at 128 ranks, drained whole: every
+    completion and activation at the reference's date to 1e-12 and in
+    its order in float64 (and the host maestro's bit for bit); within
+    the benchmark cell's date limit in float32, nothing unmatched, in
+    the same order but for dates the float64 reference itself tells
+    apart by an ulp (two ways of adding up to one activation date:
+    float32 may take those either way round)."""
+    ref = ring_reference()
+    dc = lr128
+    dag = ref.ring_dag(128, RING_STEPS)
+    system, delay = ref.dag_system("4,3;2,2;4,2;4", 125e6, 5e-5,
+                                   np.arange(128), dag)
+    assert (dc.n_c, dc.n_v, len(dc.e_var), dc.n_edges) \
+        == (*system.shape, int((dag.preds >= 0).sum()))
+    assert np.allclose(dc.exec_cost, delay, rtol=1e-12, atol=0)
+    done, started, _ = ref.drain(system, dag, delay,
+                                 np.full(dc.n_v, 131072.0), 10**6)
+    want = (ring_dates(done, dc, dag), ring_dates(started, dc, dag))
+    sim, _ = ran(dc)
+    assert sim.dtype == np.float64
+    ma = HostMaestro(dc)
+    ma.run()
+    assert ma.events == sim.events
+    assert ma.collective_events == sim.collective_events
+    low, _ = ran(dc, dtype=np.float32)
+    for run, gap, ulp in ((sim, 1e-12, 0.0), (low, DATE_GAP, 1e-12)):
+        for got, ref_t in ((run.events, want[0]),
+                           (run.collective_events, want[1])):
+            assert len(got) == len(ref_t) == dc.n_v
+            assert max(abs(t - ref_t[f]) / ref_t[f] for t, f in got) < gap
+            high = 0.0
+            for t, f in sorted(got, key=lambda e: (e[0], ref_t[e[1]])):
+                assert ref_t[f] >= high * (1 - ulp)
+                high = max(high, ref_t[f])
