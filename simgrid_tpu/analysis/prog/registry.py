@@ -122,11 +122,11 @@ def _tape(n_c: int):
 
 
 def _collective(n_v: int):
-    """A tiny chain DAG: flow i+1 waits on flow i."""
+    """A tiny chain DAG: flow i+1 waits on flow i, and the root starts
+    live (the factories' penalties), so undated."""
     pred = np.zeros(n_v, np.int32)
     pred[1:] = 1
     ready = np.full(n_v, np.inf)
-    ready[0] = 0.0
     edge_src = np.arange(n_v - 1, dtype=np.int32)
     edge_dst = np.arange(1, n_v, dtype=np.int32)
     exec_cost = np.full(n_v, 0.125)

@@ -81,8 +81,8 @@ from .device import default_platform, solve_dtype
 from .lmm_jax import (_MAX_ROUNDS, SolveError, _solve_kernel_chunk_batched,
                       _solve_kernel_chunk_batched_fresh)
 from .lmm_drain import (_FLAG_BUDGET, _FLAG_OK, _FLAG_STALLED, _STATS_HEAD,
-                        _ZERO_BITS, _pos_group, _superstep_program,
-                        _to2d)
+                        _ZERO_BITS, _check_collective_start, _pos_group,
+                        _superstep_program, _to2d)
 
 
 #: the mesh axis name the replica dimension shards over
@@ -1015,6 +1015,9 @@ class BatchDrainSim:
             if any(ov.dead_flows for ov in self.overrides):
                 raise ValueError("collective fleets cannot kill DAG "
                                  "flows via dead_flows overrides")
+            # every lane starts from the base penalties (dead_flows
+            # refused above), admitted lanes too
+            _check_collective_start(self._base_pen, cp, cr)
             self.has_coll = True
             self._coll_base = (cp, cr)
             self._coll_edges = tuple(self._put_shared(a)

@@ -254,6 +254,26 @@ def _succ_walk(pred, ring_id, n_ev, n_done, s_ptr, s_dst, width: int):
     return pred.at[dst].add(-1, mode="drop")
 
 
+def _check_collective_start(pen, pred, ready):
+    """Refuse a collective tape whose start breaks what the superstep's
+    one ring scatter rests on (see :func:`_superstep_program`): a live
+    flow (penalty > 0) with predecessors outstanding or a ready date,
+    or a dated one with predecessors outstanding.  Every tape
+    ``DeviceCollective`` lowers starts as its DAG does: the roots live
+    or dated, every other flow dormant, undated and waiting."""
+    live = np.asarray(pen, np.float64) > 0
+    dated = np.isfinite(np.asarray(ready, np.float64))
+    waits = np.asarray(pred, np.int64) > 0
+    for bad, what in ((live & waits, "live with predecessors outstanding"),
+                      (live & dated, "live with a ready date"),
+                      (dated & waits, "dated with predecessors outstanding")):
+        if bad.any():
+            raise ValueError(
+                f"collective= starts {int(bad.sum())} flow(s) {what} "
+                f"(the first: flow {int(np.argmax(bad))}); a flow starts "
+                "live, dated or waiting, not two of them")
+
+
 def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                        thresh, ids, k, round_budget, stop_live, zero_bits,
                        tape_t, tape_slot, tape_val, tape_pos,
@@ -455,51 +475,59 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
             t_ring = t_new.astype(dtype) if has_coll else t_new
 
         with jax.named_scope("sg.drain.ring"):
-            # completion ring: positions by stable slot order (cumsum), the
-            # same within-advance order the host paths emit; non-done slots
-            # scatter out-of-range and are dropped.  2D index shape: the
-            # ops/ scatter convention.
-            dcount = jnp.cumsum(done.astype(jnp.int32))
-            pos = jnp.where(done, n_ev + dcount - 1, ring_n)
-            pos2 = pos.reshape(-1, group)
-            ring_t2 = ring_t.at[pos2].set(
-                jnp.broadcast_to(t_ring, pos2.shape), mode="drop")
-            ring_id2 = ring_id.at[pos2].set(ids.reshape(-1, group),
-                                            mode="drop")
-            n_done = dcount[-1]
-
-            if has_tape:
-                # the fault fires AFTER this advance's completions (they
-                # retire AT the event date; the new capacity governs from
-                # the event onward): tagged ring entry, bound scatter, and
-                # cursor bump — all dropped when not firing
-                slot = tape_slot[ti]
-                fpos = jnp.where(f_fire, n_ev + n_done, ring_n)
-                ring_t2 = ring_t2.at[fpos].set(t_ring, mode="drop")
-                ring_id2 = ring_id2.at[fpos].set(-(1 + slot), mode="drop")
-                n_new = n_ev + n_done + f_fire.astype(jnp.int32)
-                cb2 = cb_c.at[jnp.where(f_fire, slot, n_c)].set(
-                    tape_val[ti], mode="drop")
-                tpos2 = tpos + (ok & f_fire).astype(jnp.int32)
-            else:
-                n_new = n_ev + n_done
-
             if has_coll:
                 with jax.named_scope("sg.drain.coll"):
-                    # activations fire AFTER completions and any fault entry:
-                    # every pending flow whose ready date is <= the event date
-                    # wakes up (penalty scatter), its ready slot is consumed,
-                    # and a tagged entry id = -(1 + n_c + flow_id) logs the
-                    # fired successor at the (absolute) advance clock
+                    # activations: every pending flow whose ready date is
+                    # <= the event date wakes up (penalty scatter below)
+                    # and its ready slot is consumed
                     a_any = fire & (next_at <= next_ft)
                     act = a_any & (ready_c <= next_t)
                     acount = jnp.cumsum(act.astype(jnp.int32))
-                    apos = jnp.where(act, n_new + acount - 1, ring_n)
-                    ring_t2 = ring_t2.at[apos].set(
-                        jnp.broadcast_to(t_ring, apos.shape), mode="drop")
-                    ring_id2 = ring_id2.at[apos].set(-(1 + n_c + ids),
-                                                     mode="drop")
-                    n_new = n_new + acount[-1]
+            # An advance logs, at its one date, its completions in stable
+            # slot order (cumsum, the within-advance order the host paths
+            # emit), then the fault that fired, then the activations: one
+            # run of entries [n_ev, n_new).  So the dates are one range
+            # select, and every id but the fault's one scatter; the slots
+            # it drops scatter out of range.  2D index shape: the ops/
+            # scatter convention.
+            dcount = jnp.cumsum(done.astype(jnp.int32))
+            n_done = dcount[-1]
+            pos = jnp.where(done, n_ev + dcount - 1, ring_n)
+            val = ids
+            n_new = n_ev + n_done
+            if has_tape:
+                # the fault fires AFTER this advance's completions (they
+                # retire AT the event date; the new capacity governs from
+                # the event onward): tagged entry id = -(1 + slot), bound
+                # scatter, and cursor bump — all dropped when not firing
+                slot = tape_slot[ti]
+                fpos = jnp.where(f_fire, n_new, ring_n)
+                n_new = n_new + f_fire.astype(jnp.int32)
+                cb2 = cb_c.at[jnp.where(f_fire, slot, n_c)].set(
+                    tape_val[ti], mode="drop")
+                tpos2 = tpos + (ok & f_fire).astype(jnp.int32)
+            if has_coll:
+                # a fired successor's tagged entry id = -(1 + n_c +
+                # flow_id) rides the completions' scatter, since no flow
+                # both completes (it was live) and activates (it held a
+                # ready date) in one advance: a date is set only where
+                # pred falls to zero, an activation consumes it, and a
+                # flow that is live or dated holds pred <= 0, so it never
+                # gets one.  DrainSim and BatchDrainSim refuse a start
+                # that breaks this (_check_collective_start).
+                pos = jnp.where(done, pos, jnp.where(
+                    act, n_new + acount - 1, ring_n))
+                val = jnp.where(done, ids, -(1 + n_c + ids))
+                n_new = n_new + acount[-1]
+            ring_id2 = ring_id.at[pos.reshape(-1, group)].set(
+                val.reshape(-1, group), mode="drop")
+            if has_tape:
+                ring_id2 = ring_id2.at[fpos].set(-(1 + slot), mode="drop")
+            at = lax.iota(jnp.int32, ring_n)
+            ring_t2 = jnp.where((at >= n_ev) & (at < n_new), t_ring, ring_t)
+
+            if has_coll:
+                with jax.named_scope("sg.drain.coll"):
                     pen2 = jnp.where(act, jnp.asarray(1.0, dtype), pen2)
                     ready2 = jnp.where(act, jnp.inf, ready_c)
                     # DAG walk: completions decrement their successors'
@@ -957,6 +985,7 @@ class DrainSim:
                 if len(ces) != len(ced):
                     raise ValueError("collective edge arrays must have "
                                      "equal length")
+                _check_collective_start(pen0, cp, cr)
                 self.has_coll = True
                 # a repack would scramble the DAG's static slot indexing
                 self.repack_min = 1 << 62

@@ -376,15 +376,17 @@ def test_the_registry_shows_proglint_the_index():
         assert "stablehlo.case" in text
 
 
-#: sha256 of the lowered text at the parent commit (306c526) of the
-#: programs with a collective that pass NO source-major index: the
-#: fleet's (its ``vmap`` would run both sides of the ``cond``), and the
-#: solo tape's without the last two arguments.  The six programs
-#: without a collective are pinned in ``test_collectives_routed.py``.
+#: sha256 of the lowered text of the programs with a collective that
+#: pass NO source-major index: the fleet's (its ``vmap`` would run both
+#: sides of the ``cond``), and the solo tape's without the last two
+#: arguments; re-pinned when the ring's dates became one range select
+#: and its ids one scatter (tests/test_drain_ring.py).  The six
+#: programs without a collective are pinned in
+#: ``test_collectives_routed.py``.
 PARENT_TEXT = {
-    "fleet/superstep_coll": "e50c72ff23a59b3b",
-    "drain/superstep_coll": "ffdc17a25f3ab0b2",
-    "drain/superstep_coll_f32": "3f9afe694027ead1",
+    "fleet/superstep_coll": "430b7d6c241ecf79",
+    "drain/superstep_coll": "aaa0b06f08276b20",
+    "drain/superstep_coll_f32": "2e3a4ccee93b9a7d",
 }
 
 

@@ -306,17 +306,18 @@ def test_a_replayed_dispatch_needs_its_fetch(pairwise):
 
 
 #: sha256 of ``_drain_superstep.lower(...).as_text()`` for the
-#: registry's scale-1 example at the parent commit (14240ef), where
-#: the collective arm was float64-only: the programs WITHOUT a
-#: collective must lower to the same text now.  If a later change moves
-#: the drain's program on purpose, re-pin from the failing assert.
+#: registry's scale-1 example: the programs WITHOUT a collective lower
+#: to the same text whatever the collective arm does.  Re-pinned when
+#: the ring's dates became one range select (tests/test_drain_ring.py);
+#: if a later change moves the drain's program on purpose, re-pin from
+#: the failing assert.
 PARENT_TEXT = {
-    "drain/superstep": "ed3e2d2f129b0578",
-    "drain/superstep_f32": "95ae3cd585bcf128",
-    "drain/superstep_tape": "be5dc5315ac79d63",
-    "fleet/superstep": "5cae3b4210ec5622",
-    "fleet/superstep_f32": "ef205feaa5970a72",
-    "fleet/superstep_tape": "90d6b59ad70b646a",
+    "drain/superstep": "01d68c3371f59e95",
+    "drain/superstep_f32": "57e1addca67ae5b7",
+    "drain/superstep_tape": "88c0c385ff46f3b9",
+    "fleet/superstep": "51adb3f95efade91",
+    "fleet/superstep_f32": "864abf4ce6f1bfab",
+    "fleet/superstep_tape": "7491209d2a533229",
 }
 
 
