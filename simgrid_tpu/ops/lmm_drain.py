@@ -76,6 +76,7 @@ from __future__ import annotations
 
 import functools
 from collections import deque
+from itertools import repeat
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -1417,66 +1418,66 @@ class DrainSim:
         per-advance ``(dt, [flow ids])`` batches and how many fault
         entries fired."""
         o = _STATS_HEAD
-        adv_dt = p[o:o + k_max]
-        adv_nev = p[o + k_max:o + 2 * k_max].astype(np.int64)
+        adv_dt = p[o:o + adv].tolist()
+        ends = p[o + k_max:o + k_max + adv].astype(np.int64).tolist()
         o += 2 * k_max
         ring_n = (self.n_v + (k_max if self.has_tape else 0)
                   + (self.n_v if self.has_coll else 0))
-        ring_t = p[o:o + ring_n]
-        ring_id = p[o + ring_n:o + 2 * ring_n].astype(np.int64)
-        batches: List[Tuple[float, List[int]]] = []
-        start = 0
+        n_ev = ends[-1] if adv else 0
+        ring_id = p[o + ring_n:o + ring_n + n_ev].astype(np.int64)
         # collective dates are ABSOLUTE (the Kahan clock pair is carried
-        # across dispatches), so the base folds to zero
+        # across dispatches, replayed below), so the base folds to zero;
+        # the others are the ring's offsets in the solve dtype from the
+        # dispatch's base clock, added in f64
         t_base = 0.0 if self.has_coll else self.t
-        fired = 0
-        if self.has_tape or self.has_coll:
-            # demux the ring: negative ids are tagged entries — fault
-            # fires (idx < n_c, into the fault stream) or collective
-            # activations (idx >= n_c, flow idx - n_c fired into the
-            # activation stream) — neither joins the completion batches
-            for i in range(adv):
-                end = int(adv_nev[i])
-                batch_ids: List[int] = []
-                if self.has_coll:
-                    # the ring's dates are in the solve dtype; the
-                    # advance's own is the device's float64 pair, one
-                    # step of the same recurrence on its exact dt (the
-                    # step HostMaestro takes)
-                    t_c, comp = self._coll_clk_host
-                    y = float(adv_dt[i]) - comp
-                    t_adv = t_c + y
-                    self._coll_clk_host = (t_adv, (t_adv - t_c) - y)
-                for j in range(start, end):
-                    fid = int(ring_id[j])
-                    tj = (t_adv if self.has_coll
-                          else t_base + float(ring_t[j]))
-                    if fid < 0:
-                        idx = -fid - 1
-                        if idx >= self.n_c:
-                            self.collective_events.append(
-                                (tj, idx - self.n_c))
-                        else:
-                            self.fault_events.append((tj, idx))
-                            fired += 1
-                    else:
-                        batch_ids.append(fid)
-                        self.events.append((tj, fid))
-                batches.append((float(adv_dt[i]), batch_ids))
-                start = end
-            self._tpos_host += fired
-            self._last_fired = fired > 0
-            if fired:
-                opstats.bump("fault_tape_events", fired)
-        else:
-            for i in range(adv):
-                end = int(adv_nev[i])
-                batches.append((float(adv_dt[i]),
-                                [int(f) for f in ring_id[start:end]]))
-                for j in range(start, end):
-                    self.events.append((t_base + float(ring_t[j]),
-                                        int(ring_id[j])))
-                start = end
+        t_ring = (None if self.has_coll
+                  else t_base + p[o:o + n_ev].astype(np.float64))
+
+        def runs(mask, vals):
+            """Each advance's ``(dates, ids)`` under ``mask``, in ring
+            order; dates None where they are the advance's own."""
+            at = np.flatnonzero(mask)
+            cut = [0] + np.searchsorted(at, ends).tolist()
+            ids = vals[at].tolist()
+            dates = None if t_ring is None else t_ring[at].tolist()
+            return [(None if dates is None else dates[a:b], ids[a:b])
+                    for a, b in zip(cut, cut[1:])]
+
+        # negative ids are tagged entries — fault fires (idx < n_c, into
+        # the fault stream) or collective activations (idx >= n_c, flow
+        # idx - n_c fired into the activation stream) — neither joins
+        # the completion batches
+        tagged = ring_id < 0
+        tags = -1 - ring_id
+        act = tagged & (tags >= self.n_c)
+        fault = tagged & ~act
+        done = runs(~tagged, ring_id)
+        streams = [(self.events, done)]
+        if act.any():
+            streams.append((self.collective_events,
+                            runs(act, tags - self.n_c)))
+        fired = int(np.count_nonzero(fault))
+        if fired:
+            streams.append((self.fault_events, runs(fault, tags)))
+        batches: List[Tuple[float, List[int]]] = []
+        for i, dt in enumerate(adv_dt):
+            if self.has_coll:
+                # the ring's dates are in the solve dtype; the advance's
+                # own is the device's float64 pair, one step of the same
+                # recurrence on its exact dt (the step HostMaestro takes)
+                t_c, comp = self._coll_clk_host
+                y = dt - comp
+                t_adv = t_c + y
+                self._coll_clk_host = (t_adv, (t_adv - t_c) - y)
+            for out, run in streams:
+                dates, ids = run[i]
+                out.extend(zip(repeat(t_adv) if dates is None else dates,
+                               ids))
+            batches.append((dt, done[i][1]))
+        self._tpos_host += fired
+        self._last_fired = fired > 0
+        if fired:
+            opstats.bump("fault_tape_events", fired)
         # f64 master clock: one Kahan-compensated dtype total per
         # superstep, accumulated on host in f64 (a collective's is the
         # absolute clock of the pair replayed above)
