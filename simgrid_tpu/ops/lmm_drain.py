@@ -261,6 +261,73 @@ def _succ_walk(pred, ring_id, n_ev, n_done, s_ptr, s_dst, width: int):
     return pred.at[dst].add(-1, mode="drop")
 
 
+#: The widths at which an advance of a collective tape with the DAG's
+#: source-major index writes its ring ids (:func:`_ring_narrow`): the
+#: narrowest its entries fit, and the flow-wide scatter above the top
+#: one.  A rung is kept only where it is at most an eighth of the flow
+#: slots, and there it costs a small part of that scatter (PERF.md §5).
+_RING_RUNGS = (1 << 10, 1 << 13)
+
+
+def _ring_narrow(ring_id, dcount, acount, ids, n_ev, fault, n_c: int,
+                 width: int):
+    """``ring_id`` with one advance's completion and activation ids
+    written at ``width``: the completions in slot order from ``n_ev``,
+    a slot left for the fault's entry when ``fault`` (0 or 1), then the
+    activations, as the flow-wide scatter writes them; they are at most
+    ``width`` with the fault's (the caller's test).  Position ``p``'s
+    flow is the ``p``-th counted one of ``[done, act]``, found from
+    their running counts ``dcount`` / ``acount`` viewed as rows: a dense
+    compare against the rows' last counts gives its row, one
+    ``width``-row gather and a compare within it its lane.  Then one
+    ``width``-wide gather of ids and one scatter, positions past the
+    entries (and the ring) dropped; nothing is indexed at the width of
+    the flows."""
+    n_v = dcount.shape[0]
+    ring_n = ring_id.shape[0]
+    n_done = dcount[-1]
+    total = n_done + acount[-1]
+    # rows of about the square root of the counts: the rows compared
+    # and the lanes of the one row gathered are about as many
+    lanes = 128
+    while lanes * lanes < 2 * n_v:
+        lanes *= 2
+    rows = -(-2 * n_v // lanes)
+    cnt = jnp.concatenate([
+        dcount, n_done + acount,
+        jnp.broadcast_to(total, (rows * lanes - 2 * n_v,))]).reshape(
+            rows, lanes)
+    at = lax.iota(jnp.int32, width)
+    rank = jnp.where(at < n_done, at, at - fault)
+    keep = (rank < total) & ((at < n_done) | (at >= n_done + fault))
+    row = jnp.sum(cnt[:, -1] <= rank[:, None], axis=1, dtype=jnp.int32)
+    lane = jnp.sum(jnp.take(cnt, row, axis=0, mode="clip")
+                   <= rank[:, None], axis=1, dtype=jnp.int32)
+    slot = row * lanes + lane
+    group = _pos_group(width)
+    rows_of = lambda a: a.reshape(width // group, group)
+    got = jnp.take(ids, rows_of(jnp.where(slot < n_v, slot, slot - n_v)),
+                   mode="clip").reshape(-1)
+    val = jnp.where(slot < n_v, got, -(1 + n_c + got))
+    return ring_id.at[rows_of(jnp.where(keep, n_ev + at, ring_n))].set(
+        rows_of(val), mode="drop")
+
+
+def _ring_ids(ring_id, wide, dcount, acount, ids, n_ev, count, fault,
+              n_c: int, rungs):
+    """``ring_id`` with the ids of one advance's ``count`` entries
+    written from ``n_ev``, and whether that took a rung: on the
+    narrowest of ``rungs`` they fit (:func:`_ring_narrow`), else by
+    ``wide``, the flow-wide scatter.  A ``lax.switch`` on the advance's
+    own count, as the DAG walk is chosen; both sides leave the same
+    ring to the bit."""
+    rung = sum((count > w).astype(jnp.int32) for w in rungs)
+    return lax.switch(rung, [functools.partial(
+        _ring_narrow, dcount=dcount, acount=acount, ids=ids, n_ev=n_ev,
+        fault=fault, n_c=n_c, width=w)
+        for w in rungs] + [wide], ring_id), jnp.asarray(rung) < len(rungs)
+
+
 def _check_collective_start(pen, pred, ready):
     """Refuse a collective tape whose start breaks what the superstep's
     one ring scatter rests on (see :func:`_superstep_program`): a live
@@ -369,8 +436,14 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     (:func:`_succ_walk`) and one that owns more walks the whole edge
     list, a ``lax.cond`` an advance on a count of its own completions;
     both give the same counts to the bit, and the advances that took
-    the first side are counted into a fifth scalar.  A sim without a
-    collective, and the fleet (whose ``vmap`` would run both sides),
+    the first side are counted into a fifth scalar.  With that index
+    the ring's ids are written as wide as the advance's entries, on the
+    narrowest of ``_RING_RUNGS`` they fit, by the flow-wide scatter
+    above it (:func:`_ring_ids`; the same ring to the bit), an advance
+    that does not commit logs past the ring's end instead of selecting
+    the old ring back, and the advances that took a rung are counted
+    into a sixth.  A sim without
+    a collective, and the fleet (whose ``vmap`` would run every side),
     pass neither index.
 
     ``has_fatpipe`` (static) solves the constraints ``c_fatpipe`` marks
@@ -402,6 +475,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
     src_index = has_coll and s_ptr is not None
     if src_index:
         out_deg = s_ptr[1:] - s_ptr[:-1]
+        rungs = [w for w in _RING_RUNGS if 8 * w <= n_v]
     # where the loop state holds the pending-activation dates
     ready_at = 13 + 2 * has_tape + 1
 
@@ -429,7 +503,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         if has_coll:
             pred_c, ready_c, live_sum, fires, var_entries = st[idx:idx + 5]
             if src_index:
-                src_walks = st[idx + 5]
+                src_walks, ring_narrow = st[idx + 5:idx + 7]
         with jax.named_scope("sg.drain.solve"):
             out = fixpoint(e_var, e_cnst, e_w, cb_c, fat, pen_c, v_bound,
                            eps_c, n_c, n_v, parallel_rounds=True,
@@ -534,12 +608,32 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                     act, n_new + acount - 1, ring_n))
                 val = jnp.where(done, ids, -(1 + n_c + ids))
                 n_new = n_new + acount[-1]
-            ring_id2 = ring_id.at[pos.reshape(-1, group)].set(
+            if src_index:
+                # an advance that does not commit logs past the ring's
+                # end: its writes drop, so no select of the old ring
+                # follows them and they update the ring in place
+                skip = jnp.where(ok, 0, ring_n)
+                pos = pos + skip
+                if has_tape:
+                    fpos = fpos + skip
+            wide = lambda ring_id: ring_id.at[pos.reshape(-1, group)].set(
                 val.reshape(-1, group), mode="drop")
+            if src_index:
+                ring_id2, narrow = _ring_ids(
+                    ring_id, wide, dcount, acount, ids, n_ev + skip,
+                    n_new - n_ev, f_fire.astype(jnp.int32) if has_tape else 0,
+                    n_c, rungs)
+            else:
+                ring_id2 = wide(ring_id)
             if has_tape:
                 ring_id2 = ring_id2.at[fpos].set(-(1 + slot), mode="drop")
             at = lax.iota(jnp.int32, ring_n)
-            ring_t2 = jnp.where((at >= n_ev) & (at < n_new), t_ring, ring_t)
+            if src_index:
+                ring_t2 = jnp.where((at >= n_ev + skip) & (at < n_new + skip),
+                                    t_ring, ring_t)
+            else:
+                ring_t2 = jnp.where((at >= n_ev) & (at < n_new), t_ring,
+                                    ring_t)
 
             if has_coll:
                 with jax.named_scope("sg.drain.coll"):
@@ -588,8 +682,9 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
             sel = lambda a, b: jnp.where(ok, a, b)
             out_st = (sel(pen2, pen_c), sel(rem2, rem_c),
                       sel(t_new, t_sum), sel(t_comp2, t_comp),
-                      jnp.where(ok, ring_t2, ring_t),
-                      jnp.where(ok, ring_id2, ring_id),
+                      ring_t2 if src_index else jnp.where(ok, ring_t2, ring_t),
+                      ring_id2 if src_index
+                      else jnp.where(ok, ring_id2, ring_id),
                       jnp.where(ok, adv_dt2, adv_dt),
                       jnp.where(ok, adv_nev2, adv_nev),
                       sel(n_new, n_ev),
@@ -606,7 +701,9 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
                                    sel(var_entries + out[9], var_entries))
                 if src_index:
                     out_st = out_st + (
-                        sel(src_walks + few.astype(jnp.int32), src_walks),)
+                        sel(src_walks + few.astype(jnp.int32), src_walks),
+                        sel(ring_narrow + narrow.astype(jnp.int32),
+                            ring_narrow))
         return out_st
 
     zero = jnp.asarray(0, jnp.int32)
@@ -627,7 +724,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         st0 = st0 + (coll_pred, coll_ready, jnp.zeros(2, jnp.int32), zero,
                      zero)
         if src_index:
-            st0 = st0 + (zero,)
+            st0 = st0 + (zero, zero)
     st = lax.while_loop(cond, body, st0)
     (pen_o, rem_o, t_sum, t_comp_o, ring_t, ring_id, adv_dt, adv_nev,
      n_ev, adv, rounds, flag, worked) = st[:13]
@@ -663,7 +760,7 @@ def _superstep_program(e_var, e_cnst, e_w, c_bound, v_bound, pen, rem,
         if has_coll:
             tail = [*live_sum, fires, var_entries]
             if src_index:
-                tail.append(st[idx + 5])
+                tail += st[idx + 5:idx + 7]
             parts.append(jnp.stack(tail).astype(dtype))
         packed = jnp.concatenate(parts)
     return pen_o, rem_o, cb_o, tpos_o, pred_o, ready_o, clk_o, packed
@@ -1359,10 +1456,11 @@ class DrainSim:
             if self.has_coll:
                 # the tape's own counts ride the tail of the same fetch
                 opstats.bump("collective_live_flow_advances",
-                             _live_elem_rounds(p[-5:-3]))
-                opstats.bump("collective_tape_fires", int(p[-3]))
-                opstats.bump("fixpoint_var_entries", int(p[-2]))
-                opstats.bump("collective_src_walks", int(p[-1]))
+                             _live_elem_rounds(p[-6:-4]))
+                opstats.bump("collective_tape_fires", int(p[-4]))
+                opstats.bump("fixpoint_var_entries", int(p[-3]))
+                opstats.bump("collective_src_walks", int(p[-2]))
+                opstats.bump("collective_ring_narrow", int(p[-1]))
             with opstats.span("drain.demux"):
                 batches, fired = self._demux(p, adv, tok.k_max, t_sum)
                 if self.has_coll and self._coll_loopback is not None:

@@ -67,6 +67,18 @@ context object through the solver entry points:
                               fifth scalar at the tail of the packed
                               vector.  Over the advances: the share
                               that did not pay the edge-wide walk
+* ``collective_ring_narrow`` — committed advances of a collective
+                              tape's superstep that wrote their ring
+                              ids on a rung: their entries (completions,
+                              fault, activations) fit the widest of
+                              ``lmm_drain._RING_RUNGS`` the tape keeps,
+                              so ``_ring_narrow`` found their flows
+                              from the running counts and no op indexed
+                              as many rows as the flows; a sixth scalar
+                              at the tail of the packed vector, only
+                              where the tape has the DAG's source-major
+                              index.  Over the advances: the share that
+                              did not pay the flow-wide scatter
 * ``collective_loopback_completions`` — completions, in the rings a
                               collective tape's ``_superstep_collect``
                               fetched, of flows between two ranks of

@@ -9,7 +9,10 @@ index vector.  Held here:
 
 * the census: in the traced program one flow-wide scatter into the id
   ring and none into the date ring, the fault's id a write of its own,
-  whatever the program is armed with;
+  whatever the program is armed with; a tape with the source-major
+  index adds one write as wide as each rung it keeps, the flow-wide
+  scatter the last side of their switch (``test_drain_ring_narrow.py``
+  holds those writes to the bit);
 * the ring to the bit: dispatches of several advances against a numpy
   statement of the writes (in slot order, completions, then the fault,
   then the activations, what lies past the ring dropped), read from
@@ -40,7 +43,7 @@ from simgrid_tpu.ops.lmm_batch import BatchDrainSim, ReplicaOverrides
 from simgrid_tpu.ops.lmm_drain import (_STATS_HEAD, DrainSim,
                                        _check_collective_start)
 
-from tests.test_coll_src_walk import XML, sub_jaxprs
+from tests.test_coll_src_walk import XML, conds, sub_jaxprs
 
 #: sizes no two of which coincide, nor with any ring's width below
 N_C, N_V, DEG, K = 7, 40, 3, 3
@@ -299,10 +302,14 @@ def ring_scatters(jaxpr, n):
     (True, False, False), (False, True, True), (False, True, False),
     (True, True, True)], ids=["tape", "coll", "coll_bare", "tape_coll"])
 def test_an_advance_writes_its_ids_with_one_scatter_and_no_date_scatter(
-        tape, coll, index):
+        tape, coll, index, monkeypatch):
     """Exactly one scatter into the ring is as wide as the flows, and
     it writes ids; none writes dates; the fault's id is the one other
-    write into the ring, one element wide."""
+    write into the ring, one element wide.  A tape with the source-major
+    index adds one write as wide as each rung it keeps (none at this
+    size, two once the rungs are narrow enough), and its flow-wide
+    scatter is then the last side of one switch, each other side a
+    rung's write."""
     sim = plain_sim(np.float64, tape=_tape() if tape else None, coll=coll)
     a, statics = captured(sim)
     assert (statics["has_tape"], statics["has_coll"]) == (tape, coll)
@@ -311,13 +318,32 @@ def test_an_advance_writes_its_ids_with_one_scatter_and_no_date_scatter(
         args = args[:-2]
     n = ring_n(statics)
     assert len({n, N_V, N_C, N_V * DEG, N_V - 1, K}) == 6
-    jaxpr = jax.make_jaxpr(functools.partial(
+    trace = lambda: jax.make_jaxpr(functools.partial(
         lmm_drain._superstep_program, **statics))(*args).jaxpr
+    jaxpr = trace()
     writes = ring_scatters(jaxpr, n)
     assert [w for w in writes if w[0].kind == "f"] == []
     assert writes.count((np.dtype(np.int32), N_V)) == 1
     assert writes.count((np.dtype(np.int32), 1)) == tape
     assert len(writes) == 1 + tape
+    # the rungs as an indexed tape of 40 flows keeps them: 8 x 4 <= 40
+    rungs = (2, 4)
+    assert not {n, N_V, N_C, N_V * DEG, N_V - 1, K, 1} & set(rungs)
+    monkeypatch.setattr(lmm_drain, "_RING_RUNGS", rungs)
+    jaxpr = trace()
+    writes = ring_scatters(jaxpr, n)
+    assert [w for w in writes if w[0].kind == "f"] == []
+    assert writes.count((np.dtype(np.int32), N_V)) == 1
+    assert writes.count((np.dtype(np.int32), 1)) == tape
+    for w in rungs:
+        assert writes.count((np.dtype(np.int32), w)) == index
+    assert len(writes) == 1 + tape + len(rungs) * index
+    sides = [[ring_scatters(br.jaxpr, n) for br in eqn.params["branches"]]
+             for eqn in conds(jaxpr)]
+    sides = [s for s in sides if any(s)]
+    i32 = np.dtype(np.int32)
+    assert sides == ([[[(i32, w)] for w in rungs] + [[(i32, N_V)]]]
+                     if index else [])
 
 
 def _tape():
